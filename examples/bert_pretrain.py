@@ -52,6 +52,7 @@ def main():
     args = ap.parse_args()
 
     import jax
+    mx.config.setup_compile_cache()
     n_dev = len(jax.devices())
     vocab, n_pred = 30522, max(1, int(args.seq_len * 0.15 * 0.9) // 8 * 8)
 

@@ -56,6 +56,7 @@ def main():
                     help="training batches behind the streamed snapshot")
     ap.add_argument("--batch-size", type=int, default=256)
     args = ap.parse_args()
+    mx.config.setup_compile_cache()
 
     (train_x, train_y), (test_x, test_y) = load_mnist()
     print(f"training an MLP: {args.warm_batches} warm batches, then "

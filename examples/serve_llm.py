@@ -80,8 +80,10 @@ def main():
     args = ap.parse_args()
 
     from mxnet_tpu import serving
+    from mxnet_tpu.config import setup_compile_cache
     from mxnet_tpu.gluon.model_zoo.causal_lm import CausalLMConfig
 
+    setup_compile_cache()
     cfg = CausalLMConfig(vocab_size=VOCAB, n_layers=2, n_heads=2,
                          head_dim=16, d_ff=64)
     print(f"training a {cfg.n_layers}-layer causal LM on the successor "

@@ -59,6 +59,7 @@ def main():
     ap.add_argument("--deadline", type=float, default=0.5,
                     help="per-request deadline (seconds)")
     args = ap.parse_args()
+    mx.config.setup_compile_cache()
 
     print("training a quick MLP ...")
     net = train_quick(batches=args.train_batches)

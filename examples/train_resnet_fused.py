@@ -39,6 +39,7 @@ def main():
     args = ap.parse_args()
 
     import jax
+    mx.config.setup_compile_cache()
     n_dev = len(jax.devices())
 
     kw = {"fused": True} if args.fused_conv else {}

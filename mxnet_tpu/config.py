@@ -14,7 +14,7 @@ from __future__ import annotations
 
 import os
 
-__all__ = ["get", "describe", "KNOBS"]
+__all__ = ["get", "describe", "KNOBS", "setup_compile_cache"]
 
 
 class Knob:
@@ -121,6 +121,32 @@ def describe():
         out.append(f"{k.name:<38s}{str(k.default):<26s}"
                    f"{'yes' if k.wired else 'n/a':<7s}{k.doc}")
     return "\n".join(out)
+
+
+def setup_compile_cache():
+    """Point JAX's persistent compilation cache somewhere a later process
+    finds again, and return the directory in use.
+
+    ``JAX_COMPILATION_CACHE_DIR`` wins: jax reads it itself, so nothing is
+    set here and whoever runs the program places the cache.  Otherwise
+    the cache lives at ``<checkout>/.jax_compile_cache`` — a fixed path,
+    never a temp name, pid or time, because a cache that moves never
+    hits.  Every executable is kept (0 s floor): a generation server is
+    a handful of programs of a few seconds each and a model's eager
+    deferred-init pass a few hundred sub-second ones, and a warm start
+    should recompile none of them.  Entry points call this before their
+    first compile (``chip_smoke.py``, ``bench.py``, ``tests_tpu/``, the
+    examples)."""
+    import jax
+
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        cache_dir = os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            ".jax_compile_cache")
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return cache_dir
 
 
 def _apply_startup():

@@ -589,11 +589,8 @@ class HybridBlock(Block):
                     return jit_fn(param_arrays, jax.random.key(0), False,
                                   tree, sig, *leaves)
 
-                # `from jax import export`: on older jax the bare
-                # `jax.export` attribute raises (module not auto-imported)
-                from jax import export as _jax_export
-                exp = _jax_export.export(jax.jit(serve),
-                                         platforms=("cpu", "tpu"))(
+                exp = jax.export.export(jax.jit(serve),
+                                        platforms=("cpu", "tpu"))(
                     param_avals, *leaf_avals)
                 graph_file = f"{path}-graph.bin"
                 # raw StableHLO bytes on disk + json-only metadata: the
@@ -774,9 +771,8 @@ class SymbolBlock(HybridBlock):
                 "forward (or rebuild the model class and use "
                 "load_parameters)")
         base = os.path.dirname(os.path.abspath(symbol_file))
-        from jax import export as _jax_export
         with open(os.path.join(base, graph_file), "rb") as f:
-            exported = _jax_export.deserialize(f.read())
+            exported = jax.export.deserialize(f.read())
         params_path = param_file or os.path.join(base, meta["params"])
         loaded = ndmod.load(params_path)
         missing = [n for n in meta["param_order"] if n not in loaded]

@@ -176,7 +176,10 @@ class DataLoader:
                 # into a hang — the caller owns teardown after a timeout
                 raise TimeoutError(
                     f"DataLoader worker batch {next_yield} not ready within "
-                    f"timeout={self._timeout}s") from None
+                    f"timeout={self._timeout}s (a forked worker that "
+                    f"touches an NDArray blocks for good: the device "
+                    f"belongs to the parent process — keep worker-side "
+                    f"samples in numpy, or pass thread_pool=True)") from None
             except Exception as exc:
                 self.close()
                 raise _fault.with_context(

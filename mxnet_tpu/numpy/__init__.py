@@ -170,7 +170,7 @@ _UNARY = [
     "square", "abs", "absolute", "sign", "negative", "reciprocal",
     "sin", "cos", "tan", "arcsin", "arccos", "arctan", "sinh", "cosh",
     "tanh", "arcsinh", "arccosh", "arctanh", "degrees", "radians",
-    "floor", "ceil", "rint", "trunc", "fix", "logical_not",
+    "floor", "ceil", "rint", "trunc", "logical_not",
     "isnan", "isinf", "isfinite", "isneginf", "isposinf",
 ]
 _BINARY = [
@@ -318,6 +318,7 @@ for _n in (_UNARY + _BINARY + _SHAPE + _OTHER + _REDUCE + _CONCAT + _EXTRA):
     if not hasattr(_this, _n) and hasattr(jnp, _n):
         setattr(_this, _n, _delegate(_n))
 
+fix = trunc  # noqa: F821 — numpy's name for it; jnp.fix is deprecated
 abs = _delegate("abs")          # shadow builtins deliberately, like numpy
 round = _delegate("round")
 sum = _delegate("sum")

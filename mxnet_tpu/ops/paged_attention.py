@@ -32,6 +32,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ..context import on_tpu
 from .registry import register_op
 
 _NEG = -1e30
@@ -69,7 +70,7 @@ def paged_decode_attention(q, k_pages, v_pages, page_tables, lengths,
     Returns ``[slots, heads, head_dim]`` attention output.
     """
     if impl is None:
-        impl = "pallas" if jax.default_backend() == "tpu" else "jnp"
+        impl = "pallas" if on_tpu() else "jnp"
     if impl == "pallas":
         from .pallas.paged_attention import paged_decode_attention_pallas
         return paged_decode_attention_pallas(q, k_pages, v_pages,

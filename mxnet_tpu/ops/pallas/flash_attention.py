@@ -6,7 +6,7 @@ transformer.cc).  On TPU that matrix is the HBM wall at long sequence; these
 kernels compute softmax(QK^T)V blockwise with the online-softmax recurrence
 (SURVEY §7.0.2 names this kernel).
 
-v2 design (round-3: VERDICT weak #6):
+v2 design:
 - K/V are **streamed block-by-block through the grid** — the kernel never
   holds a whole (S, D) K or V in VMEM, so sequence length is bounded by HBM,
   not VMEM.  Grid (B·H, S/bq, S/bk); accumulators (acc, m, l) live in VMEM
@@ -27,6 +27,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...context import on_tpu
+
 _NEG_INF = -1e30
 
 
@@ -44,13 +46,25 @@ def _uniform01(h_idx, q_pos, k_pos, seed):
     x = x ^ (x >> 15)
     x = x * jnp.uint32(0x846CA68B)
     x = x ^ (x >> 16)
-    return (x >> 8).astype(jnp.float32) * (1.0 / 16777216.0)
+    # Mosaic has no uint32 -> float32 cast; the top 24 bits fit an int32
+    # exactly, so reinterpret first (same value, same float)
+    bits = jax.lax.bitcast_convert_type(x >> 8, jnp.int32)
+    return bits.astype(jnp.float32) * (1.0 / 16777216.0)
 
 
 def _positions(bq, bk, qi, kj, block_q, block_k):
     q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
     k_pos = kj * block_k + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
     return q_pos, k_pos
+
+
+def _row_spec(block_q, index_map):
+    """BlockSpec of a per-query-row statistic (lse, delta).  Mosaic wants
+    the last two block dims divisible by (8, 128) or equal to the array's,
+    which a ``(1, block_q)`` block of a ``(bh, s)`` array is not; carried
+    as ``(bh, s // block_q, 1, block_q)`` the block's last two dims ARE
+    the array's, for any block_q."""
+    return pl.BlockSpec((1, 1, 1, block_q), index_map)
 
 
 # ------------------------------------------------------------- forward ------
@@ -101,7 +115,7 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
     def _finish():
         l = l_ref[...]
         o_ref[0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
-        lse_ref[0] = m_ref[...] + jnp.log(l)
+        lse_ref[0, 0, 0] = m_ref[...] + jnp.log(l)
 
 
 def _flash_fwd(q, k, v, seed, scale, causal, block_q, block_k, interpret,
@@ -125,11 +139,12 @@ def _flash_fwd(q, k, v, seed, scale, causal, block_q, block_k, interpret,
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q), lambda b, i, j: (b, i)),
+            _row_spec(block_q, lambda b, i, j: (b, i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
-            jax.ShapeDtypeStruct((bh, s), jnp.float32),
+            jax.ShapeDtypeStruct((bh, s // block_q, 1, block_q),
+                                 jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, d), jnp.float32),
@@ -138,7 +153,7 @@ def _flash_fwd(q, k, v, seed, scale, causal, block_q, block_k, interpret,
         ],
         interpret=interpret,
     )(seed, q, k, v)
-    return out, lse
+    return out, lse.reshape(bh, s)
 
 
 # ------------------------------------------------------------ backward ------
@@ -151,7 +166,8 @@ def _recompute_p(q_ref, k_ref, lse_ref, b, qi, kj, scale, causal,
                               block_q, block_k)
     if causal:
         s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-    p = jnp.exp(s - lse_ref[0][:, None])   # true softmax probs (pre-dropout)
+    # true softmax probs (pre-dropout)
+    p = jnp.exp(s - lse_ref[0, 0, 0][:, None])
     return p, q_pos, k_pos
 
 
@@ -175,7 +191,7 @@ def _dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         if dropout > 0.0:
             keep = _uniform01(b, q_pos, k_pos, seed_ref[0]) >= dropout
             dp = jnp.where(keep, dp, 0.0) * (1.0 / (1.0 - dropout))
-        ds = p * (dp - delta_ref[0][:, None])
+        ds = p * (dp - delta_ref[0, 0, 0][:, None])
         dq_acc[...] += (ds @ k_ref[0].astype(jnp.float32)) * scale
 
     if causal:
@@ -214,7 +230,7 @@ def _dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dp = do @ v.T
         if dropout > 0.0:
             dp = jnp.where(keep, dp, 0.0) * (1.0 / (1.0 - dropout))
-        ds = p * (dp - delta_ref[0][:, None])
+        ds = p * (dp - delta_ref[0, 0, 0][:, None])
         dk_acc[...] += (ds.T @ (q_ref[0].astype(jnp.float32))) * scale
 
     if causal:
@@ -235,6 +251,8 @@ def _flash_bwd(q, k, v, seed, o, lse, do, scale, causal, block_q, block_k,
     block_k = min(block_k, s)
     n_q, n_k = s // block_q, s // block_k
     delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
+    lse = lse.reshape(bh, n_q, 1, block_q)
+    delta = delta.reshape(bh, n_q, 1, block_q)
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
@@ -247,8 +265,8 @@ def _flash_bwd(q, k, v, seed, o, lse, do, scale, causal, block_q, block_k,
             pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q), lambda b, i, j: (b, i)),
-            pl.BlockSpec((1, block_q), lambda b, i, j: (b, i)),
+            _row_spec(block_q, lambda b, i, j: (b, i, 0, 0)),
+            _row_spec(block_q, lambda b, i, j: (b, i, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
@@ -267,8 +285,8 @@ def _flash_bwd(q, k, v, seed, o, lse, do, scale, causal, block_q, block_k,
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
             pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q), lambda b, j, i: (b, i)),
-            pl.BlockSpec((1, block_q), lambda b, j, i: (b, i)),
+            _row_spec(block_q, lambda b, j, i: (b, i, 0, 0)),
+            _row_spec(block_q, lambda b, j, i: (b, i, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
@@ -316,7 +334,7 @@ def _resolve(scale, d, interpret):
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu()
     return scale, interpret
 
 

@@ -40,6 +40,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...context import on_tpu
+
 __all__ = ["norm_relu_conv", "norm_relu_conv_reference", "supports"]
 
 
@@ -74,7 +76,7 @@ def _taps(Xp, h, w_dim, ci, k, stride):
     matmuls sum to the convolution.
 
     Mosaic rejects strided vector slices (`vector.extract_strided_slice`
-    requires unit strides — see TPU_FUSED_COMPILE_r05.md), so for
+    requires unit strides), so for
     stride > 1 the decimation is a contiguous slice + reshape + static
     index, all of which lower to unit-stride ops.  Callers must pad Xp
     with `stride - 1` extra rows/cols (see ``_pad_guard``) so the
@@ -125,11 +127,15 @@ def _fwd_kernel(x_ref, scale_ref, shift_ref, w_ref, *rest, k, stride, relu,
 
 def _pick_block_co(co, want):
     """Largest divisor of co that is <= want (grid tiles must cover co
-    exactly — a non-dividing block would leave tail channels unwritten)."""
+    exactly — a non-dividing block would leave tail channels unwritten)
+    and that Mosaic takes as the lane dim of the w/do/out blocks: a
+    multiple of 128, else all of co.  co=192 at want=128 is one 192-wide
+    tile, not two of 96.  The interpreter tiles by the same rule, so the
+    parity tests run the grid the chip runs."""
     for d in range(min(want, co), 0, -1):
-        if co % d == 0:
+        if co % d == 0 and d % 128 == 0:
             return d
-    return 1
+    return co
 
 
 def _fwd(x, scale, shift, w, res, relu, stride, block_co, interpret):
@@ -430,7 +436,7 @@ def norm_relu_conv(x, scale, shift, w, residual=None, relu=True, stride=1,
         raise ValueError(f"fused kernel supports 1x1/3x3 stride 1/2; got "
                          f"{w.shape[:2]} stride {stride}")
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu()
     if residual is None:
         return _core(x, scale, shift, w, relu, stride, block_co, interpret)
     return _core_res(x, scale, shift, w, residual, relu, stride, block_co,

@@ -25,6 +25,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...context import on_tpu
+
 _NEG = -1e30
 
 
@@ -92,7 +94,7 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, page_tables,
     (same argument contract).  ``interpret=None`` auto-selects the
     Pallas interpreter off-TPU so parity tests run anywhere."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu()
     n_pages, page_size, heads, head_dim = k_pages.shape
     slots, pages_per_seq = page_tables.shape
     kernel = functools.partial(_kernel, page_size=page_size,

@@ -28,30 +28,6 @@ __all__ = ["AXES", "make_mesh", "current_mesh", "default_mesh", "MeshScope",
            "replicated", "named_sharding", "shard_map", "validate_specs"]
 
 
-def _compat_shard_map():
-    """jax.shard_map across versions: older jax exposes it only under
-    jax.experimental with the replication-check kwarg named ``check_rep``
-    (renamed ``check_vma`` when promoted to the top level)."""
-    try:
-        from jax import shard_map as sm
-        return sm
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _sm
-        import functools
-
-        @functools.wraps(_sm)
-        def sm(f=None, **kw):
-            if "check_vma" in kw:
-                kw["check_rep"] = kw.pop("check_vma")
-            if f is None:
-                return lambda g: _sm(g, **kw)
-            return _sm(f, **kw)
-        return sm
-
-
-_jax_shard_map = _compat_shard_map()
-
-
 def _spec_axis_names(specs):
     """Every axis name appearing in a specs pytree (PartitionSpec
     leaves; entries may be names or tuples of names)."""
@@ -90,8 +66,8 @@ def shard_map(f=None, *, mesh=None, in_specs=None, out_specs=None, **kw):
     """``jax.shard_map`` with call-time axis validation: every axis
     named in ``in_specs``/``out_specs`` must exist in
     ``mesh.axis_names`` (``validate_specs``).  Currying (``f=None``)
-    and the ``check_vma``/``check_rep`` compat of older jax are
-    preserved."""
+    is preserved; every other keyword (``check_vma``, ...) passes
+    through."""
     if mesh is not None:
         validate_specs(mesh, in_specs, out_specs)
     inner = {}
@@ -103,8 +79,8 @@ def shard_map(f=None, *, mesh=None, in_specs=None, out_specs=None, **kw):
         inner["out_specs"] = out_specs
     inner.update(kw)
     if f is None:
-        return lambda g: _jax_shard_map(g, **inner)
-    return _jax_shard_map(f, **inner)
+        return lambda g: jax.shard_map(g, **inner)
+    return jax.shard_map(f, **inner)
 
 # Canonical axis order: collectives that ride adjacent devices (tp, sp) go
 # last so they land on the fastest ICI neighbours in the device enumeration.

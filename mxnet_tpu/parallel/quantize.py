@@ -168,7 +168,15 @@ def all_reduce_activations(x, axis_name, n_dev, mode="int8", key=None,
     device dequantizes the same way — the replication an out_spec may
     honestly claim).
 
-    ``mode``: ``"f32"`` = plain ``psum`` (uncompressed reference),
+    ``mode``: ``"f32"`` = uncompressed: ``all_gather`` the partials and
+    add them in device order, the same order for every element.  Not
+    ``psum``: on the v5e 2x2 the all-reduce adds the four partials in an
+    order that depends on where the element sits in the buffer and on
+    the buffer's size (measured, PERF.md PR 21), so one sequence got
+    different last bits in another slot or prefill bucket, a bf16 matmul
+    pass turned those into different logits, and sampled and greedy
+    tokens parted.  The gather moves ``n_dev - 1`` buffers to each
+    device where a ring all-reduce moves ``2 (n_dev - 1) / n_dev``.
     ``"int8"`` = the two-phase chunked exchange (``all_to_all`` int8
     slices → dequant+sum the owned slice → requantize → ``all_gather``)
     at ~1/4 the f32 wire bytes.  ``key=None`` (the serving default)
@@ -181,7 +189,11 @@ def all_reduce_activations(x, axis_name, n_dev, mode="int8", key=None,
         raise ValueError(f"all_reduce_activations: mode {mode!r} not in "
                          f"{ACTIVATION_REDUCE_MODES}")
     if mode == "f32":
-        return lax.psum(x, axis_name)
+        parts = lax.all_gather(x, axis_name)            # (n_dev, ...)
+        out = parts[0]
+        for i in range(1, n_dev):
+            out = out + parts[i]
+        return out
     return _reduce_leaf_int8(x, axis_name, n_dev, key, chunk, mean=False)
 
 

@@ -417,13 +417,15 @@ def build_prefill_step(config, page_size, attention_impl=None, mesh=None,
 
     With ``mesh`` the forward is Megatron-sharded like the decode step
     and each device scatters its OWN head shard of the prompt K/V into
-    its pool shard.  Prefill collectives stay f32: the prompt forward
-    is compute-bound, not latency-bound on collective bytes (the
-    ``tp_collectives`` knob is a decode-path trade)."""
-    import jax
+    its pool shard.  Prefill collectives stay f32 (the order-fixed sum
+    of ``all_reduce_activations``, so a prompt's K/V bits do not depend
+    on its bucket or row): the prompt forward is compute-bound, not
+    latency-bound on collective bytes (the ``tp_collectives`` knob is a
+    decode-path trade)."""
     import jax.numpy as jnp
 
     from ..gluon.model_zoo.causal_lm import prefill_forward
+    from ..parallel.quantize import all_reduce_activations
 
     del attention_impl      # prefill is dense-causal (ops.multi_head_attention)
 
@@ -434,7 +436,7 @@ def build_prefill_step(config, page_size, attention_impl=None, mesh=None,
             config, mesh, tp_axis)
 
         def reduce_fn(x):
-            return jax.lax.psum(x, tp_axis)
+            return all_reduce_activations(x, tp_axis, shards, mode="f32")
 
     def prefill_step(params, k_pool, v_pool, tokens, lengths, active,
                      tables, seeds, temps, topks):
@@ -478,10 +480,10 @@ def build_prefill_kv_step(config, attention_impl=None, mesh=None,
     ``build_prefill_step``) and the payload comes back with its head
     axis sharded over ``tp_axis`` — the wire shape the sharded handoff
     scatter consumes."""
-    import jax
     import jax.numpy as jnp
 
     from ..gluon.model_zoo.causal_lm import prefill_forward
+    from ..parallel.quantize import all_reduce_activations
 
     del attention_impl      # prefill is dense-causal (ops.multi_head_attention)
 
@@ -492,7 +494,7 @@ def build_prefill_kv_step(config, attention_impl=None, mesh=None,
             config, mesh, tp_axis)
 
         def reduce_fn(x):
-            return jax.lax.psum(x, tp_axis)
+            return all_reduce_activations(x, tp_axis, shards, mode="f32")
 
     def prefill_kv_step(params, tokens, lengths, seeds, temps, topks):
         logits, k_all, v_all = prefill_forward(params, config, tokens,
@@ -1303,6 +1305,32 @@ class GenerationServer:
         if self._verify is not None:
             n += self._verify._cache_size()
         return n
+
+    def lower_decode(self):
+        """THE decode program, lowered (not compiled) at the signature
+        the loop dispatches: this server's params and slot grid, and its
+        pools' shape, dtype and sharding.  What a check of the served
+        program reads — kernel custom-calls, cost, structure — instead
+        of rebuilding ``build_decode_step`` by hand.  Needs ``start()``
+        (the pools exist from there on); adds nothing to
+        ``jit_cache_count()``."""
+        import jax
+
+        # avals, not the arrays: the loop owns them (it donates the pools
+        # and rewrites the slot grid every step) — only their shape,
+        # dtype and placement enter the lowering
+        with self._admit_lock:
+            if self._k_pool is None:
+                raise RuntimeError(
+                    f"{self._name}: lower_decode() before start() — the "
+                    f"pools do not exist yet")
+            avals = [jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=getattr(a, "sharding", None))
+                for a in (self._k_pool, self._v_pool, self._tokens,
+                          self._lengths, self._active, self._tables,
+                          self._cow_src, self._cow_dst, self._seeds,
+                          self._temps, self._topks)]
+        return self._decode.lower(self._params, *avals)
 
     # ------------------------------------------------------------ admission --
     def submit(self, tokens, *, max_new_tokens=None, temperature=0.0,

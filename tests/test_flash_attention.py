@@ -12,6 +12,17 @@ from mxnet_tpu.ndarray import array as nd
 from mxnet_tpu.test_utils import assert_almost_equal
 
 
+def _tol(rtol, atol, tpu_atol):
+    """Off the TPU the kernel and its reference agree to f32 rounding.  On
+    it both run the MXU's default single bf16 pass on these f32 inputs and
+    round the softmax differently; ``tpu_atol`` is a few times the largest
+    difference measured on the chip at that precision (PR 21), far below
+    what a wrong mask or block would give."""
+    if mx.context.on_tpu():
+        return {"rtol": 2e-2, "atol": tpu_atol}
+    return {"rtol": rtol, "atol": atol}
+
+
 def _qkv(b, s, c, seed=0):
     rng = np.random.RandomState(seed)
     return [rng.randn(b, s, c).astype(np.float32) * 0.5 for _ in range(3)]
@@ -25,7 +36,8 @@ def test_flash_matches_dense(causal):
                    causal=causal).asnumpy()
     flash = invoke("flash_attention", nd(q), nd(k), nd(v), heads=heads,
                    causal=causal, block_q=16, block_k=16).asnumpy()
-    assert_almost_equal(flash, dense, rtol=2e-4, atol=2e-5)
+    # on the chip: 5.8e-4 (full), 2.2e-3 (causal)
+    assert_almost_equal(flash, dense, **_tol(2e-4, 2e-5, 1e-2))
 
 
 def test_flash_gradients_match_dense():
@@ -47,7 +59,7 @@ def test_flash_gradients_match_dense():
         grads[impl] = [a.grad.asnumpy() for a in nds]
     for gd, gf in zip(grads["multi_head_attention"],
                       grads["flash_attention"]):
-        assert_almost_equal(gf, gd, rtol=1e-3, atol=1e-4)
+        assert_almost_equal(gf, gd, **_tol(1e-3, 1e-4, 5e-3))  # chip: 4.7e-4
 
 
 def test_flash_bf16():
@@ -218,7 +230,7 @@ def test_flash_seq8k_streams_kv():
     ref = np.einsum("bqk,bkd->bqd", p, np.asarray(v[:, :128]))
     got = np.asarray(flash_attention(q, k, v, causal=True, block_q=512,
                                      block_k=512))[:, :128]
-    np.testing.assert_allclose(got, ref, rtol=2e-3, atol=2e-3)
+    np.testing.assert_allclose(got, ref, **_tol(2e-3, 2e-3, 2e-2))  # chip: 6.3e-3
 
 
 def test_bert_flash_dropout_trains():

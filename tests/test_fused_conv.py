@@ -22,11 +22,14 @@ from mxnet_tpu.ops.pallas.fused_conv import (norm_relu_conv,
                                              norm_relu_conv_reference)
 
 
-@pytest.mark.parametrize("k,res_on,relu", [(3, False, True), (1, False, True),
-                                           (3, True, True), (3, True, False)])
-def test_kernel_parity(k, res_on, relu):
+# co=256 is two 128-wide channel tiles (a tile is a multiple of 128 or all
+# of co), so the tiled grid is compared too, here and compiled on the chip
+@pytest.mark.parametrize("k,res_on,relu,co", [
+    (3, False, True, 16), (1, False, True, 16), (3, True, True, 16),
+    (3, True, False, 16), (3, True, True, 256)])
+def test_kernel_parity(k, res_on, relu, co):
     rng = np.random.RandomState(0)
-    n, h, w_, ci, co = 2, 8, 8, 8, 16
+    n, h, w_, ci = 2, 8, 8, 8
     x = jnp.asarray(rng.randn(n, h, w_, ci).astype(np.float32))
     sc = jnp.asarray(rng.rand(ci).astype(np.float32) + 0.5)
     sh = jnp.asarray(rng.randn(ci).astype(np.float32) * 0.1)
@@ -34,7 +37,7 @@ def test_kernel_parity(k, res_on, relu):
     res = jnp.asarray(rng.randn(n, h, w_, ci).astype(np.float32)) \
         if res_on else None
 
-    of = norm_relu_conv(x, sc, sh, w, residual=res, relu=relu, block_co=8)
+    of = norm_relu_conv(x, sc, sh, w, residual=res, relu=relu)
     orf = norm_relu_conv_reference(x, sc, sh, w, residual=res, relu=relu)
     np.testing.assert_allclose(np.asarray(of), np.asarray(orf),
                                rtol=2e-4, atol=2e-4)
@@ -42,7 +45,7 @@ def test_kernel_parity(k, res_on, relu):
     argnums = (0, 1, 2, 3) + ((4,) if res_on else ())
 
     def loss_f(x, sc, sh, w, res=None):
-        o = norm_relu_conv(x, sc, sh, w, residual=res, relu=relu, block_co=8)
+        o = norm_relu_conv(x, sc, sh, w, residual=res, relu=relu)
         return (o.astype(jnp.float32) ** 2).sum()
 
     def loss_r(x, sc, sh, w, res=None):
@@ -220,21 +223,22 @@ def test_non_power_of_two_channels():
                                rtol=2e-3, atol=2e-3)
 
 
-@pytest.mark.parametrize("k,stride,h", [(3, 2, 8), (3, 2, 9), (1, 2, 8)])
-def test_kernel_stride2_parity(k, stride, h):
+@pytest.mark.parametrize("k,stride,h,co", [(3, 2, 8, 16), (3, 2, 9, 16),
+                                           (1, 2, 8, 16), (3, 2, 8, 256)])
+def test_kernel_stride2_parity(k, stride, h, co):
     """Stride-2 (the resnet downsample 3x3s): fwd + all grads match the
-    XLA composition, incl. odd spatial extents."""
+    XLA composition, incl. odd spatial extents and two channel tiles."""
     rng = np.random.RandomState(7)
-    n, ci, co = 2, 8, 16
+    n, ci = 2, 8
     x = jnp.asarray(rng.randn(n, h, h, ci).astype(np.float32))
     sc = jnp.asarray(rng.rand(ci).astype(np.float32) + 0.5)
     sh = jnp.asarray(rng.randn(ci).astype(np.float32) * 0.1)
     w = jnp.asarray(rng.randn(k, k, ci, co).astype(np.float32) * 0.2)
-    of = norm_relu_conv(x, sc, sh, w, stride=stride, block_co=8)
+    of = norm_relu_conv(x, sc, sh, w, stride=stride)
     orf = norm_relu_conv_reference(x, sc, sh, w, stride=stride)
     np.testing.assert_allclose(np.asarray(of), np.asarray(orf),
                                rtol=2e-4, atol=2e-4)
-    gf = jax.grad(lambda *a: (norm_relu_conv(*a, stride=stride, block_co=8)
+    gf = jax.grad(lambda *a: (norm_relu_conv(*a, stride=stride)
                               .astype(jnp.float32) ** 2).sum(),
                   argnums=(0, 1, 2, 3))(x, sc, sh, w)
     gr = jax.grad(lambda *a: (norm_relu_conv_reference(*a, stride=stride)

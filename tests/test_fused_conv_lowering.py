@@ -4,17 +4,14 @@ interpreter sweeps so every default run still catches Mosaic regressions."""
 import numpy as np
 
 import jax
-# older jax does not auto-import the export submodule: the bare
-# `jax.export` attribute raises until this import runs (see gluon/block.py)
-from jax import export as _jax_export  # noqa: F401
 import jax.numpy as jnp
 
 
 def test_mosaic_tpu_lowering_all_variants():
     """Lower every (k, stride, residual) variant fwd+bwd for the REAL TPU
     platform via jax.export — the same client-side Mosaic path that
-    rejected the round-4 kernels (TPU_FUSED_COMPILE_r05.md: strided
-    vector slices; output block-shape rule).  Interpreter-mode parity
+    rejected the round-4 kernels (strided vector slices; output
+    block-shape rule).  Interpreter-mode parity
     cannot catch these; this test runs on CPU and needs no hardware."""
     import mxnet_tpu.ops.pallas.fused_conv as fc
 
@@ -47,8 +44,9 @@ def test_mosaic_tpu_lowering_all_variants():
 
 def test_kernel_parity_smoke():
     """Fast default-tier parity guard over the changed kernel paths (one
-    stride-1 and one stride-2 case, fwd + input grad, interpreter mode);
-    the exhaustive sweeps live in the slow tier (test_fused_conv.py)."""
+    stride-1 and one stride-2 case, fwd + input grad, interpreter mode,
+    co=256 so the grid has two channel tiles); the exhaustive sweeps live
+    in the slow tier (test_fused_conv.py)."""
     from mxnet_tpu.ops.pallas.fused_conv import (norm_relu_conv,
                                                  norm_relu_conv_reference)
     rng = np.random.RandomState(0)
@@ -56,14 +54,14 @@ def test_kernel_parity_smoke():
         x = jnp.asarray(rng.randn(2, 8, 8, 8).astype(np.float32))
         sc = jnp.asarray(rng.rand(8).astype(np.float32) + 0.5)
         sh = jnp.asarray(rng.randn(8).astype(np.float32) * 0.1)
-        w = jnp.asarray(rng.randn(3, 3, 8, 16).astype(np.float32) * 0.2)
-        out = norm_relu_conv(x, sc, sh, w, stride=stride, block_co=8)
+        w = jnp.asarray(rng.randn(3, 3, 8, 256).astype(np.float32) * 0.2)
+        out = norm_relu_conv(x, sc, sh, w, stride=stride)
         ref = norm_relu_conv_reference(x, sc, sh, w, stride=stride)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-4, atol=2e-4)
 
         def loss_f(x):
-            o = norm_relu_conv(x, sc, sh, w, stride=stride, block_co=8)
+            o = norm_relu_conv(x, sc, sh, w, stride=stride)
             return (o.astype(jnp.float32) ** 2).sum()
 
         def loss_r(x):

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from mxnet_tpu import fault, profiler
+from mxnet_tpu.context import on_tpu
 from mxnet_tpu.gluon.model_zoo.causal_lm import (CausalLMConfig,
                                                  init_causal_lm,
                                                  prefill_forward)
@@ -158,7 +159,11 @@ def test_paged_attention_pallas_interpret_parity():
     out = np.asarray(paged_decode_attention_pallas(
         jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
         jnp.asarray(tables), jnp.asarray(lengths)))
-    np.testing.assert_allclose(out[:2], ref[:2], rtol=1e-5, atol=1e-5)
+    # on the TPU both sides run the MXU's default single bf16 pass on these
+    # f32 inputs: 3.2e-3 apart measured on the chip (PR 21); a wrong page or
+    # mask is an O(1) error
+    tol = 2e-2 if on_tpu() else 1e-5
+    np.testing.assert_allclose(out[:2], ref[:2], rtol=tol, atol=tol)
     assert np.all(out[2] == 0.0)               # length-0 slot never ran a page
 
 
@@ -571,6 +576,14 @@ def test_handoff_fault_fails_group_explicitly_spares_bystanders():
         assert srv.alloc.free_count() == srv.alloc.allocatable
 
 
+def needs_devices(n):
+    """Tier-1 has 8 virtual CPU devices; the on-chip re-run has as many
+    as the host has chips."""
+    return pytest.mark.skipif(
+        jax.device_count() < n,
+        reason=f"needs {n} devices, jax sees {jax.device_count()}")
+
+
 # ======================= ISSUE 14: tensor-parallel sharded decode --
 # CFG has 2 heads (tp=2-divisible); the 8-way acceptance needs a head
 # per shard — same d_model, 8 x 4 heads
@@ -581,6 +594,7 @@ TP8_LOUD = {k: v * 8.0 if k in ("embed", "wqkv", "wo", "w1", "w2") else v
             for k, v in TP8_PARAMS.items()}
 
 
+@needs_devices(2)
 def test_tp_sharded_decode_token_exact_parity():
     """ISSUE 14: sharding is a lowering property, not a math change —
     the tp=2 server (head-sharded pools, Megatron weights, f32
@@ -611,6 +625,7 @@ def test_tp_sharded_decode_token_exact_parity():
     assert tp.alloc.free_count() == tp.alloc.allocatable
 
 
+@needs_devices(2)
 def test_tp_int8_collectives_bounded_divergence():
     """``tp_collectives="int8"`` trades exactness for wire bytes on
     the decode path ONLY: the first token (prefill — f32 collectives)
@@ -640,6 +655,7 @@ def test_tp_int8_collectives_bounded_divergence():
         np.testing.assert_array_equal(g, g2)   # deterministic
 
 
+@needs_devices(8)
 def test_tp8_census_matches_runtime_jit_cache_on_real_mesh():
     """The ISSUE 14 acceptance: a tp=8 GenerationServer on the real
     8-device mesh — mixed-length, mixed-sampling traffic replay —
@@ -675,6 +691,7 @@ def test_tp8_census_matches_runtime_jit_cache_on_real_mesh():
 
 
 @slo
+@needs_devices(2)
 def test_tp_disaggregated_handoff_sharded():
     """Disaggregation composes with sharding: a tp=2 server with a
     prefill worker group (pool-free sharded prefill → head-sharded

@@ -1951,7 +1951,7 @@ def test_spmd_replication_claim_int8_path(tmp_path):
     ``reduce_gradients`` (every device dequantizes identical all_gather
     payloads) honestly claims replication — CLEAN; strip the gathers
     (return the per-device partial) and the same claim is unsound —
-    FLAGGED.  The statically checkable core of check_rep."""
+    FLAGGED.  The statically checkable core of check_vma."""
     assert not fired(lint(tmp_path, _SPMD_INT8_PATH),
                      "spmd-replication-claim")
     msgs = fired(lint(tmp_path, _SPMD_INT8_MUTATED, name="mutated.py"),

@@ -164,7 +164,10 @@ def test_reduce_gradients_matches_true_mean_under_shard_map():
 
 def test_all_reduce_activations_modes_and_bound():
     """The serving activation all-reduce (ISSUE 14, the tp_collectives
-    wire): f32 == psum exactly; int8 stays within the chunk
+    wire): f32 is the partials added in device order, bit for bit — the
+    same order for every element, which psum does not promise (on the
+    v5e 2x2 it varies with the element's place in the buffer; PERF.md
+    PR 21); int8 stays within the chunk
     quantization bound of the true sum (two quantization stages, each
     |err| <= scale/2 = amax/254 per stage per addend, summed over
     devices); both are bit-identical across devices (taken on faith by
@@ -183,10 +186,12 @@ def test_all_reduce_activations_modes_and_bound():
         return np.asarray(f(x))          # per-device outputs, stacked
 
     true = x.sum(axis=0)
+    in_order = x[0]
+    for d in range(1, 8):
+        in_order = in_order + x[d]
     got_f32 = run("f32")
     for d in range(8):                   # replicated: every device equal
-        np.testing.assert_allclose(got_f32[d], true, rtol=1e-5,
-                                   atol=1e-5)
+        np.testing.assert_array_equal(got_f32[d], in_order)
     got_q8 = run("int8")
     for d in range(1, 8):
         np.testing.assert_array_equal(got_q8[0], got_q8[d])

@@ -142,7 +142,9 @@ def test_sample_multinomial():
     assert dv.shape == (2, 4) and lpv.shape == (2, 4)
     np.testing.assert_allclose(
         lpv, np.log(np.maximum(probs.asnumpy(), 1e-30))[
-            np.arange(2)[:, None], dv.astype(int)], rtol=1e-5)
+            np.arange(2)[:, None], dv.astype(int)],
+        # the TPU's log is within 8e-5 of libm's (measured on the chip)
+        rtol=2e-4 if mx.context.on_tpu() else 1e-5)
     # the module-style wrapper is the same implementation
     mx.random.seed(11)
     m1 = nd.random.multinomial(probs, shape=6).asnumpy()

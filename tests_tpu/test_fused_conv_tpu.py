@@ -5,8 +5,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-if jax.default_backend() == "cpu":
-    pytest.skip("TPU re-run suite needs an accelerator backend",
+from mxnet_tpu.context import on_tpu
+
+if not on_tpu():
+    pytest.skip("TPU re-run suite needs the TPU backend",
                 allow_module_level=True)
 
 from test_fused_conv import *        # noqa: F401,F403,E402
@@ -32,7 +34,7 @@ def test_fused_conv_compile_only(k, stride, residual):
     """Lower + compile each fused variant on real Mosaic WITHOUT running it.
 
     Distinguishes 'Mosaic rejects the kernel' (this fails) from 'numerics
-    drift on-chip' (the imported parity suite fails) — VERDICT r4 weak #2.
+    drift on-chip' (the imported parity suite fails).
     Covers the forward kernel alone and the full fwd+bwd pair, since the
     two backward kernels (_dx, _dw) are separate Mosaic programs.
     """
@@ -42,12 +44,18 @@ def test_fused_conv_compile_only(k, stride, residual):
         return fc.norm_relu_conv(x, scale, shift, w, residual=res,
                                  stride=stride, interpret=False)
 
-    jax.jit(fwd).lower(x, scale, shift, w, res).compile()
+    fwd_low = jax.jit(fwd).lower(x, scale, shift, w, res)
+    # a Mosaic program, not the interpreter's expansion
+    assert fwd_low.as_text().count("tpu_custom_call") == 1
+    fwd_low.compile()
 
     def loss(x, scale, shift, w, res):
         return fc.norm_relu_conv(x, scale, shift, w, residual=res,
                                  stride=stride,
                                  interpret=False).astype(jnp.float32).sum()
 
-    grads = jax.grad(loss, argnums=(0, 1, 2, 3))
-    jax.jit(grads).lower(x, scale, shift, w, res).compile()
+    grads = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))) \
+        .lower(x, scale, shift, w, res)
+    # the dx and dw kernels (a sum loss leaves the forward kernel dead)
+    assert grads.as_text().count("tpu_custom_call") == 2
+    grads.compile()

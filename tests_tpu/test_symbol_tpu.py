@@ -3,11 +3,12 @@ real TPU (ref: tests/python/gpu — the GPU re-run trick; see
 test_operator_tpu.py for the mechanism).  The symbolic executor is a
 jit-traced DAG, so this is the on-chip proof that bind/forward/backward
 and Module.fit compile and run on hardware, not just XLA:CPU."""
-import jax
 import pytest
 
-if jax.default_backend() == "cpu":
-    pytest.skip("TPU re-run suite needs an accelerator backend",
+from mxnet_tpu.context import on_tpu
+
+if not on_tpu():
+    pytest.skip("TPU re-run suite needs the TPU backend",
                 allow_module_level=True)
 
 from test_symbol import *            # noqa: F401,F403,E402

@@ -29,7 +29,7 @@ sharded decode.  These rules make that discipline mechanical:
                             output with no ``psum``/``pmean``/
                             ``all_gather`` producer on its dataflow path
                             — the statically checkable core of
-                            ``check_rep``
+                            ``check_vma``
 ``spmd-collective-in-loop`` collectives issued inside Python
                             ``for``/``while`` bodies (one collective per
                             unrolled iteration instead of one fused /
@@ -757,7 +757,7 @@ class SpmdReplicationClaimRule(Rule):
                     f"derives from per-device inputs with no psum/"
                     f"pmean/all_gather on the dataflow path — the "
                     f"claim is unsound: devices hold DIFFERENT values "
-                    f"and jax will either reject it (check_rep) or "
+                    f"and jax will either reject it (check_vma) or "
                     f"silently serve one shard's answer; reduce before "
                     f"claiming replication, or shard the output spec")
 

@@ -76,9 +76,6 @@ def _build_entrypoint(name: str) -> Surface:
 
 def _export_tpu(fn, *avals) -> str:
     import jax
-    # older jax does not auto-import the export submodule (see
-    # gluon/block.py): the bare attribute raises until this runs
-    from jax import export as _jax_export  # noqa: F401
     return jax.export.export(jax.jit(fn),
                              platforms=["tpu"])(*avals).mlir_module()
 
