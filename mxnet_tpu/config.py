@@ -134,9 +134,9 @@ def setup_compile_cache():
     hits.  Every executable is kept (0 s floor): a generation server is
     a handful of programs of a few seconds each and a model's eager
     deferred-init pass a few hundred sub-second ones, and a warm start
-    should recompile none of them.  Entry points call this before their
-    first compile (``chip_smoke.py``, ``bench.py``, ``tests_tpu/``, the
-    examples)."""
+    should recompile none of them.  Also registers ``watch_compiles()``.
+    Entry points call this before their first compile (``chip_smoke.py``,
+    ``bench.py``, ``tests_tpu/``, the examples)."""
     import jax
 
     cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
@@ -146,7 +146,27 @@ def setup_compile_cache():
             ".jax_compile_cache")
         jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    watch_compiles()
     return cache_dir
+
+
+_WATCHING = False
+
+
+def watch_compiles():
+    """Feed ``telemetry.compile_stats()``'s always-on counters from jax's
+    own monitoring events (executables compiled or loaded, persistent-cache
+    hits, misses and seconds saved).  Registered once per process; the
+    listeners run only when jax compiles or loads."""
+    global _WATCHING
+    if _WATCHING:
+        return
+    _WATCHING = True
+    from jax import monitoring
+
+    from . import telemetry
+    monitoring.register_event_listener(telemetry.note_jax_event)
+    monitoring.register_event_duration_secs_listener(telemetry.note_jax_event)
 
 
 def _apply_startup():

@@ -116,17 +116,18 @@ def _layer_tail(params, layer, h, ctx, reduce):
     products, with the row-parallel biases (``bo``, ``b2``) added once
     AFTER it, never per shard.  One body — the TP token-parity
     contract cannot diverge between prefill and decode."""
-    if reduce is None:
-        h = h + ctx @ params["wo"][layer] + params["bo"][layer]
-        return h + _ffn(_ln(h, params["ln2_s"][layer],
-                            params["ln2_b"][layer]),
-                        params["w1"][layer], params["b1"][layer],
-                        params["w2"][layer], params["b2"][layer])
-    h = h + reduce(ctx @ params["wo"][layer]) + params["bo"][layer]
-    x2 = _ln(h, params["ln2_s"][layer], params["ln2_b"][layer])
-    return h + reduce(jax.nn.gelu(x2 @ params["w1"][layer]
-                                  + params["b1"][layer])
-                      @ params["w2"][layer]) + params["b2"][layer]
+    with jax.named_scope("attention"):
+        proj = ctx @ params["wo"][layer]
+        h = h + (proj if reduce is None else reduce(proj)) \
+            + params["bo"][layer]
+    with jax.named_scope("mlp"):
+        x2 = _ln(h, params["ln2_s"][layer], params["ln2_b"][layer])
+        if reduce is None:
+            return h + _ffn(x2, params["w1"][layer], params["b1"][layer],
+                            params["w2"][layer], params["b2"][layer])
+        return h + reduce(jax.nn.gelu(x2 @ params["w1"][layer]
+                                      + params["b1"][layer])
+                          @ params["w2"][layer]) + params["b2"][layer]
 
 
 def lm_logits(params, h):
@@ -150,12 +151,16 @@ def decode_hidden(params, layer, h, attend, reduce=None):
     docstring): ``None`` keeps the exact single-chip expression order;
     a callable reduces the two row-parallel partial products, with the
     row-parallel biases added once after it."""
-    x = _ln(h, params["ln1_s"][layer], params["ln1_b"][layer])
-    qkv = x @ params["wqkv"][layer] + params["bqkv"][layer]
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    slots = h.shape[0]
-    ctx = attend(q, k, v)                   # [slots, H_local, D] resolved
-    return _layer_tail(params, layer, h, ctx.reshape(slots, -1), reduce)
+    # device-side names (``layer<i>/attention``, ``.../mlp``; the caller's
+    # ``attend`` adds ``.../kv_write``): a profile reads them per layer
+    with jax.named_scope(f"layer{layer}"):
+        with jax.named_scope("attention"):
+            x = _ln(h, params["ln1_s"][layer], params["ln1_b"][layer])
+            qkv = x @ params["wqkv"][layer] + params["bqkv"][layer]
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+        slots = h.shape[0]
+        ctx = attend(q, k, v)               # [slots, H_local, D] resolved
+        return _layer_tail(params, layer, h, ctx.reshape(slots, -1), reduce)
 
 
 def _stack_forward(params, config: CausalLMConfig, tokens, lengths,
@@ -179,14 +184,16 @@ def _stack_forward(params, config: CausalLMConfig, tokens, lengths,
                 < lengths[:, None]).astype(jnp.float32)[:, None, None, :]
     ks, vs = [], []
     for layer in range(c.n_layers):
-        x = _ln(h, params["ln1_s"][layer], params["ln1_b"][layer])
-        qkv = x @ params["wqkv"][layer] + params["bqkv"][layer]
-        q, k, v = jnp.split(qkv, 3, axis=-1)      # each [b, L, d_local]
-        ks.append(k.reshape(b, L, heads, c.head_dim))
-        vs.append(v.reshape(b, L, heads, c.head_dim))
-        ctx = _mha(q, k, v, mask=mask, heads=heads, causal=True,
-                   dropout=0.0, training=False)
-        h = _layer_tail(params, layer, h, ctx, reduce)
+        with jax.named_scope(f"layer{layer}"):
+            with jax.named_scope("attention"):
+                x = _ln(h, params["ln1_s"][layer], params["ln1_b"][layer])
+                qkv = x @ params["wqkv"][layer] + params["bqkv"][layer]
+                q, k, v = jnp.split(qkv, 3, axis=-1)  # each [b, L, d_local]
+                ks.append(k.reshape(b, L, heads, c.head_dim))
+                vs.append(v.reshape(b, L, heads, c.head_dim))
+                ctx = _mha(q, k, v, mask=mask, heads=heads, causal=True,
+                           dropout=0.0, training=False)
+            h = _layer_tail(params, layer, h, ctx, reduce)
     return h, jnp.stack(ks), jnp.stack(vs)
 
 

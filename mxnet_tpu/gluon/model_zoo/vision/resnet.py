@@ -7,6 +7,8 @@ bf16 via net.cast('bfloat16') (AMP).
 """
 from __future__ import annotations
 
+import jax
+
 from ...block import HybridBlock
 from ... import nn
 
@@ -197,6 +199,21 @@ class BottleneckV2(HybridBlock):
         return x + residual
 
 
+class _Stage(nn.HybridSequential):
+    """One stage of residual blocks, its ops named ``stage<i>/...`` in the
+    compiled program (``jax.named_scope``), so a device profile can say
+    which stage a fusion belongs to.  Parameter names are the prefix's, as
+    for the plain ``HybridSequential`` this stands in for."""
+
+    def __init__(self, prefix, params=None):
+        super().__init__(prefix=prefix, params=params)
+        self._scope = prefix.rstrip("_").rsplit("_", 1)[-1]
+
+    def forward(self, x, *args):
+        with jax.named_scope(self._scope):
+            return super().forward(x, *args)
+
+
 class ResNetV1(HybridBlock):
     """ref: class ResNetV1."""
 
@@ -227,7 +244,7 @@ class ResNetV1(HybridBlock):
     def _make_layer(self, block, layers, channels, stride, stage_index,
                     in_channels=0, layout="NCHW", fused=False):
         kw = {"fused": fused} if fused else {}
-        layer = nn.HybridSequential(prefix=f"stage{stage_index}_")
+        layer = _Stage(prefix=f"stage{stage_index}_")
         with layer.name_scope():
             layer.add(block(channels, stride, channels != in_channels,
                             in_channels=in_channels, layout=layout,

@@ -151,6 +151,7 @@ def _flash_fwd(q, k, v, seed, scale, causal, block_q, block_k, interpret,
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q,), jnp.float32),
         ],
+        name="flash_attention_fwd",
         interpret=interpret,
     )(seed, q, k, v)
     return out, lse.reshape(bh, s)
@@ -271,6 +272,7 @@ def _flash_bwd(q, k, v, seed, o, lse, do, scale, causal, block_q, block_k,
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        name="flash_attention_bwd_dq",
         interpret=interpret,
     )(seed, q, k, v, do, lse, delta)
 
@@ -298,6 +300,7 @@ def _flash_bwd(q, k, v, seed, o, lse, do, scale, causal, block_q, block_k,
         ],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
+        name="flash_attention_bwd_dkv",
         interpret=interpret,
     )(seed, q, k, v, do, lse, delta)
     return dq, dk, dv

@@ -162,6 +162,7 @@ def _fwd(x, scale, shift, w, res, relu, stride, block_co, interpret):
         out_specs=pl.BlockSpec((1, ho, wo, block_co),
                                lambda nb, cb: (nb, 0, 0, cb)),
         out_shape=jax.ShapeDtypeStruct((n, ho, wo, co), x.dtype),
+        name="fused_conv_fwd",
         interpret=interpret,
     )(*inputs)
 
@@ -272,6 +273,7 @@ def _dx(x, scale, shift, w, res, do, relu, stride, interpret):
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
+        name="fused_conv_bwd_dx",
         interpret=interpret,
     )(*inputs)
     if has_res:
@@ -345,6 +347,7 @@ def _dw(x, scale, shift, res, do, k, co, relu, stride, block_co,
                                lambda cb, nb: (0, 0, 0, cb)),
         out_shape=jax.ShapeDtypeStruct((k, k, ci, co), jnp.float32),
         scratch_shapes=[pltpu.VMEM((k, k, ci, block_co), jnp.float32)],
+        name="fused_conv_bwd_dw",
         interpret=interpret,
     )(*inputs)
 

@@ -123,5 +123,6 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, page_tables,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((slots, heads, head_dim), q.dtype),
+        name="paged_attention",
         interpret=interpret,
     )(page_tables, lengths, q, k_pages, v_pages)

@@ -30,18 +30,24 @@ every numpy / jax.Array / NDArray leaf is placed, everything else passes
 through untouched.  Without ``step``/``sharding`` the leaves go to the
 default device (the gluon DataLoader ``pin_memory`` path).
 
-Observability mirrors ``mx.io.PrefetchingIter``: ``stats`` carries
-``produced``/``consumed``, live ``queue_depth``, and the wait split —
-``producer_wait_s`` (placement blocked on a full queue: the step is the
-bottleneck) vs ``consumer_wait_s`` (the step blocked on an empty queue: the
-feed is the bottleneck) — and the same numbers are emitted as profiler
-counters/spans when the profiler runs.
+Observability: three ``profiler.scope`` spans, each also an annotation in
+any jax profiler trace on the thread that ran it —
+``DevicePrefetcher.device_put`` (producer thread: placing one batch; the
+span ends when ``jax.device_put`` has DISPATCHED every leaf's transfer, not
+when the bytes have arrived: the producer goes on to the source's next
+batch while they travel, and a step on a batch still in flight waits on
+the device, where only a device trace shows it),
+``DevicePrefetcher.producer_wait`` (producer blocked on a full queue: the
+step is the bottleneck) and ``DevicePrefetcher.consumer_wait`` (the
+consumer blocked on an empty queue: the feed is the bottleneck).  ``stats``
+carries ``produced``/``consumed``, live ``queue_depth`` (also the
+``DevicePrefetcher::queue_depth`` counter) and the two waits summed, from
+the same stamps as the spans.
 """
 from __future__ import annotations
 
 import queue as _queue
 import threading
-import time
 
 import numpy as np
 import jax
@@ -115,39 +121,34 @@ class DevicePrefetcher:
                       "producer_wait_s": 0.0, "consumer_wait_s": 0.0}
         self._depth_counter = _profiler.Counter(
             None, "DevicePrefetcher::queue_depth")
-        # the wait split as cumulative-ms counter series (ISSUE 15):
-        # readable with the profiler off, and what TrainStep's per-step
-        # spans read feed-wait deltas from
-        self._cwait_counter = _profiler.Counter(
-            None, "DevicePrefetcher::consumer_wait_ms")
-        self._pwait_counter = _profiler.Counter(
-            None, "DevicePrefetcher::producer_wait_ms")
 
     # ----------------------------------------------------------- produce --
     def _produce(self, it, q, stop):
         while not stop.is_set():
             try:
                 _fire("prefetch.device_put")
-                item = _map_leaves(self._put, next(it))
+                item = next(it)
+                with _profiler.scope("DevicePrefetcher.device_put",
+                                     cat="feed"):
+                    item = _map_leaves(self._put, item)
             except StopIteration:
                 item = self._STOP
             except Exception as exc:  # re-raised on the consumer side,
                 # tagged as placement-thread provenance (the consumer's
                 # traceback otherwise points at the blameless q.get)
                 item = _with_context(exc, "DevicePrefetcher producer")
-            t0 = time.perf_counter()
             enqueued = False
-            while not stop.is_set():
-                try:
-                    q.put(item, timeout=0.05)
-                    enqueued = True
-                    break
-                except _queue.Full:
-                    continue
-            waited = time.perf_counter() - t0
-            self._pwait_counter.increment(waited * 1e3)
+            with _profiler.scope("DevicePrefetcher.producer_wait",
+                                 cat="wait") as wait:
+                while not stop.is_set():
+                    try:
+                        q.put(item, timeout=0.05)
+                        enqueued = True
+                        break
+                    except _queue.Full:
+                        continue
             with self._lock:
-                self.stats["producer_wait_s"] += waited
+                self.stats["producer_wait_s"] += wait.seconds
                 if enqueued and item is not self._STOP \
                         and not isinstance(item, Exception):
                     # a batch dropped by a halt is NOT produced: keeps the
@@ -176,9 +177,8 @@ class DevicePrefetcher:
         thread.start()
         try:
             while True:
-                t0 = time.perf_counter()
                 with _profiler.scope("DevicePrefetcher.consumer_wait",
-                                     cat="wait"):
+                                     cat="wait") as wait:
                     # poll so a stale generator resumed AFTER a newer
                     # __iter__ superseded it (its producer joined, queue
                     # drained) ends cleanly instead of blocking forever
@@ -190,10 +190,8 @@ class DevicePrefetcher:
                             if stop.is_set():
                                 item = self._STOP
                                 break
-                waited = time.perf_counter() - t0
-                self._cwait_counter.increment(waited * 1e3)
                 with self._lock:
-                    self.stats["consumer_wait_s"] += waited
+                    self.stats["consumer_wait_s"] += wait.seconds
                     self._set_depth_locked(q)
                 if item is self._STOP:
                     return

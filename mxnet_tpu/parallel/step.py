@@ -209,10 +209,6 @@ class TrainStep:
         self._built = False
         self._jit = None
         self._num_update = optimizer.begin_num_update
-        # feed-wait attribution for the per-step span (ISSUE 15): the
-        # cumulative DevicePrefetcher consumer-wait reading at the last
-        # traced step, so each step span carries the wait accrued since
-        self._feed_wait_seen = None
 
     @property
     def data_sharding(self):
@@ -242,7 +238,8 @@ class TrainStep:
             small = _unflatten_nd(tree, tuple(
                 NDArray(jax.lax.slice_in_dim(jnp.asarray(a._data), 0, 1, axis=ax))
                 for a in nds))
-            with _autograd.pause(), MeshScope(self.mesh):
+            with _pscope("TrainStep.deferred_init", cat="step"), \
+                    _autograd.pause(), MeshScope(self.mesh):
                 Block.__call__(net, *small)
         names, plist, arrays = param_names_and_values(net)
         self._names, self._plist = names, plist
@@ -295,7 +292,7 @@ class TrainStep:
                     for i, a in zip(aux_idx, aux_in):
                         pa[i] = a
                     # mesh visible to mesh-aware ops (ring/ulysses attn)
-                    with MeshScope(self.mesh):
+                    with MeshScope(self.mesh), jax.named_scope("forward"):
                         outs = functional_call(net, plist, pa, data_tree,
                                                dl, key_in, True,
                                                state_holder)
@@ -305,12 +302,16 @@ class TrainStep:
                                            tuple(NDArray(l) for l in ll))
                     if isinstance(lab_nd, tuple) and len(lab_nd) == 1:
                         lab_nd = lab_nd[0]
-                    loss = loss_fn(out_nd, lab_nd)
-                    lv = loss._data if isinstance(loss, NDArray) else loss
-                    lv = jnp.mean(lv) if reduce == "mean" else jnp.sum(lv)
+                    with jax.named_scope("loss"):
+                        loss = loss_fn(out_nd, lab_nd)
+                        lv = loss._data if isinstance(loss, NDArray) else loss
+                        lv = jnp.mean(lv) if reduce == "mean" else jnp.sum(lv)
                     mut = [m for _, m in state_holder.mutated]
                     return lv.astype(jnp.float32), mut
 
+                # device-side names: jax writes the forward pass's ops as
+                # ``jvp(forward)/...`` and their transposes, the backward
+                # pass, as ``transpose(jvp(forward))/...``
                 return jax.value_and_grad(loss_of, has_aux=True)(ta_in)
 
             if self._grad_reduce == "f32":
@@ -362,12 +363,14 @@ class TrainStep:
                     check_vma=False)(train_arrays, aux_arrays, key, *batch)
             t1 = t + 1
             new_train, new_states = [], []
-            for k, (w, g, s) in enumerate(zip(train_arrays, grads, states)):
-                lr_k = lr * lr_mults[k]
-                wd_k = opt.wd * wd_mults[k]
-                nw, ns = pure_update(opt, w, g, s, t1, lr_k, wd_k)
-                new_train.append(nw)
-                new_states.append(ns)
+            with jax.named_scope("optimizer"):
+                for k, (w, g, s) in enumerate(zip(train_arrays, grads,
+                                                  states)):
+                    lr_k = lr * lr_mults[k]
+                    wd_k = opt.wd * wd_mults[k]
+                    nw, ns = pure_update(opt, w, g, s, t1, lr_k, wd_k)
+                    new_train.append(nw)
+                    new_states.append(ns)
             # aux-state writeback (BatchNorm running stats — the reference's
             # aux_states path in cached_op.cc)
             mut_map = {i: v for (i, _), v in zip(state_holder.mutated, mut)}
@@ -415,8 +418,26 @@ class TrainStep:
         return self.step(data, label)
 
     def step(self, data, label):
-        with _pscope("TrainStep.step", cat="step"):
-            return self._step(data, label)
+        try:
+            with _pscope("TrainStep.step", cat="step") as span:
+                return self._step(data, label, span)
+        except NonFiniteAbortError:
+            # the numeric-abort flight trigger (ISSUE 15).  The scope has
+            # closed with the error, so the dying step's spans are in the
+            # ring BEFORE the post-mortem bundle lands; then the raise
+            # unwinds
+            _telemetry.flight_trip(
+                "nonfinite-abort", step=int(self._num_update),
+                consecutive_skips=self.consecutive_skips)
+            try:
+                # queued async snapshots commit before the abort unwinds
+                # (ISSUE 17): the last GOOD state must be on disk when
+                # the supervisor inspects the wreck
+                from .checkpoint import flush_pending
+                flush_pending(timeout=60.0)
+            except Exception:  # noqa: BLE001 — the abort verdict must
+                pass           # not be masked by a flush
+            raise
 
     def _prepare(self, data, label):
         """Everything a step needs short of touching the device: coerce
@@ -465,24 +486,26 @@ class TrainStep:
         with _telemetry.compile_guard("TrainStep", self._jit, key="step"):
             return self._invoke(args)
 
-    @staticmethod
-    def _finish_step_trace(tr, error=None):
-        """Export a step trace on a FAILING path: the flight-recorder
-        bundle dumped at abort time must contain the spans of the very
-        step that died, not every step except it.  ``finish()`` closes
-        the still-open spans itself; never raises."""
-        if tr is None:
-            return
-        try:
-            if error is not None:
-                cls = error if isinstance(error, type) else type(error)
-                tr.root.attrs["error"] = cls.__name__
-            tr.root.end()
-            tr.finish()
-        except Exception:   # noqa: BLE001 — tracing never worsens a death
-            pass
+    def _compile_first(self, args):
+        """The first call of a signature: trace, lower, compile or load
+        from the persistent cache, and the first execution (waited for, so
+        the span holds all of it).  ``cache_hit`` says which it was, from
+        jax's own cache events (None when nobody watches them:
+        ``config.watch_compiles``)."""
+        with _pscope("TrainStep.compile", cat="step") as span:
+            before = _telemetry.compile_stats()
+            out = self._run_guarded(args)
+            jax.block_until_ready(out[4])
+            after = _telemetry.compile_stats()
+            hits = after["persistent_cache_hits"] \
+                - before["persistent_cache_hits"]
+            misses = after["persistent_cache_misses"] \
+                - before["persistent_cache_misses"]
+            span.set(cache_hit=(hits > 0 and misses == 0)
+                     if hits or misses else None)
+        return out
 
-    def _step(self, data, label):
+    def _step(self, data, label, span):
         _fire("step")
         t_wall = time.perf_counter()
         data_leaves, label_leaves = self._prepare(data, label)
@@ -490,19 +513,18 @@ class TrainStep:
         # heartbeat BEFORE the compiling call so the supervisor's
         # watchdog can tell a long first compile from a hung step
         # (ISSUE 15 — startup grace stops being a blind timer)
-        if self._heartbeat is not None and self._jit._cache_size() == 0:
+        fresh = self._jit._cache_size() == 0
+        if self._heartbeat is not None and fresh:
             self._heartbeat.beat(self._num_update, phase="train",
                                  compile_in_progress=True)
-        tr = _telemetry.maybe_trace("step", server="TrainStep") \
-            if _telemetry.ACTIVE else None
         key = _random.next_key()
         lr = jnp.float32(self._base_lr())
         dat_sh = NamedSharding(self.mesh, self._data_pspec)
-        sp_h2d = None if tr is None else tr.open("h2d", parent=tr.root)
-        data_leaves = [_put_batch(l, dat_sh) for l in data_leaves]
-        label_leaves = [_put_batch(l, dat_sh) for l in label_leaves]
-        if sp_h2d is not None:
-            sp_h2d.end()
+        # ends when the transfers are dispatched, not when they arrive;
+        # empty for a batch a DevicePrefetcher placed already
+        with _pscope("TrainStep.h2d", cat="step"):
+            data_leaves = [_put_batch(l, dat_sh) for l in data_leaves]
+            label_leaves = [_put_batch(l, dat_sh) for l in label_leaves]
         args = (self._train_arrays, self._aux_arrays, self._states,
                 self._t, key, lr, *data_leaves, *label_leaves)
         if getattr(self, "_last_avals", None) is None:
@@ -510,13 +532,13 @@ class TrainStep:
             # with (shapes are fixed until sig changes)
             self._last_avals = jax.tree.map(
                 lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args)
-        sp_compute = None if tr is None else tr.open("compute",
-                                                     parent=tr.root)
-        try:
-            out = self._run_guarded(args)
-        except BaseException as exc:
-            self._finish_step_trace(tr, error=exc)
-            raise
+        if fresh:
+            out = self._compile_first(args)
+        else:
+            # host time to hand the program to the device: the device
+            # runs it after this span has closed
+            with _pscope("TrainStep.dispatch", cat="step"):
+                out = self._run_guarded(args)
         if self._skip_nonfinite:
             (self._train_arrays, self._aux_arrays, self._states, self._t,
              loss, finite) = out
@@ -535,24 +557,8 @@ class TrainStep:
                         lv = float(np.asarray(loss))
                     except Exception:
                         lv = float("nan")
-                    # the numeric-abort flight trigger (ISSUE 15): the
-                    # dying step's trace exports FIRST (into the ring),
-                    # then the post-mortem bundle lands, then the raise
-                    # unwinds
-                    self._finish_step_trace(tr, error=NonFiniteAbortError)
-                    tr = None          # the except/finish below must not
-                    #                    double-handle an exported trace
-                    _telemetry.flight_trip(
-                        "nonfinite-abort", step=int(self._num_update),
-                        consecutive_skips=self.consecutive_skips)
-                    try:
-                        # queued async snapshots commit before the abort
-                        # unwinds (ISSUE 17): the last GOOD state must be
-                        # on disk when the supervisor inspects the wreck
-                        from .checkpoint import flush_pending
-                        flush_pending(timeout=60.0)
-                    except Exception:  # noqa: BLE001 — the abort verdict
-                        pass           # must not be masked by a flush
+                    # step() trips the flight recorder once this raise has
+                    # closed the step's scope
                     raise NonFiniteAbortError(
                         f"TrainStep: {self.consecutive_skips} consecutive "
                         f"non-finite updates (budget {budget}) at "
@@ -567,32 +573,11 @@ class TrainStep:
              loss) = out
             self._num_update += 1
         self.optimizer.num_update = self._num_update
-        step_ms = (time.perf_counter() - t_wall) * 1e3
-        if sp_compute is not None:
-            sp_compute.end()
-        if tr is not None:
-            # feed-wait attribution: the DevicePrefetcher consumer-wait
-            # accrued since the last traced step rides the root span
-            # (the wait happened before this step's window opened, so
-            # it is an attribute + histogram, not a child span)
-            try:
-                w = _profiler.counter_value(
-                    "DevicePrefetcher::consumer_wait_ms")
-                if w is not None:
-                    seen = self._feed_wait_seen
-                    delta = 0.0 if seen is None else max(0.0, w - seen)
-                    self._feed_wait_seen = w
-                    tr.root.attrs["feed_wait_ms"] = round(delta, 3)
-                    _telemetry.registry().histogram(
-                        "TrainStep::feed_wait_ms",
-                        _telemetry.SPAN_MS_BUCKETS).observe(delta)
-                tr.root.attrs["num_update"] = int(self._num_update)
-                tr.root.end()
-                tr.finish()
-            except Exception:   # noqa: BLE001 — tracing never fails a step
-                pass
+        span.set(num_update=int(self._num_update))
         if self._heartbeat is not None:
-            self._heartbeat.beat(self._num_update, last_step_ms=step_ms)
+            self._heartbeat.beat(
+                self._num_update,
+                last_step_ms=(time.perf_counter() - t_wall) * 1e3)
         return NDArray(loss)
 
     # ------------------------------------------------------------- costing --

@@ -23,12 +23,14 @@ import os
 import threading
 import time
 
+from jax.profiler import TraceAnnotation as _Annotation
+
 from . import telemetry as _telemetry
 
 __all__ = ["set_config", "set_state", "start", "stop", "pause", "resume",
            "dump", "dumps", "reset", "Task", "Frame", "Event", "Counter",
-           "Marker", "scope", "counter_value", "counters",
-           "counters_clear", "ingest_events"]
+           "Marker", "scope", "scope_cost", "counter_value", "counters",
+           "counters_clear"]
 
 _lock = threading.Lock()
 
@@ -45,7 +47,7 @@ class _ProfilerState:
         self.continuous_dump = False
         self.events = []             # chrome trace events
         self.stats = {}              # name -> [count, total_s, min_s, max_s]
-        self._device_tracing = False
+        self._started_jax_trace = False
 
 
 _P = _ProfilerState()
@@ -88,22 +90,22 @@ def set_state(state="stop"):
             # graph so an idle profiler costs the hot path nothing)
             from .ndarray import ndarray as _nd_mod
             _nd_mod._PROF = sys.modules[__name__]
-            if _P.device and not _P._device_tracing:
+            if _P.device and not _P._started_jax_trace:
                 try:
                     import jax
                     jax.profiler.start_trace(_P.logdir)
-                    _P._device_tracing = True
+                    _P._started_jax_trace = True
                 except Exception:
                     pass
         elif state == "stop":
             _P.active = False
-            if _P._device_tracing:
+            if _P._started_jax_trace:
                 try:
                     import jax
                     jax.profiler.stop_trace()
                 except Exception:
                     pass
-                _P._device_tracing = False
+                _P._started_jax_trace = False
             dump_after = _P.continuous_dump
         else:
             raise ValueError("state must be 'run' or 'stop'")
@@ -164,49 +166,83 @@ def want_sync():
 
 
 class scope:
-    """``with profiler.scope("name"):`` — explicit span over any region.
-    Also forwards to jax's TraceAnnotation so device traces carry the name."""
+    """``with profiler.scope("name"):`` — the program's thread-bound span.
+    One ``with``, one name, three records:
 
-    def __init__(self, name, cat="region"):
+    - always a ``jax.profiler.TraceAnnotation(name)``, so ANY jax profiler
+      session (``mx.profiler``'s, a benchmark's ``start_trace``, a
+      TensorBoard capture) shows the region on the thread that ran it,
+      beside the device's events.  With no session active it costs a flag
+      test (``scope_cost()``);
+    - while ``telemetry`` is armed, a ``telemetry.Span`` of the same name
+      in its store, child of the enclosing scope on this thread
+      (``telemetry.open_scope``); ``set(**attrs)`` adds attributes to it;
+    - while ``mx.profiler`` runs, an event in its Chrome-trace buffer.
+
+    The program's names are ``<Component>.<phase>`` with the component in
+    CamelCase or a server's own name (``TrainStep.step``,
+    ``DevicePrefetcher.device_put``, ``<server name>.decode``); jax's own
+    host events never have that form (``PjitFunction(f)``, ``$file:line``).
+    """
+
+    __slots__ = ("_name", "_cat", "_attrs", "_ann", "_t0", "_t1", "_token")
+    # Task/Frame/Event start() and stop() need not nest, which a stack of
+    # enclosing scopes cannot follow: they keep out of the span store
+    _STORE = True
+
+    def __init__(self, name, cat="region", **attrs):
         self._name = name
         self._cat = cat
-        self._jax_ctx = None
+        self._attrs = attrs or None
+        self._token = None
 
     def __enter__(self):
+        self._ann = _Annotation(self._name)
+        self._ann.__enter__()
+        if _telemetry.ACTIVE and self._STORE:
+            self._token = _telemetry.open_scope(self._name, self._attrs)
         self._t0 = _now_us()
-        if _P._device_tracing:
-            try:
-                import jax
-                self._jax_ctx = jax.profiler.TraceAnnotation(self._name)
-                self._jax_ctx.__enter__()
-            except Exception:
-                self._jax_ctx = None
         return self
 
-    def __exit__(self, *exc):
-        if self._jax_ctx is not None:
-            self._jax_ctx.__exit__(*exc)
+    def set(self, **attrs):
+        """Attributes learned inside the region (in-memory span only: an
+        annotation's are fixed when it opens)."""
+        if self._token is not None and isinstance(
+                self._token[1], _telemetry.Span):
+            self._token[1].attrs.update(attrs)
+
+    @property
+    def seconds(self):
+        """Length of the closed region, from the span's own two stamps."""
+        return (self._t1 - self._t0) / 1e6
+
+    def __exit__(self, exc_type, exc, tb):
+        t1 = self._t1 = _now_us()
+        self._ann.__exit__(exc_type, exc, tb)
+        if self._token is not None:     # exporting is not part of the region
+            _telemetry.close_scope(self._token, t1, error=exc_type)
+            self._token = None
         if ACTIVE:
-            record_span(self._name, self._t0, _now_us(), self._cat)
+            record_span(self._name, self._t0, t1, self._cat)
 
 
-def ingest_events(events):
-    """Append pre-built Chrome-trace events to the profiler stream —
-    the channel ``telemetry.Trace.finish`` uses so request spans land
-    on the SAME timeline as profiler spans and counters.  Events are
-    only kept while the profiler is recording."""
-    if not ACTIVE:
-        return
-    with _lock:
-        _P.events.extend(events)
+def scope_cost(iters=100_000):
+    """Measured seconds per ``with scope(...)`` right now; with no profiler
+    session, ``mx.profiler`` stopped and telemetry off that is the dark
+    cost every instrumented site pays (``telemetry.guard_cost``'s idiom)."""
+    t0 = time.perf_counter()
+    for _ in range(iters):
+        with scope("Profiler.scope_cost"):
+            pass
+    return (time.perf_counter() - t0) / iters
 
 
 # ---------------------------------------------------------------- output --
 def dump(finished=True):
     """Write the Chrome-trace JSON to the configured filename.  Events
-    are sorted by timestamp (telemetry traces export whole trees at
-    request resolution, out of arrival order) so ``ts`` is monotonic
-    per tid in the written stream."""
+    are sorted by timestamp (a span is appended when it closes, so an
+    outer one follows its children) so ``ts`` is monotonic per tid in the
+    written stream."""
     with _lock:
         payload = {"traceEvents": sorted(_P.events,
                                          key=lambda e: e.get("ts", 0)),
@@ -244,6 +280,8 @@ class Domain:
 
 class Task(scope):
     """Named task span (ref: profiler.Task). start()/stop() API."""
+
+    _STORE = False
 
     def __init__(self, domain=None, name="task"):
         super().__init__(name if domain is None

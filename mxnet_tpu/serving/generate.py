@@ -274,11 +274,13 @@ def _sample_tokens(logits, seeds, positions, temps, topks):
     import jax
     import jax.numpy as jnp
 
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    masked = _scaled_masked(logits, temps, topks)
-    keys = _position_keys(seeds, positions)
-    drawn = jax.vmap(jax.random.categorical)(keys, masked).astype(jnp.int32)
-    return jnp.where(temps > 0.0, drawn, greedy)
+    with jax.named_scope("sample"):
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        masked = _scaled_masked(logits, temps, topks)
+        keys = _position_keys(seeds, positions)
+        drawn = jax.vmap(jax.random.categorical)(keys, masked) \
+            .astype(jnp.int32)
+        return jnp.where(temps > 0.0, drawn, greedy)
 
 
 # -------------------------------------------------------- program builders --
@@ -344,6 +346,7 @@ def build_decode_step(config, page_size, attention_impl=None, mesh=None,
     decode is latency-bound on collective bytes).  Slot state, tokens,
     and the sampled output stay replicated, so the serving loop drives
     both shapes identically."""
+    import jax
     import jax.numpy as jnp
 
     from ..gluon.model_zoo.causal_lm import decode_hidden, lm_logits
@@ -374,8 +377,9 @@ def build_decode_step(config, page_size, attention_impl=None, mesh=None,
         # the page-0 sink).  The gather reads the pre-step pool, so a
         # lane whose src page was concurrently recycled still copies
         # the prefix content it diverged from.
-        k_pool = k_pool.at[:, cow_dst].set(k_pool[:, cow_src])
-        v_pool = v_pool.at[:, cow_dst].set(v_pool[:, cow_src])
+        with jax.named_scope("cow"):
+            k_pool = k_pool.at[:, cow_dst].set(k_pool[:, cow_src])
+            v_pool = v_pool.at[:, cow_dst].set(v_pool[:, cow_src])
         h = params["embed"][tokens]                     # [S, d]
         pos = lengths
         page = jnp.take_along_axis(tables, (pos // page_size)[:, None],
@@ -390,11 +394,13 @@ def build_decode_step(config, page_size, attention_impl=None, mesh=None,
                 k = k.reshape(slots, heads_l, head_dim)
                 v = v.reshape(slots, heads_l, head_dim)
                 q = q.reshape(slots, heads_l, head_dim)
-                k_pool = k_pool.at[_l, page, off].set(k)
-                v_pool = v_pool.at[_l, page, off].set(v)
-                return paged_decode_attention(q, k_pool[_l], v_pool[_l],
-                                              tables, att_len,
-                                              impl=attention_impl)
+                with jax.named_scope("kv_write"):
+                    k_pool = k_pool.at[_l, page, off].set(k)
+                    v_pool = v_pool.at[_l, page, off].set(v)
+                with jax.named_scope("attention"):
+                    return paged_decode_attention(
+                        q, k_pool[_l], v_pool[_l], tables, att_len,
+                        impl=attention_impl)
             h = decode_hidden(params, layer, h, attend, reduce=reduce_fn)
         nxt = _sample_tokens(lm_logits(params, h), seeds, lengths + 1,
                              temps, topks)
@@ -422,6 +428,7 @@ def build_prefill_step(config, page_size, attention_impl=None, mesh=None,
     on its bucket or row): the prompt forward is compute-bound, not
     latency-bound on collective bytes (the ``tp_collectives`` knob is a
     decode-path trade)."""
+    import jax
     import jax.numpy as jnp
 
     from ..gluon.model_zoo.causal_lm import prefill_forward
@@ -448,8 +455,10 @@ def build_prefill_step(config, page_size, attention_impl=None, mesh=None,
         page = jnp.where(valid, tables[:, pos // page_size], 0)  # [b, L]
         off = jnp.broadcast_to((pos % page_size)[None, :], (b, L))
         for layer in range(config.n_layers):
-            k_pool = k_pool.at[layer, page, off].set(k_all[layer])
-            v_pool = v_pool.at[layer, page, off].set(v_all[layer])
+            with jax.named_scope(f"layer{layer}"), \
+                    jax.named_scope("kv_write"):
+                k_pool = k_pool.at[layer, page, off].set(k_all[layer])
+                v_pool = v_pool.at[layer, page, off].set(v_all[layer])
         # the first generated token sits at absolute position lengths[i]
         # (0-based) of prompt+output — same schedule the decode step
         # continues at lengths + 1
@@ -529,6 +538,7 @@ def build_handoff_step(config, page_size, mesh=None, tp_axis="tp"):
     With ``mesh`` the payload AND the pools are head-sharded over
     ``tp_axis``: each device scatters its own head shard, no
     collectives at all (the scatter indices are head-independent)."""
+    import jax
     import jax.numpy as jnp
 
     if mesh is not None:
@@ -543,8 +553,10 @@ def build_handoff_step(config, page_size, mesh=None, tp_axis="tp"):
         page = jnp.where(valid, tables[:, pos // page_size], 0)   # [B, L]
         off = jnp.broadcast_to((pos % page_size)[None, :], (B, L))
         for layer in range(config.n_layers):
-            k_pool = k_pool.at[layer, page, off].set(k_all[layer])
-            v_pool = v_pool.at[layer, page, off].set(v_all[layer])
+            with jax.named_scope(f"layer{layer}"), \
+                    jax.named_scope("kv_write"):
+                k_pool = k_pool.at[layer, page, off].set(k_all[layer])
+                v_pool = v_pool.at[layer, page, off].set(v_all[layer])
         return k_pool, v_pool
 
     if mesh is None:
@@ -562,6 +574,7 @@ def build_dense_decode_step(config, max_ctx, attention_impl=None):
     reservation the paged pool replaces.  Exists for the parity tests
     and as the costguard ``llm_decode_step_dense`` golden the paged
     win is committed against; the serving loop never runs it."""
+    import jax
     import jax.numpy as jnp
 
     from ..gluon.model_zoo.causal_lm import decode_hidden, lm_logits
@@ -585,10 +598,12 @@ def build_dense_decode_step(config, max_ctx, attention_impl=None):
                 k = k.reshape(slots, heads, head_dim)
                 v = v.reshape(slots, heads, head_dim)
                 q = q.reshape(slots, heads, head_dim)
-                k_cache = k_cache.at[_l, row, pos].set(k)
-                v_cache = v_cache.at[_l, row, pos].set(v)
-                return dense_decode_attention(q, k_cache[_l], v_cache[_l],
-                                              att_len)
+                with jax.named_scope("kv_write"):
+                    k_cache = k_cache.at[_l, row, pos].set(k)
+                    v_cache = v_cache.at[_l, row, pos].set(v)
+                with jax.named_scope("attention"):
+                    return dense_decode_attention(q, k_cache[_l],
+                                                  v_cache[_l], att_len)
             h = decode_hidden(params, layer, h, attend)
         nxt = _sample_tokens(lm_logits(params, h), seeds, lengths + 1,
                              temps, topks)
@@ -689,8 +704,9 @@ def build_verify_step(config, draft_cfg, page_size, spec_k, window,
         S = tokens.shape[0]
         W = window.shape[1]
         # (1) CoW fault lanes, exactly as in the decode step
-        k_pool = k_pool.at[:, cow_dst].set(k_pool[:, cow_src])
-        v_pool = v_pool.at[:, cow_dst].set(v_pool[:, cow_src])
+        with jax.named_scope("cow"):
+            k_pool = k_pool.at[:, cow_dst].set(k_pool[:, cow_src])
+            v_pool = v_pool.at[:, cow_dst].set(v_pool[:, cow_src])
 
         # (2) draft proposes k tokens from the dense right-aligned
         # window (pool-free; the draft runs replicated under tp).  q_i
@@ -735,11 +751,13 @@ def build_verify_step(config, draft_cfg, page_size, spec_k, window,
             kk = kk.reshape(lanes, heads_l, head_dim)
             vv = vv.reshape(lanes, heads_l, head_dim)
             q = q.reshape(lanes, heads_l, head_dim)
-            k_pool = k_pool.at[_l, page_l, off_l].set(kk)
-            v_pool = v_pool.at[_l, page_l, off_l].set(vv)
-            return paged_decode_attention(q, k_pool[_l], v_pool[_l],
-                                          tables_l, att_len,
-                                          impl=attention_impl)
+            with jax.named_scope("kv_write"):
+                k_pool = k_pool.at[_l, page_l, off_l].set(kk)
+                v_pool = v_pool.at[_l, page_l, off_l].set(vv)
+            with jax.named_scope("attention"):
+                return paged_decode_attention(q, k_pool[_l], v_pool[_l],
+                                              tables_l, att_len,
+                                              impl=attention_impl)
         logits = verify_logits(params, config, T, attend,
                                reduce=reduce_fn)          # [S, K1, V]
 

@@ -17,9 +17,12 @@ paths; this is the equivalent for the REQUEST path:
   classifier path, and admit → queue → prefill (worker id) → handoff →
   decode residency → retire for generation — with preemption/requeue
   and ``fault.fire`` firings recorded as span events.  Finished traces
-  export as JSONL (``JsonlSink``) AND into the profiler's Chrome-trace
-  stream, so request spans land on the same timeline as the profiler's
-  counters and ``TrainStep`` spans.
+  export as JSONL (``JsonlSink``).  The program's THREAD-BOUND spans
+  (``profiler.scope``: ``TrainStep.step``, ``DevicePrefetcher.device_put``,
+  ``<server>.decode`` ...) are recorded here too while tracing is armed
+  (``open_scope``/``close_scope``), under the same name the scope gives
+  its ``jax.profiler.TraceAnnotation`` — one ``with``, one name, on the
+  profiler's timeline and in this store.
 - **The off-switch contract** — tracing is armed per-process with
   ``enable(sample=...)`` and disarmed with ``disable()``.  Every
   instrumentation site in the serving stack is guarded by a single
@@ -97,14 +100,14 @@ __all__ = [
     "begin_request", "abort_request", "open_span", "end_span",
     "span_event", "get_span", "suppress",
     "use_spans", "push_current", "pop_current", "note_fault",
-    "finished_traces", "now_us",
+    "finished_traces", "now_us", "open_scope", "close_scope", "scope_spans",
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "registry",
     "log_buckets", "histogram_quantile", "merge_snapshots",
     "LATENCY_BUCKETS_S", "SPAN_MS_BUCKETS",
     "JsonlSink", "read_spans",
     "exposition", "render", "render_prometheus", "merge_payloads",
     "audit_spans", "audit_jsonl", "guard_cost",
-    "compile_event", "track_compile", "compile_guard",
+    "compile_event", "track_compile", "compile_guard", "note_jax_event",
     "pin_compile_census",
     "compile_site_stats", "compile_stats", "compile_events",
     "compile_gauges", "reset_compiles", "memory_gauges", "ckpt_gauges",
@@ -116,9 +119,8 @@ SCHEMA = "mxtpu.telemetry/1"
 
 
 def now_us():
-    """Microsecond timestamp on the profiler's timebase
-    (``time.perf_counter``) so request spans and profiler events share
-    one Chrome-trace timeline."""
+    """Microsecond timestamp on ``mx.profiler``'s timebase
+    (``time.perf_counter``)."""
     return time.perf_counter() * 1e6
 
 
@@ -131,7 +133,7 @@ class _Config:
         self.sink = None               # JsonlSink for finished spans
         self.collect = False           # keep finished Trace objects
         self.collected = collections.deque(maxlen=4096)
-        self.export_profiler = True    # mirror spans into profiler events
+        self.scopes = collections.deque(maxlen=4096)   # finished scope traces
         self.errors = 0                # tracer-internal swallowed failures
 
 
@@ -149,8 +151,7 @@ def config():
     return _CFG
 
 
-def enable(sample=1.0, sink=None, collect=False, collect_limit=4096,
-           export_profiler=True):
+def enable(sample=1.0, sink=None, collect=False, collect_limit=4096):
     """Arm request tracing process-wide.
 
     ``sample`` ∈ [0, 1] is the per-trace sampling probability (1.0 =
@@ -158,7 +159,10 @@ def enable(sample=1.0, sink=None, collect=False, collect_limit=4096,
     way).  ``sink`` is a ``JsonlSink`` or a path; finished traces write
     one JSONL line per span there.  ``collect=True`` additionally keeps
     finished ``Trace`` objects in memory (bounded by ``collect_limit``)
-    for tests and audits.  Also installs the ``fault.fire`` observer so
+    for tests and audits: request and other explicit traces for
+    ``finished_traces()``, the program's scope traces apart for
+    ``scope_spans()``, so the first stays "one tree per accepted
+    request".  Also installs the ``fault.fire`` observer so
     fault firings land as span events."""
     global ACTIVE
     _CFG.sample = float(sample)
@@ -173,7 +177,7 @@ def enable(sample=1.0, sink=None, collect=False, collect_limit=4096,
     _CFG.sink = sink
     _CFG.collect = bool(collect)
     _CFG.collected = collections.deque(maxlen=int(collect_limit))
-    _CFG.export_profiler = bool(export_profiler)
+    _CFG.scopes = collections.deque(maxlen=int(collect_limit))
     try:    # package mode only; standalone (launcher) has no fault twin
         from . import fault as _fault
         _fault.set_observer(note_fault)
@@ -291,17 +295,20 @@ class Span:
 class Trace:
     """One request's span tree.  Created by ``begin_request`` on the
     accepting server (or by hand for tests); ``finish()`` exports every
-    span to the configured sink, the profiler's Chrome-trace stream,
-    and the per-phase latency histograms.  Span appends are GIL-atomic
+    span to the configured sink and the per-phase latency histograms
+    (``<server>::<span>_ms``; a scope trace, which has no server, feeds
+    ``<span>_ms``).  Span appends are GIL-atomic
     list appends — the tracer takes no lock on the serving hot path."""
 
-    __slots__ = ("trace_id", "server", "root", "spans", "finished")
+    __slots__ = ("trace_id", "server", "root", "spans", "finished",
+                 "scoped")
 
     def __init__(self, name="request", server="", t0=None, attrs=None):
         self.trace_id = f"{os.getpid():x}-{next(_ids):x}"
         self.server = str(server)
         self.spans = []
         self.finished = False
+        self.scoped = False            # a profiler.scope's own small tree
         self.root = self.open(name, parent=None, t0=t0, **(attrs or {}))
 
     def open(self, name, parent=None, t0=None, **attrs):
@@ -327,7 +334,8 @@ class Trace:
             if sp.t1 is None:          # defensive: audit wants closure
                 sp.end()
             try:
-                reg.histogram(f"{self.server}::{sp.name}_ms",
+                reg.histogram(f"{self.server}::{sp.name}_ms" if self.server
+                              else f"{sp.name}_ms",
                               SPAN_MS_BUCKETS).observe(sp.dur_us / 1e3)
             except Exception:
                 _oops()
@@ -338,11 +346,6 @@ class Trace:
                     sink.write(rec.pop("kind"), rec.pop("name"), **rec)
             except Exception:
                 _oops()
-        if _CFG.export_profiler:
-            try:
-                self._export_profiler()
-            except Exception:
-                _oops()
         if _FLIGHT.enabled:
             try:
                 for rec in self.records():
@@ -351,33 +354,7 @@ class Trace:
             except Exception:
                 _oops()
         if _CFG.collect:
-            _CFG.collected.append(self)
-
-    def _export_profiler(self):
-        """Mirror the finished tree into the profiler's event buffer so
-        request spans land on the SAME Chrome-trace timeline as the
-        profiler's own spans and counters (no-op unless the profiler is
-        recording)."""
-        from . import profiler as _profiler
-        if not _profiler.ACTIVE:
-            return
-        pid = os.getpid()
-        events = []
-        for sp in list(self.spans):
-            events.append({
-                "name": f"{self.server}.{sp.name}" if self.server
-                else sp.name,
-                "ph": "X", "ts": sp.t0, "dur": sp.dur_us, "pid": pid,
-                "tid": sp.tid, "cat": "trace",
-                "args": {"trace": self.trace_id, "span": sp.sid,
-                         "parent": sp.parent_id, **sp.attrs}})
-            for ev in sp.events:
-                events.append({"name": ev["name"], "ph": "i",
-                               "ts": ev["t_us"], "pid": pid,
-                               "tid": sp.tid, "s": "t", "cat": "trace",
-                               "args": {"trace": self.trace_id,
-                                        "span": sp.sid}})
-        _profiler.ingest_events(events)
+            (_CFG.scopes if self.scoped else _CFG.collected).append(self)
 
 
 def maybe_trace(name, server="", t0=None, attrs=None):
@@ -393,6 +370,72 @@ def maybe_trace(name, server="", t0=None, attrs=None):
     except Exception:
         _oops()
         return None
+
+
+# ------------------------------------------------------ thread-bound scopes --
+# ``profiler.scope`` is the program's one span primitive; while tracing is
+# armed it records here.  A scope with no enclosing scope on its thread is
+# the root of a small trace of its own (finished, hence exported, when it
+# closes); a nested one is a child span of the enclosing scope.  One device
+# step serves a whole group of requests and belongs to none of them, so a
+# scope never joins a request's tree: it names the requests current on its
+# thread (``push_current``) in its ``traces`` attribute instead.
+_UNSAMPLED = object()      # an enclosing scope was sampled out: so are we
+
+
+def open_scope(name, attrs=None):
+    """Begin the in-memory span of a ``profiler.scope`` and return the
+    token ``close_scope`` takes.  Never raises."""
+    prev = getattr(_tls, "scope", None)
+    span = _UNSAMPLED
+    try:
+        if prev is None:
+            tr = maybe_trace(name, attrs=attrs)
+            if tr is not None:
+                tr.scoped = True
+                span = tr.root
+                stack = getattr(_tls, "stack", None)
+                if stack and stack[-1]:
+                    span.attrs["traces"] = sorted(
+                        {sp.trace.trace_id for sp in stack[-1]})
+        elif prev is not _UNSAMPLED:
+            span = prev.trace.open(name, parent=prev, **(attrs or {}))
+    except Exception:
+        _oops()
+    _tls.scope = span
+    return prev, span
+
+
+def close_scope(token, t1=None, error=None):
+    """End, at ``t1``, the span ``open_scope`` began; a root scope finishes
+    (exports) its trace.  ``error`` is the exception class the region
+    raised."""
+    prev, span = token
+    _tls.scope = prev
+    if span is _UNSAMPLED:
+        return
+    try:
+        span.end(t1)
+        if error is not None:
+            span.attrs.setdefault("error", error.__name__)
+        if prev is None:
+            span.trace.finish()
+    except Exception:
+        _oops()
+
+
+def scope_spans(name=None, since_us=None, until_us=None):
+    """Finished scope spans kept by ``enable(collect=True)``, oldest
+    first: those named ``name`` (all when None) that lie wholly inside
+    ``[since_us, until_us]`` on the ``now_us`` clock."""
+    out = []
+    for tr in list(_CFG.scopes):
+        for sp in list(tr.spans):
+            if (name is None or sp.name == name) and sp.t1 is not None \
+                    and (since_us is None or sp.t0 >= since_us) \
+                    and (until_us is None or sp.t1 <= until_us):
+                out.append(sp)
+    return out
 
 
 # ------------------------------------------------- request instrumentation --
@@ -1177,12 +1220,47 @@ def compile_site_stats(site):
                 "ms_total": st.ms_total, "unexpected": st.unexpected}
 
 
+# What jax itself compiled or loaded, whoever asked and whether or not
+# tracing is armed: fed by the ``jax.monitoring`` listeners that
+# ``config.setup_compile_cache()`` registers (this module stays
+# standard-library only).  They fire only when jax compiles or loads an
+# executable, so a steady-state step pays nothing.
+_JAX_EVENTS = {
+    "/jax/core/compile/backend_compile_duration":
+        ("executables_created", "backend_compile_s"),
+    "/jax/compilation_cache/cache_hits": ("persistent_cache_hits", None),
+    "/jax/compilation_cache/cache_misses": ("persistent_cache_misses", None),
+    "/jax/compilation_cache/compile_time_saved_sec":
+        (None, "compile_time_saved_s"),
+}
+_JAX_COMPILES = {"executables_created": 0, "backend_compile_s": 0.0,
+                 "persistent_cache_hits": 0, "persistent_cache_misses": 0,
+                 "compile_time_saved_s": 0.0}
+
+
+def note_jax_event(event, seconds=None, **_):
+    """``jax.monitoring`` listener body (events and event durations)."""
+    keys = _JAX_EVENTS.get(event)
+    if keys is None:
+        return
+    count, secs = keys
+    with _COMPILE_LOCK:
+        if count is not None:
+            _JAX_COMPILES[count] += 1
+        if secs is not None and seconds is not None:
+            _JAX_COMPILES[secs] += float(seconds)
+
+
 def compile_stats():
-    """Process-wide compile-stream totals (the BENCH-line columns)."""
+    """Process-wide compile totals.  ``events`` .. ``sites`` come from
+    the program's tracked compile sites and count only while tracing is
+    armed; ``executables_created`` (compiled or loaded from the
+    persistent cache), ``persistent_cache_hits`` / ``_misses`` and the
+    two second-sums are jax's own events and count always."""
     with _COMPILE_LOCK:
         sites = dict(_COMPILE_SITES)
         out = {"events": 0, "hits": 0, "misses": 0, "ms_total": 0.0,
-               "unexpected": 0, "sites": {}}
+               "unexpected": 0, "sites": {}, **_JAX_COMPILES}
         for name, st in sites.items():
             out["hits"] += st.hits
             out["misses"] += st.misses
@@ -1220,6 +1298,8 @@ def reset_compiles():
     with _COMPILE_LOCK:
         _COMPILE_SITES.clear()
         _COMPILE_EVENTS.clear()
+        for k in _JAX_COMPILES:
+            _JAX_COMPILES[k] = type(_JAX_COMPILES[k])(0)
     with _PROBE_LOCK:
         _PROBE_HW.clear()
 

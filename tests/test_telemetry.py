@@ -661,8 +661,11 @@ def test_chrome_trace_validity_and_jsonl_roundtrip(tmp_path):
 
     payload = json.loads(trace_path.read_text())  # parses as JSON
     events = payload["traceEvents"]
-    cats = {e.get("cat") for e in events}
-    assert "trace" in cats                   # request spans landed
+    # the program's scopes land in the Chrome buffer under their own
+    # names, once (request trees go to the JSONL sink, not here)
+    steps = [e for e in events if e.get("name") == "TelChrome.step"]
+    assert steps and all(e["cat"] == "serving" for e in steps)
+    assert "trace" not in {e.get("cat") for e in events}
     by_tid = {}
     for e in events:
         assert e["ph"] in ("X", "C", "i", "B", "E")
@@ -679,8 +682,13 @@ def test_chrome_trace_validity_and_jsonl_roundtrip(tmp_path):
     # JSONL round-trip reconstructs every span tree
     assert telemetry.audit_jsonl(jsonl_path) == {}
     trees = telemetry.read_spans(jsonl_path)
+    scoped = {sp.trace.trace_id for sp in telemetry.scope_spans()}
+    assert {e["name"] for e in steps} == {
+        sp.name for sp in telemetry.scope_spans()}
+    assert len(steps) == len(scoped)
     live = {tr.trace_id: tr for tr in telemetry.finished_traces()}
-    assert set(trees) == set(live)
+    assert set(trees) - scoped == set(live)
+    trees = {tid: recs for tid, recs in trees.items() if tid in live}
     for tid, recs in trees.items():
         assert len(recs) == len(live[tid].spans)
         ids = {r["span"] for r in recs}
@@ -688,9 +696,10 @@ def test_chrome_trace_validity_and_jsonl_roundtrip(tmp_path):
                    for r in recs)
 
 
-def test_profiler_export_needs_recording():
-    """Trace export into the profiler stream is a no-op while the
-    profiler is off — finished traces must not grow a dead buffer."""
+def test_scope_keeps_out_of_chrome_buffer_while_profiler_is_off():
+    """A scope feeds mx.profiler's Chrome buffer only while mx.profiler
+    runs — its span still reaches the telemetry store — so an idle
+    profiler never grows a dead buffer."""
     telemetry.enable(collect=True)
     profiler.reset()
     srv = make_server(name="TelNoProf")
@@ -699,8 +708,8 @@ def test_profiler_export_needs_recording():
     finally:
         srv.drain()
     assert telemetry.finished_traces()
-    assert not [e for e in profiler._P.events
-                if e.get("cat") == "trace"]
+    assert [sp.name for sp in telemetry.scope_spans()] == ["TelNoProf.step"]
+    assert not profiler._P.events
 
 
 # ================================================== ISSUE 15: introspection --
@@ -822,16 +831,22 @@ def test_trainstep_step_spans_and_compile_events():
         step(x, y).asnumpy()
     st = telemetry.compile_site_stats("TrainStep")
     assert st["misses"] == 1 and st["hits"] == 2
-    trees = [tr for tr in telemetry.finished_traces()
-             if tr.server == "TrainStep"]
-    assert len(trees) == 3
-    for tr in trees:
+    assert telemetry.finished_traces() == []      # no request was served
+    roots = telemetry.scope_spans("TrainStep.step")
+    assert len(roots) == 3
+    for i, root in enumerate(roots):
+        tr = root.trace
         assert telemetry.audit_spans(tr) == []
-        names = {sp.name for sp in tr.spans}
-        assert {"step", "h2d", "compute"} <= names
+        assert root.attrs["num_update"] == i + 1
+        names = [sp.name for sp in tr.spans if sp.parent_id == root.sid]
+        # the first call of a signature compiles, later ones dispatch: a
+        # span that ends when the dispatch returns is not called compute
+        assert names == (["TrainStep.h2d", "TrainStep.compile"] if i == 0
+                         else ["TrainStep.h2d", "TrainStep.dispatch"])
     snap = telemetry.registry().snapshot()
-    assert "TrainStep::step_ms" in snap["histograms"]
-    assert snap["histograms"]["TrainStep::step_ms"]["count"] == 3
+    assert snap["histograms"]["TrainStep.step_ms"]["count"] == 3
+    assert snap["histograms"]["TrainStep.dispatch_ms"]["count"] == 2
+    assert "TrainStep::feed_wait_ms" not in snap["histograms"]
 
 
 def test_trainstep_steps_untraced_when_dark():
@@ -984,7 +999,16 @@ def test_flight_dump_bundle_roundtrips_through_audit(tmp_path):
     assert recs[0]["kind"] == "flight" and recs[0]["reason"] == "test-dump"
     kinds = {r["kind"] for r in recs}
     assert {"flight", "span", "compile", "metrics"} <= kinds
-    assert len(telemetry.read_spans(path)) == 4       # all four trees
+    trees = telemetry.read_spans(path)
+    requests = [t for t in trees.values()
+                if any(r["name"] == "request" for r in t)]
+    assert len(requests) == 4                         # all four trees
+    # beside them the device steps' own scope trees, each naming the
+    # requests it served
+    steps = [t for t in trees.values() if t[0]["name"] == "FlightSrv.step"]
+    assert len(steps) == 4 and len(requests) + len(steps) == len(trees)
+    assert {s[0]["attrs"]["traces"][0] for s in steps} == {
+        r["trace"] for t in requests for r in t}
     # the metrics snapshot is the LAST line and carries the registry
     assert recs[-1]["kind"] == "metrics"
     assert "gauges" in recs[-1]
@@ -1054,7 +1078,7 @@ def test_nonfinite_abort_trips_flight_dump(tmp_path):
     # one), marked with the abort error
     recs = [json.loads(line) for line in open(path)]
     fatal = [r for r in recs if r.get("kind") == "span"
-             and r.get("name") == "step"
+             and r.get("name") == "TrainStep.step"
              and r.get("attrs", {}).get("error") == "NonFiniteAbortError"]
     assert fatal, [r.get("name") for r in recs]
 
