@@ -132,9 +132,9 @@ def setup_compile_cache():
     the cache lives at ``<checkout>/.jax_compile_cache`` — a fixed path,
     never a temp name, pid or time, because a cache that moves never
     hits.  Every executable is kept (0 s floor): a generation server is
-    a handful of programs of a few seconds each and a model's eager
-    deferred-init pass a few hundred sub-second ones, and a warm start
-    should recompile none of them.  Also registers ``watch_compiles()``.
+    a handful of programs of a few seconds each, a model's initializer
+    program one, the eager ops around them sub-second ones, and a warm
+    start should recompile none of them.  Also registers ``watch_compiles()``.
     Entry points call this before their first compile (``chip_smoke.py``,
     ``bench.py``, ``tests_tpu/``, the examples)."""
     import jax

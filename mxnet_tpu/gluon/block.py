@@ -28,9 +28,10 @@ from .. import random as _random
 from ..base import dtype_np
 from ..context import current_context
 from ..ndarray import NDArray
+from . import parameter as _parameter
 from .parameter import DeferredInitializationError, Parameter, ParameterDict
 
-__all__ = ["Block", "HybridBlock", "SymbolBlock"]
+__all__ = ["Block", "HybridBlock", "SymbolBlock", "infer_shapes"]
 
 _naming = threading.local()
 
@@ -115,6 +116,51 @@ def _unflatten_nd(tree, leaves):
         return tuple(_walk(x) for x in t)
 
     return _walk(tree)
+
+
+def infer_shapes(block, *args):
+    """Fill the shapes of ``block``'s deferred parameters from ``args``
+    without running an operation: ``Block.__call__`` under
+    ``jax.eval_shape``, the layers' ``infer_shape`` hooks doing what they do
+    in an eager first forward, every pending parameter standing in as an
+    abstract array of its shape.  Returns the tree's pending parameters
+    whose shape is now known, first those the forward asked for, in its
+    order (the order an eager first forward would have initialized them
+    in), then the rest: hand it to ``parameter.materialize``.
+
+    The whole tree runs eagerly-styled (no child serves or builds a jit
+    cache), paused and in predict mode.  Nothing outlives the pass but the
+    shapes: every parameter gets back the array, or none, it held before,
+    and layers that draw random numbers draw from a traced key, never from
+    the framework's stream."""
+    leaves, tree = _flatten_nd(args)
+    owned = list(block.collect_params().values())
+    held = [p._data for p in owned]
+    prev_dry = getattr(_naming, "dry_run", False)
+    _naming.dry_run = True
+    _parameter._shape_pass.met = met = []
+
+    def dry(key, *avals):
+        inputs = _unflatten_nd(tree, tuple(NDArray(a) for a in avals))
+        with _random.RandomScope(key), _autograd.pause():
+            out = Block.__call__(block, *inputs)
+        return [o._data for o in _flatten_nd(out)[0]]
+
+    try:
+        jax.eval_shape(
+            dry, _parameter._key_aval(),
+            *[jax.ShapeDtypeStruct(l.shape, jax.dtypes.canonicalize_dtype(
+                l._data.dtype)) for l in leaves])
+    finally:
+        _parameter._shape_pass.met = None
+        _naming.dry_run = prev_dry
+        for p in met:
+            p._data = None
+        for p, d in zip(owned, held):
+            p._data = d
+    seen = set(map(id, met))
+    return met + [p for p in owned if id(p) not in seen
+                  and p._deferred_init is not None and p._shape_known()]
 
 
 class _HookHandle:
@@ -377,7 +423,7 @@ class HybridBlock(Block):
     def infer_shape(self, *args):
         """Layer hook: fill wildcard (0) dims of own params from inputs.
         ref: HybridBlock._deferred_infer_shape (symbolic infer replaced by
-        per-layer rules; composite blocks infer via a dry eager run)."""
+        per-layer rules; composite blocks infer via ``infer_shapes``)."""
         raise DeferredInitializationError(
             f"{type(self).__name__} cannot infer parameter shapes; "
             f"initialize with fully-specified shapes")
@@ -387,22 +433,16 @@ class HybridBlock(Block):
         pending = [p for p in self._reg_params.values() if p._deferred_init is not None]
         if pending:
             self.infer_shape(*args)
-            for p in self._reg_params.values():
-                if p._deferred_init is not None:
-                    p._finish_deferred_init()
-        for c in self._children.values():
-            if isinstance(c, HybridBlock):
-                # children get their inputs only during forward; composite
-                # blocks resolve via the eager dry-run in __call__
-                pass
+            for p in pending:
+                p._finish_deferred_init()
 
-    def _has_deferred(self):
-        if getattr(self, "_deferred_done", False):
+    def _has_pending(self):
+        if getattr(self, "_pending_done", False):
             return False
         for p in self.collect_params().values():
             if p._deferred_init is not None:
                 return True
-        self._deferred_done = True
+        self._pending_done = True
         return False
 
     # -------------------------------------------------------------- forward --
@@ -411,16 +451,10 @@ class HybridBlock(Block):
                 and not any(
                     isinstance(a, NDArray) and isinstance(a._data, jax.core.Tracer)
                     for a in args)):
-            if self._has_deferred():
-                # One eager dry run resolves every deferred shape in the tree.
-                # Children must NOT individually compile during it (that would
-                # also perturb the init RNG stream), hence the dry_run flag.
-                _naming.dry_run = True
-                try:
-                    with _autograd.pause():
-                        Block.__call__(self, *args)
-                finally:
-                    _naming.dry_run = False
+            if self._has_pending():
+                # one abstract pass resolves every deferred shape in the
+                # tree, one program makes every pending array
+                _parameter.materialize(infer_shapes(self, *args))
             return self._call_cached(*args)
         return Block.__call__(self, *args)
 

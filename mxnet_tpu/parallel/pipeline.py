@@ -156,7 +156,7 @@ def _make_pipeline_stack():
             self.template.initialize()
             stacked_names = []
             for name, p in sorted(self.template.collect_params().items()):
-                if p._deferred_init is not None:
+                if not p._shape_known():
                     raise ValueError(
                         f"pipeline stages need fully-specified shapes; "
                         f"parameter '{name}' has deferred init "

@@ -26,14 +26,14 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec
 
 from .. import random as _random
-from .. import autograd as _autograd
 from ..fault import fire as _fire
 from ..elastic import NonFiniteAbortError
 from .. import profiler as _profiler
 from .. import telemetry as _telemetry
 from ..profiler import scope as _pscope
 from ..ndarray import NDArray
-from ..gluon.block import Block, _flatten_nd, _unflatten_nd
+from ..gluon.block import _flatten_nd, _unflatten_nd, infer_shapes
+from ..gluon.parameter import _run_program, materialize
 from .mesh import MeshScope, default_mesh
 from .sharding import ShardingRules, batch_spec, param_sharding
 from .functional import (FunctionalState, functional_call,
@@ -139,6 +139,19 @@ def _put_batch(leaf, sharding):
     return jax.device_put(leaf, sharding)
 
 
+def _has_pending(net):
+    return any(p._deferred_init is not None
+               for p in net.collect_params().values())
+
+
+def _materialize_net(net, sample_args, mesh):
+    """Before a step first reads ``net``'s parameters: one abstract pass
+    for the deferred shapes, then one program that makes every pending
+    array.  Returns how many it made."""
+    with MeshScope(mesh):
+        return materialize(infer_shapes(net, *sample_args))
+
+
 class TrainStep:
     """Compiled (params, states, batch) → (params', states', loss) on a mesh."""
 
@@ -218,50 +231,43 @@ class TrainStep:
         return NamedSharding(self.mesh, self._data_pspec)
 
     # --------------------------------------------------------------- build --
-    def _batch_axis(self):
-        """Index of the dp-sharded (batch) axis in the data pspec."""
-        for i, el in enumerate(self._data_pspec):
-            names = el if isinstance(el, tuple) else (el,)
-            if "dp" in names:
-                return i
-        return 0
-
     def _build(self, sample_args):
-        net = self.net
-        if any(p._deferred_init is not None
-               for p in net.collect_params().values()):
-            # shape-inference dry run on a batch-1 slice: deferred init only
-            # needs feature dims, and a full-batch eager forward would both
-            # waste a step of compute and OOM at large batch sizes
-            ax = self._batch_axis()
-            nds, tree = _flatten_nd(sample_args)
-            small = _unflatten_nd(tree, tuple(
-                NDArray(jax.lax.slice_in_dim(jnp.asarray(a._data), 0, 1, axis=ax))
-                for a in nds))
-            with _pscope("TrainStep.deferred_init", cat="step"), \
-                    _autograd.pause(), MeshScope(self.mesh):
-                Block.__call__(net, *small)
-        names, plist, arrays = param_names_and_values(net)
+        if _has_pending(self.net):
+            # ``executables``: what jax created under the span, counted
+            # while config.watch_compiles listens
+            with _pscope("TrainStep.deferred_init", cat="step") as span:
+                before = _telemetry.compile_stats()["executables_created"]
+                made = _materialize_net(self.net, sample_args, self.mesh)
+                span.set(params=made, executables=_telemetry.compile_stats()[
+                    "executables_created"] - before)
+        names, plist, arrays = param_names_and_values(self.net)
         self._names, self._plist = names, plist
         self._train_idx, self._aux_idx = trainable_split(plist)
         shardings = param_sharding(names, [a.shape for a in arrays],
                                    self.mesh, self.rules)
         self._param_shardings = shardings
-        arrays = [jax.device_put(a, s) for a, s in zip(arrays, shardings)]
-        self._train_arrays = [arrays[i] for i in self._train_idx]
-        self._aux_arrays = [arrays[i] for i in self._aux_idx]
-        self._states = tuple(
-            tuple(jax.device_put(s, shardings[i])
-                  for s in state_template(self.optimizer, arrays[i]))
-            for i in self._train_idx)
+        arrays = jax.device_put(arrays, shardings)
+        train_sh = [shardings[i] for i in self._train_idx]
+        aux_sh = [shardings[i] for i in self._aux_idx]
+        opt = self.optimizer
+
+        def own(arrays):
+            # the step DONATES its arrays: copies, so the net's Parameters
+            # stay readable, and the optimizer state, in one program
+            train = [arrays[i] for i in self._train_idx]
+            return ([jnp.copy(a) for a in train],
+                    [jnp.copy(arrays[i]) for i in self._aux_idx],
+                    tuple(tuple(state_template(opt, a)) for a in train))
+
+        self._train_arrays, self._aux_arrays, self._states = _run_program(
+            own, (arrays,), (train_sh, aux_sh, tuple(train_sh)))
         # static per-param lr/wd multipliers (ref: Optimizer._get_lr/_get_wd)
         self._lr_mults = [plist[i].lr_mult for i in self._train_idx]
         self._wd_mults = [plist[i].wd_mult for i in self._train_idx]
         self._repl = NamedSharding(self.mesh, PartitionSpec())
         # device_put so t's aval carries the mesh like the jit outputs do —
         # otherwise step 2 retraces (t: i32[]({}) vs i32[]({Auto: (dp,)}))
-        self._t = jax.device_put(jnp.zeros((), jnp.int32) + self._num_update,
-                                 self._repl)
+        self._t = jax.device_put(np.int32(self._num_update), self._repl)
         self._built = True
 
     def _base_lr(self):
@@ -721,15 +727,13 @@ class EvalStep:
         return NamedSharding(self.mesh, self._data_pspec)
 
     def _build(self, sample_args):
-        if any(p._deferred_init is not None
-               for p in self.net.collect_params().values()):
-            with _autograd.pause(), MeshScope(self.mesh):
-                Block.__call__(self.net, *sample_args)
+        if _has_pending(self.net):
+            _materialize_net(self.net, sample_args, self.mesh)
         names, plist, arrays = param_names_and_values(self.net)
         self._names, self._plist = names, plist
         sh = param_sharding(names, [a.shape for a in arrays], self.mesh,
                             self.rules)
-        self._arrays = [jax.device_put(a, s) for a, s in zip(arrays, sh)]
+        self._arrays = jax.device_put(arrays, sh)
         self._shardings = sh
         self._built = True
 

@@ -12,7 +12,7 @@ import threading
 
 import jax
 
-__all__ = ["seed", "next_key", "RandomScope", "current_key_source"]
+__all__ = ["seed", "next_key", "RandomScope", "KeyTape", "current_key_source"]
 
 _tls = threading.local()
 
@@ -62,6 +62,30 @@ class RandomScope:
 
     def __exit__(self, *exc):
         _tls.stack.pop()
+
+
+class KeyTape(RandomScope):
+    """Hands out keys that were drawn earlier, in the order they were drawn.
+
+    A pending Parameter draws its initializer's keys from the framework
+    stream when it is initialized and the initializer runs later, alone or
+    traced into the one program that materialises a whole model
+    (gluon/parameter.py).  Either way it sees these keys, so the stream is
+    the one an eager ``initialize()`` would have consumed.
+    """
+
+    def __init__(self, keys):
+        super().__init__(None)
+        self._keys = list(keys)
+
+    def next_key(self):
+        if self._count == len(self._keys):
+            raise RuntimeError(
+                f"initializer drew more than the {len(self._keys)} PRNG "
+                f"key(s) its abstract run drew: the number of draws must "
+                f"depend on name, shape and dtype alone")
+        self._count += 1
+        return self._keys[self._count - 1]
 
 
 def current_key_source():

@@ -253,6 +253,23 @@ def test_first_call_spans_deferred_init_and_compile():
         "TrainStep.h2d", "TrainStep.dispatch"]
 
 
+@pytest.mark.parametrize("in_units", [0, 4])
+def test_deferred_init_span_counts_params_and_executables(in_units):
+    # shapes deferred or given, the first call makes every parameter that
+    # initialize() recorded, in one program: the span says how many, and
+    # how many executables jax created under it
+    config.watch_compiles()
+    telemetry.enable(collect=True)
+    step = _tiny_step(in_units=in_units)
+    x, y = _batches(1)[0]
+    step(x, y).asnumpy()
+    (sp,) = telemetry.scope_spans("TrainStep.deferred_init")
+    assert sp.attrs["params"] == 4
+    assert 0 <= sp.attrs["executables"] <= 1     # 0: another test's program
+    assert all(p._deferred_init is None
+               for p in step.net.collect_params().values())
+
+
 # ------------------------------------------------ always-on compile counts --
 def test_compile_stats_counts_with_telemetry_never_enabled():
     config.watch_compiles()

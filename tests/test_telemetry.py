@@ -839,9 +839,11 @@ def test_trainstep_step_spans_and_compile_events():
         assert telemetry.audit_spans(tr) == []
         assert root.attrs["num_update"] == i + 1
         names = [sp.name for sp in tr.spans if sp.parent_id == root.sid]
-        # the first call of a signature compiles, later ones dispatch: a
-        # span that ends when the dispatch returns is not called compute
-        assert names == (["TrainStep.h2d", "TrainStep.compile"] if i == 0
+        # the first call makes the parameters initialize() recorded and
+        # compiles, later ones dispatch: a span that ends when the dispatch
+        # returns is not called compute
+        assert names == (["TrainStep.deferred_init", "TrainStep.h2d",
+                          "TrainStep.compile"] if i == 0
                          else ["TrainStep.h2d", "TrainStep.dispatch"])
     snap = telemetry.registry().snapshot()
     assert snap["histograms"]["TrainStep.step_ms"]["count"] == 3
@@ -1061,7 +1063,9 @@ def test_breaker_open_trips_flight_dump(tmp_path):
 
 def test_nonfinite_abort_trips_flight_dump(tmp_path):
     telemetry.enable(collect=True)          # the dying step is traced
-    telemetry.enable_flight(directory=tmp_path)
+    # a ring of its own size: the eviction test above leaves the shared
+    # recorder at four entries, one fewer than a first step's spans
+    telemetry.enable_flight(directory=tmp_path, limit=4096)
     step = _tiny_train_step(skip_nonfinite=True, nonfinite_budget=1)
     x = np.full((16, 4), np.nan, np.float32)
     y = np.zeros((16,), np.int32)
