@@ -163,6 +163,31 @@ def infer_shapes(block, *args):
                   and p._deferred_init is not None and p._shape_known()]
 
 
+def _recomputed(block, args):
+    """``block(*args)`` under ``jax.checkpoint``: the backward pass keeps
+    the block's inputs and runs its forward again for everything else.  The
+    block's parameters enter as the traced values they already are."""
+    leaves, tree = _flatten_nd(args)
+    held = [(p, p._data._data) for p in block.collect_params().values()
+            if p._data is not None]
+    seen = {}
+
+    def body(*arrays):
+        out = block._forward_hooked(
+            *_unflatten_nd(tree, tuple(NDArray(a) for a in arrays)))
+        for p, a in held:
+            if p._data._data is not a:
+                raise NotImplementedError(
+                    f"{block.name}.recompute(): the forward rewrites "
+                    f"{p.name} (aux state such as BatchNorm's running "
+                    f"statistics), which cannot leave a recomputed block")
+        out_leaves, seen["out"] = _flatten_nd(out)
+        return [o._data for o in out_leaves]
+
+    outs = jax.checkpoint(body)(*[l._data for l in leaves])
+    return _unflatten_nd(seen["out"], tuple(NDArray(o) for o in outs))
+
+
 class _HookHandle:
     """Removable hook registration (ref: mxnet.gluon.utils.HookHandle)."""
 
@@ -182,6 +207,8 @@ class _HookHandle:
 
 class Block:
     """Base neural-network container (ref: gluon/block.py — class Block)."""
+
+    _recompute = False      # see recompute()
 
     def __init__(self, prefix=None, params=None):
         self._empty_prefix = prefix == ""
@@ -280,6 +307,20 @@ class Block:
         for c in self._children.values():
             c._invalidate_cache()
 
+    def recompute(self):
+        """Trade compute for memory: wherever this block runs under a trace
+        that is differentiated (``parallel.TrainStep``, a hybridized
+        parent, ``jax.grad`` over ``functional_call``), keep only its
+        inputs for the backward pass and run its forward a second time
+        there (``jax.checkpoint``).  Values and gradients are unchanged.
+        Marks this block alone: mark each layer of a stack to hold one
+        layer's intermediates at a time.  A block whose forward rewrites
+        aux state (BatchNorm) raises.  Eager calls are not affected (the
+        tape keeps every op's result).  Returns the block."""
+        self._recompute = True
+        self._invalidate_cache()
+        return self
+
     # ---------------------------------------------------------------- save --
     def _collect_params_with_prefix(self, prefix=""):
         """Structural names ("features.0.weight") independent of name scopes
@@ -317,6 +358,13 @@ class Block:
 
     # ------------------------------------------------------------- forward --
     def __call__(self, *args):
+        if self._recompute and not getattr(_naming, "dry_run", False) \
+                and any(isinstance(a._data, jax.core.Tracer)
+                        for a in _flatten_nd(args)[0]):
+            return _recomputed(self, args)
+        return self._forward_hooked(*args)
+
+    def _forward_hooked(self, *args):
         for hook in self._forward_pre_hooks:
             hook(self, args)
         out = self.forward(*args)

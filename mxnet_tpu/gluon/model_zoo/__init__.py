@@ -5,4 +5,5 @@ from . import bert
 from . import ssd
 from . import language_model
 from . import causal_lm
+from . import sambay
 from .vision import get_model

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .registry import register_op
+from ..base import dtype_np
 from .. import random as _random
 from .. import autograd as _autograd
 
@@ -30,11 +31,15 @@ def _tup(v, n):
 
 # -------------------------------------------------------------- linear ------
 @register_op("FullyConnected", aliases=("fully_connected",))
-def _fully_connected(data, weight, bias=None, num_hidden=None, no_bias=False, flatten=True):
+def _fully_connected(data, weight, bias=None, num_hidden=None, no_bias=False, flatten=True,
+                     out_dtype=None):
     """ref: src/operator/nn/fully_connected-inl.h — FCForward (cuBLAS gemm).
-    Weight layout (num_hidden, in_units), reference convention."""
+    Weight layout (num_hidden, in_units), reference convention.
+    ``out_dtype`` asks the matmul for a wider result than its inputs' type
+    (float32 logits from bf16 weights: the MXU accumulates in float32 anyway)."""
     x = data.reshape(data.shape[0], -1) if flatten else data
-    out = jnp.matmul(x, weight.T)
+    out = jnp.matmul(x, weight.T, preferred_element_type=None
+                     if out_dtype is None else dtype_np(out_dtype))
     if bias is not None and not no_bias:
         out = out + bias
     return out

@@ -118,6 +118,15 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
         lse_ref[0, 0, 0] = m_ref[...] + jnp.log(l)
 
 
+def _kv_row(q, k):
+    """Grouped-query heads: ``q`` holds ``group`` times the rows of ``k``
+    (rows are batch-major, then heads, so query row ``b`` reads K/V row
+    ``b // group``).  Returns the map from a query row to its K/V row."""
+    group, rest = divmod(q.shape[0], k.shape[0])
+    assert rest == 0, (q.shape, k.shape)
+    return (lambda b: b) if group == 1 else (lambda b: b // group)
+
+
 def _flash_fwd(q, k, v, seed, scale, causal, block_q, block_k, interpret,
                dropout):
     bh, s, d = q.shape
@@ -125,6 +134,7 @@ def _flash_fwd(q, k, v, seed, scale, causal, block_q, block_k, interpret,
     block_k = min(block_k, s)
     assert s % block_q == 0 and s % block_k == 0, (s, block_q, block_k)
     n_k = s // block_k
+    kv = _kv_row(q, k)
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, block_q=block_q,
         block_k=block_k, n_k=n_k, dropout=dropout)
@@ -134,8 +144,8 @@ def _flash_fwd(q, k, v, seed, scale, causal, block_q, block_k, interpret,
         in_specs=[
             pl.BlockSpec((1,), lambda b, i, j: (0,)),
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, d), lambda b, i, j: (kv(b), j, 0)),
+            pl.BlockSpec((1, block_k, d), lambda b, i, j: (kv(b), j, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -251,6 +261,7 @@ def _flash_bwd(q, k, v, seed, o, lse, do, scale, causal, block_q, block_k,
     block_q = min(block_q, s)
     block_k = min(block_k, s)
     n_q, n_k = s // block_q, s // block_k
+    kv = _kv_row(q, k)
     delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
     lse = lse.reshape(bh, n_q, 1, block_q)
     delta = delta.reshape(bh, n_q, 1, block_q)
@@ -263,8 +274,8 @@ def _flash_bwd(q, k, v, seed, o, lse, do, scale, causal, block_q, block_k,
         in_specs=[
             pl.BlockSpec((1,), lambda b, i, j: (0,)),
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, d), lambda b, i, j: (kv(b), j, 0)),
+            pl.BlockSpec((1, block_k, d), lambda b, i, j: (kv(b), j, 0)),
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
             _row_spec(block_q, lambda b, i, j: (b, i, 0, 0)),
             _row_spec(block_q, lambda b, i, j: (b, i, 0, 0)),
@@ -276,6 +287,10 @@ def _flash_bwd(q, k, v, seed, o, lse, do, scale, causal, block_q, block_k,
         interpret=interpret,
     )(seed, q, k, v, do, lse, delta)
 
+    # grouped-query heads: the kernel writes one float32 part per QUERY
+    # head, and a K/V head's gradient is the sum over its group
+    grouped = k.shape[0] != bh
+    part = jnp.float32 if grouped else k.dtype
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, n_q=n_q,
@@ -284,8 +299,8 @@ def _flash_bwd(q, k, v, seed, o, lse, do, scale, causal, block_q, block_k,
         in_specs=[
             pl.BlockSpec((1,), lambda b, j, i: (0,)),
             pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
+            pl.BlockSpec((1, block_k, d), lambda b, j, i: (kv(b), j, 0)),
+            pl.BlockSpec((1, block_k, d), lambda b, j, i: (kv(b), j, 0)),
             pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
             _row_spec(block_q, lambda b, j, i: (b, i, 0, 0)),
             _row_spec(block_q, lambda b, j, i: (b, i, 0, 0)),
@@ -295,14 +310,17 @@ def _flash_bwd(q, k, v, seed, o, lse, do, scale, causal, block_q, block_k,
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(k.shape, k.dtype),
-            jax.ShapeDtypeStruct(v.shape, v.dtype),
+            jax.ShapeDtypeStruct((bh, s, d), part),
+            jax.ShapeDtypeStruct((bh, s, d), part),
         ],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         name="flash_attention_bwd_dkv",
         interpret=interpret,
     )(seed, q, k, v, do, lse, delta)
+    if grouped:
+        dk, dv = (g.reshape(k.shape[0], -1, s, d).sum(axis=1).astype(k.dtype)
+                  for g in (dk, dv))
     return dq, dk, dv
 
 
@@ -319,7 +337,10 @@ def flash_attention(q, k, v, scale=None, causal=False, block_q=128,
                     block_k=128, interpret=None, dropout=0.0, seed=None):
     """softmax(scale · Q Kᵀ [, causal]) V without materialising S×S.
 
-    q, k, v: (B*H, S, D).  ``dropout`` applies attention-probability dropout
+    q: (B*H, S, D); k, v the same, or (B*H_kv, S, D) for grouped-query
+    heads with H a multiple of H_kv: query head h reads K/V head
+    h // (H / H_kv) through the kernels' index maps, and nothing is repeated
+    in memory.  ``dropout`` applies attention-probability dropout
     inside the kernel (the mask is regenerated from a counter-based hash in
     forward AND backward — never stored).  ``seed`` may be a traced int32
     scalar so each training step draws a fresh mask without retracing.

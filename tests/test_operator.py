@@ -941,6 +941,9 @@ COVERED_ELSEWHERE = {
     "ulysses_attention": "tests/test_sequence_parallel.py",
     "moe_ffn": "tests/test_moe.py",
     "flash_attention": "tests/test_flash_attention.py",
+    "window_attention": "tests/test_sambay.py",
+    "selective_scan": "tests/test_sambay.py",
+    "causal_conv1d": "tests/test_sambay.py",
     "paged_decode_attention": "tests/test_generate.py",
     "dense_decode_attention": "tests/test_generate.py",
     "quantized_conv": "tests/test_misc_subsystems.py",
