@@ -3,23 +3,26 @@
 convolution that precedes it.  No reference analogue (the reference's
 recurrences are the fused ``RNN`` op).
 
-Both are plain XLA: the scan is a ``lax.scan`` over chunks of ``SCAN_CHUNK``
-positions carrying the state, each chunk a ``lax.scan`` over its positions
-under ``jax.checkpoint``, so the backward pass keeps the states at chunk
-boundaries and rebuilds one chunk's at a time (never the ``[T, N, D]`` tensor
-of all states: 1.3 GB at T 4096, D 5120, N 16).  The state is laid out
-``[B, N, D]``: the channels D fill the TPU's 128 lanes, the N states its
-sublanes.
+The convolution is plain XLA.  The scan is a pair of Pallas kernels
+(``ops/pallas/selective_scan.py``, ``selective_scan_fwd`` and
+``selective_scan_bwd``) behind a ``jax.custom_vjp``: a grid step holds
+``SCAN_CHUNK`` positions of a tile of channels and the state never leaves
+VMEM; the forward keeps the states at chunk boundaries and the backward
+rebuilds one chunk's at a time (never the ``[T, N, D]`` tensor of all states:
+1.3 GB at T 4096, D 5120, N 16).  The state of a tile is laid out ``[N,
+bd]``: the channels fill the TPU's 128 lanes, the N states its sublanes.  Off
+the TPU the same kernels run in the Pallas interpreter.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from .pallas import selective_scan as _kernels
 from .registry import register_op
 
-# positions a chunk holds: what the backward pass rebuilds at a time
-SCAN_CHUNK = 256
+# positions a grid step of the kernels holds: what the backward pass rebuilds
+# at a time
+SCAN_CHUNK = 128
 # the recurrence's state, its step and its decay are float32 whatever the
 # inputs' type: a product of T decays close to 1 does not survive bf16
 _STATE_DTYPE = jnp.float32
@@ -39,21 +42,6 @@ def _causal_conv1d(data, weight, bias=None):
     return out if bias is None else out + bias
 
 
-def _scan_chunk(h, chunk, a):
-    """One chunk, position by position.  ``h`` (B, N, D); ``chunk`` = (dt, x,
-    b, c) time-major; ``a`` (N, D).  Returns the state after the chunk and
-    its outputs (L, B, D)."""
-    f = _STATE_DTYPE
-
-    def step(h, xs):
-        dt, x, b, c = (v.astype(f) for v in xs)
-        h = jnp.exp(dt[:, None, :] * a) * h \
-            + b[:, :, None] * (dt * x)[:, None, :]
-        return h, jnp.sum(h * c[:, :, None], axis=1)
-
-    return jax.lax.scan(step, h, chunk)
-
-
 @register_op("selective_scan")
 def _selective_scan(x, dt, a, b, c, d=None):
     """``h[t] = exp(dt[t] * A) * h[t-1] + (dt[t] * x[t]) B[t]^T``,
@@ -64,21 +52,8 @@ def _selective_scan(x, dt, a, b, c, d=None):
     maps; ``d`` (D,) the skip.  Returns y (B, T, D) in ``x``'s type; the
     state, ``dt`` and A are float32 throughout."""
     f = _STATE_DTYPE
-    bsz, t, dim = x.shape
-    size = min(SCAN_CHUNK, t)
-    n_chunks = -(-t // size)
-    pad = n_chunks * size - t
-
-    def chunks(v):      # (B, T, F) -> (chunks, size, B, F); a padded step
-        v = jnp.pad(v, ((0, 0), (0, pad), (0, 0)))     # of 0 changes nothing
-        return jnp.moveaxis(v, 1, 0).reshape(n_chunks, size, bsz, v.shape[-1])
-
-    a = a.astype(f).T
-    h0 = jnp.zeros((bsz, a.shape[0], dim), f)
-    _, y = jax.lax.scan(
-        jax.checkpoint(lambda h, chunk: _scan_chunk(h, chunk, a)), h0,
-        (chunks(dt), chunks(x), chunks(b), chunks(c)))
-    y = jnp.moveaxis(y.reshape(n_chunks * size, bsz, dim), 0, 1)[:, :t]
+    y = _kernels.selective_scan(x, dt, a, b, c, chunk=SCAN_CHUNK,
+                                state_dtype=f)
     if d is not None:
         y = y + d.astype(f) * x.astype(f)
     return y.astype(x.dtype)

@@ -192,6 +192,43 @@ def test_chunked_scan_against_the_scan_by_position(monkeypatch, chunk, t):
         assert float(jnp.abs(g - w).max() / jnp.abs(w).max()) < 1e-5
 
 
+@pytest.mark.parametrize("t,dim,n,low", [
+    (3 * 32 + 17, 200, 16, False), (20, 24, 4, False),
+    (3 * 32 + 17, 136, 8, True)],
+    ids=["ragged_wide", "short", "bf16"])
+def test_scan_kernels_against_the_scan_by_position(t, dim, n, low):
+    """The Pallas kernels (the interpreter here) at a chunk of 32: T ragged
+    against the chunk and short of one, D short of and beyond one tile of 128
+    lanes, N that needs padding to the sublanes, two sequences; forward and
+    all six gradients under a fixed cotangent.  ``low``: x, b, c, d in bf16
+    beside a float32 dt, as the cell runs them; what comes back in bf16 is
+    held to one bf16 step of its largest entry forward and two backward
+    (x's gradient is the kernel's, rounded, plus the skip's)."""
+    x, dt, a, b, c, d = _scan_inputs(t, dim, n)
+    w = jnp.asarray(np.random.RandomState(5).randn(2, t, dim),
+                    jnp.bfloat16).astype(jnp.float32)
+    if low:
+        x, b, c, d = (v.astype(jnp.bfloat16) for v in (x, b, c, d))
+    args = (x, dt, a, b, c, d)
+    wide = tuple(v.astype(jnp.float32) for v in args)
+    scan = get_op("selective_scan")
+    got, want = scan(*args), _scan_by_position(*wide)
+    assert got.dtype == x.dtype
+    step = 2.0 ** -8 if low else 0.0
+    assert float(jnp.abs(got.astype(jnp.float32) - want).max()) \
+        <= 5e-6 + step * float(jnp.abs(want).max())
+
+    def total(fn):
+        return lambda *v: (fn(*v).astype(jnp.float32) * w).sum()
+    got = jax.grad(total(scan), range(6))(*args)
+    want = jax.grad(total(_scan_by_position), range(6))(*wide)
+    for v, g, r in zip(args, got, want):
+        assert g.dtype == v.dtype and g.shape == v.shape
+        limit = 2.0 ** -7 if v.dtype == jnp.bfloat16 else 1e-5
+        assert float(jnp.abs(g.astype(jnp.float32) - r).max()
+                     / jnp.abs(r).max()) < limit
+
+
 def test_scan_keeps_chunk_boundaries_only():
     """The backward pass holds no [T, N, D] tensor of states: the largest
     float32 array of the differentiated program is a chunk's."""

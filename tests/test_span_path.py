@@ -370,6 +370,18 @@ def _paged():
         jnp.zeros((2, 2), jnp.int32), jnp.ones((2,), jnp.int32))
 
 
+def _scan(grad):
+    from mxnet_tpu.ops.pallas.selective_scan import selective_scan
+    x = jnp.ones((1, 32, 128), jnp.float32)
+    a, b = -jnp.ones((128, 8), jnp.float32), jnp.ones((1, 32, 8), jnp.float32)
+
+    def total(x, dt, a, b, c):
+        return selective_scan(x, dt, a, b, c, chunk=16).sum()
+    if not grad:
+        return _pallas_names(total, x, x, a, b, b)
+    return _pallas_names(jax.grad(total, range(5)), x, x, a, b, b)
+
+
 @pytest.mark.parametrize("name,found", [
     ("paged_attention", _paged),
     ("flash_attention_fwd", lambda: _flash(False)),
@@ -378,6 +390,8 @@ def _paged():
     ("fused_conv_fwd", lambda: _conv(False)),
     ("fused_conv_bwd_dx", lambda: _conv(True)),
     ("fused_conv_bwd_dw", lambda: _conv(True)),
+    ("selective_scan_fwd", lambda: _scan(False)),
+    ("selective_scan_bwd", lambda: _scan(True)),
 ])
 def test_every_pallas_call_carries_its_name(name, found):
     names = found()
@@ -397,6 +411,29 @@ def test_kernel_name_reaches_the_tpu_lowering():
         jnp.ones((8,), jnp.int32)).lower(
         lowering_platforms=("tpu",)).as_text()
     assert 'kernel_name = "paged_attention"' in text
+
+
+@pytest.mark.parametrize("name,grad", [("selective_scan_fwd", False),
+                                       ("selective_scan_bwd", True)])
+def test_scan_kernels_lower_for_the_tpu_at_the_cell_shape(name, grad):
+    """Pallas' lowering to Mosaic at the widths ``phi4_mini_flash.
+    train_s4096`` runs (one sequence of 4,096, D 5120, N 16, bf16 x, b, c):
+    what it refuses (a block shape, a loop it cannot unroll, a primitive
+    without a rule) shows here, without a chip."""
+    from mxnet_tpu.ops import state_space
+    from mxnet_tpu.ops.pallas.selective_scan import selective_scan
+    wide = jax.ShapeDtypeStruct((1, 4096, 5120), jnp.bfloat16)
+    col = jax.ShapeDtypeStruct((1, 4096, 16), jnp.bfloat16)
+
+    def total(x, dt, a, b, c):
+        return selective_scan(x, dt, a, b, c, chunk=state_space.SCAN_CHUNK,
+                              interpret=False).sum()
+    fn = jax.grad(total, range(5)) if grad else total
+    text = jax.jit(fn).trace(
+        wide, jax.ShapeDtypeStruct(wide.shape, jnp.float32),
+        jax.ShapeDtypeStruct((5120, 16), jnp.float32), col, col).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert f'kernel_name = "{name}"' in text
 
 
 def _scope_paths(lowered):

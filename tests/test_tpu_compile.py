@@ -1,0 +1,74 @@
+"""Kernels of the benchmark's main path compiled FOR the TPU v5e, by the
+TPU's own compiler, at the widths the cells run: what Mosaic refuses (a slice
+off the tiling, a cast it has no rule for, more VMEM than a kernel may have)
+shows here and costs no chip time.  Nothing runs, so nothing here is a result
+or a speed.
+
+The TPU's library belongs to one process at a time: the topology is described
+inside a fixture, by the one worker that is given this file, and every such
+compile lives in this file (``on-chip-measurement`` guide, section 2)."""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from mxnet_tpu.ops import state_space
+from mxnet_tpu.ops.pallas.selective_scan import selective_scan
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache but
+    cannot be read back without one; keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+    held = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", held)
+    compilation_cache.reset_cache()
+
+
+# phi4_mini_flash.train_s4096: one sequence of 4,096 positions, D 5120, N 16,
+# bf16 x, b and c beside a float32 dt
+T, DIM, N = 4096, 5120, 16
+
+
+@pytest.mark.parametrize("state", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bf16_control"])
+def test_scan_kernels_compile_for_v5e(one_chip, no_compile_cache, state):
+    """Forward and backward at the cell's shape and the module's chunk; the
+    bf16 state is the precision control of ``tests_tpu/test_sambay_tpu.py``,
+    which has to compile on the chip to be a control.  The program holds the
+    chunk-boundary states and never the ``[T, N, D]`` tensor (1.3 GB)."""
+    t, dim, n = T, DIM, N
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def total(x, dt, a, b, c):
+        return selective_scan(x, dt, a, b, c, chunk=state_space.SCAN_CHUNK,
+                              state_dtype=state, interpret=False).sum()
+    compiled = jax.jit(jax.grad(total, range(5))).trace(
+        shape((1, t, dim), jnp.bfloat16), shape((1, t, dim), jnp.float32),
+        shape((dim, n), jnp.float32), shape((1, t, n), jnp.bfloat16),
+        shape((1, t, n), jnp.bfloat16)).lower(
+        lowering_platforms=("tpu",)).compile()
+    text = compiled.as_text()
+    assert "selective_scan_fwd" in text and "selective_scan_bwd" in text
+    assert text.count("tpu_custom_call") >= 2
+    assert compiled.memory_analysis().temp_size_in_bytes < t * n * dim * 4 / 4
