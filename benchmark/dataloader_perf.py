@@ -3,7 +3,8 @@
 ref: the reference sizes its C++ decode pipeline (iter_image_recordio_2)
 to keep GPUs fed; here the same question for the TPU step: how many
 img/s can ImageRecordIter deliver on this host?  Compare against the
-model step rate (bench.py resnet ≈ 2.5k img/s/chip) to know when input
+model step rate (the resnet50_v1.train_b256 cell: 2672 img/s on one v5e,
+PERF_LEDGER.jsonl PR 28) to know when input
 becomes the bottleneck.
 
 Three decode paths (see mxnet_tpu/io.py):

@@ -1,13 +1,13 @@
 #!/usr/bin/env python
 """chip_smoke.py — the quickest proof that the system still starts on the chip.
 
-Drives the two hot paths once, through the constructors a user calls, at the
-full width of the repo's own bench configurations, with seeded random weights:
+Drives the two hot paths once, through the constructors a user calls, at a
+realistic width, with seeded random weights:
 
 1. trainer — ``resnet50_v1(layout="NHWC")`` in bf16, global batch 256 at
    224x224, ``parallel.TrainStep`` with SGD momentum over
    ``make_mesh(dp=len(jax.devices()))``: one fixed batch, a few steps.
-2. server — ``serving.GenerationServer`` at the bench shape (vocab 4096,
+2. server — ``serving.GenerationServer`` (vocab 4096,
    4 layers, 8 heads x 64, d_ff 2048, 64 slots, 512 pages x 64, buckets
    (1,2,4)x(32,64), ``attention_impl=None``): warmup, a few requests, drain;
    then two checks that the kernel is the kernel.
@@ -27,16 +27,16 @@ import os
 import sys
 import time
 
-# trainer: bench.py bench_resnet's configuration
+# trainer: the resnet50_v1.train_b256 cell's model and batch
 BATCH, IMAGE, CLASSES = 256, 224, 1000
 TRAIN_STEPS = 6
-# lr 0.1 (the bench's) overshoots on the first steps of a fixed random batch;
+# lr 0.1 overshoots on the first steps of a fixed random batch;
 # the smoke checks that the loss falls, so it steps gently
 TRAIN_LR = 0.01
 # dp=N vs dp=1 first-step loss, bf16 activations: reduction order only
 DP_LOSS_RTOL = 2e-2
 
-# server: bench.py bench_llm's configuration
+# server
 LM = dict(vocab_size=4096, n_layers=4, n_heads=8, head_dim=64, d_ff=2048)
 N_SLOTS, N_PAGES, PAGE_SIZE, MAX_NEW = 64, 512, 64, 64
 BUCKET_BATCH, BUCKET_LENGTH = (1, 2, 4), (32, 64)
@@ -224,9 +224,9 @@ def paged_kernel_phase(cfg, pages_per_seq):
     check("impl='pallas' lowers to one tpu_custom_call", n_kernels == 1,
           str(n_kernels))
     out = np.asarray(kernel(*args))
+    gather = jax.jit(lambda *a: paged_decode_attention(*a, impl="jnp"))
     with jax.default_matmul_precision("highest"):
-        ref = np.asarray(jax.jit(
-            lambda *a: paged_decode_attention(*a, impl="jnp"))(*args))
+        ref = np.asarray(gather(*args))
     live = lengths > 0
     err = float(np.abs(out[live] - ref[live]).max())
     check(f"Pallas vs jnp paged attention within atol {PAGED_ATOL}",

@@ -2,8 +2,8 @@
 
 ref: example/image-classification/train_imagenet.py, modernised to the
 TPU-native fast path: parallel.TrainStep compiles forward+backward+
-optimizer into ONE XLA program over a device mesh (this is the loop
-bench.py measures at ~2.5k img/s/chip bf16).
+optimizer into ONE XLA program over a device mesh (this is the loop the
+benchmark's resnet50_v1.train_b256 cell runs, chipbench/run.py).
 
     python examples/train_resnet_fused.py [--model resnet50_v1] [--iters 50]
     # Pallas fused norm-relu-conv blocks (bn+relu folded into the convs):

@@ -195,6 +195,22 @@ def test_parse_custom_call_payload_duplicates():
     assert len(cc["payloads"]) == 3 and len(set(cc["payloads"])) == 2
 
 
+def test_partitioner_markers_are_not_counted():
+    """``Sharding`` / ``SPMD*ToShape`` custom calls are jax's lowering of
+    shard_map, not our program: the census leaves them out of targets
+    and total, and counts every other target."""
+    text = _custom_call_text(["KERN_A"]).replace(
+        "    return", "".join(
+            f'    %m{i} = stablehlo.custom_call @{t}(%arg0) : '
+            f'(tensor<8x128xf32>) -> tensor<8x128xf32>\n'
+            for i, t in enumerate(("Sharding", "SPMDFullToShardShape",
+                                   "SPMDShardToFullShape", "my_ffi")))
+        + "    return")
+    cc = entry_census({"p": extract_facts(text)})["custom_calls"]
+    assert cc["targets"] == {"my_ffi": 1, "tpu_custom_call": 1}
+    assert cc["total"] == 2 and cc["pallas_total"] == 1
+
+
 def test_parse_custom_call_shape_normalized():
     # same kernel at two geometries: raw payloads differ, the
     # shape-normalized forms collapse (ROADMAP item 4's dedup signal)
@@ -354,6 +370,24 @@ def test_env_mismatch_audits_without_gating(tmp_path):
                               for f in res.findings)
 
 
+def test_failing_surface_names_the_goldens_jax(tmp_path):
+    """The jax version does not gate; a FAILING surface whose golden was
+    cut under another jax says so, a passing one says nothing."""
+    def stale(g):
+        g["jax_version"] = "0.0.1"
+    res = check_entry(CHEAP, _doctored_root(tmp_path / "ok", stale))
+    assert res.gated and res.ok and res.findings == []
+
+    def stale_and_drifted(g):
+        stale(g)
+        g["census"]["copies"]["copy"] += 7
+    res = check_entry(CHEAP, _doctored_root(tmp_path / "bad",
+                                            stale_and_drifted))
+    assert not res.ok
+    assert any("golden cut under jax 0.0.1" in f.message
+               for f in res.findings)
+
+
 def test_schema_mismatch_requires_regen(tmp_path):
     def mutate(g):
         g["report_version"] = "0.0"
@@ -461,19 +495,26 @@ def test_cli_end_to_end_json():
 # the committed-tree gate (tier-1 acceptance)
 # ---------------------------------------------------------------------------
 
-def test_export_surface_census_dedup():
-    """The Pallas census must see through re-instantiation: the fused
-    tower repeats one 3x3 geometry (unique < total), paged attention
-    runs two geometries of one kernel (unique == total)."""
-    s = surfaces.build("pallas_fused_conv_tpu")
+@pytest.mark.parametrize("surface, total, unique", [
+    # two layers, ONE lowering of each kernel: _scan_fwd and _scan_bwd
+    # are jax.jits (PR 28), so the second layer calls the first's
+    ("pallas_selective_scan_tpu", 2, 2),
+    # plain functions: each layer lowers its own fwd, dq and dk/dv
+    ("pallas_flash_attention_tpu", 6, 3),
+    # the fused tower repeats one 3x3 geometry
+    ("pallas_fused_conv_tpu", 3, 2),
+    # paged attention runs two geometries of one kernel
+    ("pallas_paged_attention_tpu", 2, 2),
+])
+def test_export_surface_census_dedup(surface, total, unique):
+    """The Pallas census must see through re-instantiation: total is
+    the kernels a program traces and lowers before its first step,
+    unique the distinct ones among them."""
+    s = surfaces.build(surface)
     cc = entry_census(facts_for_programs(s.programs))["custom_calls"]
-    assert cc["pallas_total"] == 3
-    assert cc["pallas_unique"] == 2
-    assert cc["pallas_unique"] < cc["pallas_total"]
-
-    s = surfaces.build("pallas_paged_attention_tpu")
-    cc = entry_census(facts_for_programs(s.programs))["custom_calls"]
-    assert cc["pallas_total"] == 2 and cc["pallas_unique"] == 2
+    assert (cc["pallas_total"], cc["pallas_unique"]) == (total, unique)
+    assert cc["targets"] == {"tpu_custom_call": total}
+    assert load_golden(surface, REPO)["census"]["custom_calls"] == cc
 
 
 def test_hloguard_gate_committed_tree():

@@ -220,23 +220,6 @@ def test_quantize_net_on_hybridized_net():
     assert np.allclose(got, manual, atol=1e-6)
 
 
-def test_opperf_harness():
-    """benchmark/opperf.py: the per-op sweep runs and reports timings
-    (ref: benchmark/opperf/opperf.py — run_performance_test)."""
-    import importlib.util as iu
-    spec = iu.spec_from_file_location(
-        "opperf", os.path.join(os.path.dirname(__file__), "..",
-                               "benchmark", "opperf.py"))
-    opperf = iu.module_from_spec(spec)
-    spec.loader.exec_module(opperf)
-    res = opperf.run_performance_test(ops={"exp", "dot", "Convolution"},
-                                      warmup=1, runs=2)
-    assert len(res) == 3
-    for r in res:
-        assert "avg_time_ms" in r, r
-        assert r["avg_time_ms"] > 0
-
-
 def test_quantize_net_survives_calibration_failure():
     """A bad calibration batch must not leave collector wrappers or lost
     hybridization behind (regression)."""

@@ -290,8 +290,7 @@ def test_kvstore_pull_mismatch_raises():
 
 def test_trainstep_cost_analysis():
     """TrainStep.cost_analysis(): XLA's cost model of the compiled step
-    (a static count; used by benchmark/hlo_costs.py for the fused-conv
-    HBM A/B)."""
+    (a static count)."""
     net = gluon.nn.Dense(4, in_units=8)
     net.initialize()
     step = parallel.TrainStep(net, gluon.loss.L2Loss(),

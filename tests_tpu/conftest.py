@@ -32,7 +32,7 @@ import jax
 from mxnet_tpu.config import setup_compile_cache
 from mxnet_tpu.context import on_tpu
 
-# Persistent compile cache shared with chip_smoke.py and bench.py: the
+# Persistent compile cache shared with chip_smoke.py and chipbench/run.py: the
 # full on-chip re-run suite spends most of its wall clock in XLA compiles.
 setup_compile_cache()
 

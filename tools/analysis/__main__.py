@@ -31,7 +31,7 @@ def main(argv=None) -> int:
                         help="files or directories to analyze "
                              "(default: mxnet_tpu; with --changed, the "
                              "whole gated surface — mxnet_tpu, tools, "
-                             "examples, bench.py — so an edit anywhere "
+                             "examples, chip_smoke.py — so an edit anywhere "
                              "the gate covers is seen)")
     parser.add_argument("--format", choices=("human", "json", "sarif"),
                         default="human", dest="fmt",
@@ -86,7 +86,7 @@ def main(argv=None) -> int:
         # default set is the whole gated surface: "lint what I
         # changed" silently skipping a changed tools/ or examples/
         # file would be a false all-clear
-        defaults = ("mxnet_tpu", "tools", "examples", "bench.py") \
+        defaults = ("mxnet_tpu", "tools", "examples", "chip_smoke.py") \
             if args.changed else ("mxnet_tpu",)
         paths = [root / p for p in defaults if (root / p).exists()]
     findings = analyze(paths, config=config, root=root,

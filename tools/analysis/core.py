@@ -65,7 +65,7 @@ BAD_SUPPRESSION = "bad-suppression"
 # table, lock graph) are always gathered over these subtrees of the
 # root when they exist, regardless of which subset a run analyzes —
 # linting one file must not make every doc row look stale
-PROJECT_SCOPE = ("mxnet_tpu", "tools", "bench.py")
+PROJECT_SCOPE = ("mxnet_tpu", "tools")
 
 
 @dataclasses.dataclass

@@ -1594,7 +1594,7 @@ def lint_mode(args):
     """Incremental-analyzer smoke: cold run, warm run, compare (ISSUE 5).
 
     Both runs cover the full gate surface (mxnet_tpu + tools +
-    bench.py) with ALL findings serialized — suppressed ones included —
+    chip_smoke.py) with ALL findings serialized — suppressed ones included —
     so the byte-comparison covers the suppression/justification channel,
     not just the live-findings one.
     """
@@ -1607,7 +1607,7 @@ def lint_mode(args):
     cache_dir = tempfile.mkdtemp(prefix="chaos_lint_cache_")
     paths = [os.path.join(root, "mxnet_tpu"),
              os.path.join(root, "tools"),
-             os.path.join(root, "bench.py")]
+             os.path.join(root, "chip_smoke.py")]
     try:
         t0 = time.perf_counter()
         cold = analyze(paths, root=root, use_cache=True,

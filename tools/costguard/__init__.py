@@ -1,13 +1,15 @@
 """costguard — compiled-program cost budgets and recompile audit.
 
 The static-analysis instrument for the compile boundary (ISSUE 6):
-mxlint gates the Python-source surface; costguard gates what XLA
-actually compiled.  It lowers each registered entry point (model train
-step / serving bucket grid) WITHOUT executing a step, extracts a
-normalized report — FLOPs, bytes accessed, compiled-buffer memory,
-entry-instruction categories, donation coverage, executable count —
-and diffs it against committed per-model budget goldens
-(``tests/goldens/budgets/*.json``) with per-metric relative tolerances.
+mxlint gates the Python-source surface; costguard gates the programs
+handed to XLA.  It lowers and compiles each registered entry point
+(model train step / serving bucket grid) WITHOUT executing a step,
+extracts a normalized report and diffs the rows our code decides —
+executable count, donation coverage, argument and collective bytes,
+conv/dot/custom-call counts, the lowered module's FLOPs and bytes:
+``budget.DEFAULT_TOLERANCES`` is the list — against committed per-model
+budget goldens (``tests/goldens/budgets/*.json``) with per-metric
+relative tolerances.
 The static executable census makes "traffic can never trigger a
 recompile" a checked invariant rather than a comment.
 
@@ -28,21 +30,20 @@ Budgets regenerate via ``python tests/goldens/budgets/regen_budgets.py``
 (review the diff like source).  Docs: docs/analysis.md "Cost budgets".
 """
 from .budget import (DEFAULT_TOLERANCES, CheckResult, EntryResult,
-                     MetricRow, check_entry, diff_report, environment,
-                     golden_path, load_golden, run_check)
+                     MetricRow, budgeted, check_entry, diff_report,
+                     environment, golden_path, load_golden, run_check)
 from .census import executable_census, grid_signatures
 from .entrypoints import EntryBuild, build, entrypoint, names, source_of
 from .report import (REPORT_VERSION, Program, collective_payload_bytes,
                      instruction_counts, merge_reports,
-                     report_for_programs, unit_report)
+                     report_for_programs)
 
 __all__ = [
     "DEFAULT_TOLERANCES", "CheckResult", "EntryResult", "MetricRow",
-    "check_entry", "diff_report", "environment", "golden_path",
-    "load_golden", "run_check",
+    "budgeted", "check_entry", "diff_report", "environment",
+    "golden_path", "load_golden", "run_check",
     "executable_census", "grid_signatures",
     "EntryBuild", "build", "entrypoint", "names", "source_of",
     "REPORT_VERSION", "Program", "collective_payload_bytes",
     "instruction_counts", "merge_reports", "report_for_programs",
-    "unit_report",
 ]

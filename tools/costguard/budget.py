@@ -2,9 +2,10 @@
 per-metric relative tolerances, and the diff/check machinery the tier-1
 gate and the CLI share.
 
-A golden (``tests/goldens/budgets/<entry>.json``) commits the full
-normalized report plus the environment it was recorded in.  The check
-re-lowers + re-compiles the entry point and compares metric by metric:
+A golden (``tests/goldens/budgets/<entry>.json``) commits the budgeted
+rows of the normalized report (``DEFAULT_TOLERANCES`` names them) plus
+the environment it was recorded in.  The check re-lowers + re-compiles
+the entry point and compares metric by metric:
 
 - within tolerance → ok;
 - above budget beyond tolerance → **REGRESSION**, the gate fails;
@@ -15,7 +16,9 @@ re-lowers + re-compiles the entry point and compares metric by metric:
 
 Goldens gate only in a matching environment (backend + device count):
 CPU byte counts are not TPU byte counts (PERF.md), so a TPU run of the
-same entries reports without gating.
+same entries reports without gating.  The jax version is not part of
+that key: the budgeted rows are meant to survive a bump, and a failing
+entry whose golden was cut under another jax says so.
 """
 from __future__ import annotations
 
@@ -29,38 +32,39 @@ from .report import REPORT_VERSION, report_for_programs
 
 GOLDEN_SUBDIR = Path("tests") / "goldens" / "budgets"
 
-#: dotted metric → relative tolerance.  Tight where the number is
-#: structural (executable count, donation coverage, conv/collective
-#: instruction counts are exact properties of the program), loose where
-#: the compiler has latitude (fusion decisions, buffer assignment).
+#: THE list of budgeted metrics: dotted metric → relative tolerance.
+#: ``regen_budgets.py`` writes these rows of a report and no others.
+#:
+#: A row is here when OUR code decides it: executables a configuration
+#: can compile, donated arguments, bytes of the arguments and of the
+#: collectives' payloads (shapes), devices a program spans, convolutions,
+#: dots and custom calls of its entry computation, and the cost of the
+#: module as lowered.  What XLA:CPU's optimiser decides at toy shapes is
+#: reported and not budgeted: the fusion, copy, collective and total
+#: instruction counts (it combines and splits all-reduces: 321 → 99 in
+#: resnet50 with ``collective_bytes`` equal to the byte; hloguard pins
+#: kinds and counts on the lowered text), its buffer assignment's peak
+#: bytes, the compiled module's flops and bytes.  Over jax 0.4.37 →
+#: 0.9.0 those failed 13 of 16 entries with no change of ours, and hid
+#: the one real stale golden (docs/analysis.md "Cost budgets").
+#:
+#: The ``per_device`` byte rows MIRROR ``memory.argument_bytes`` and
+#: ``collective_bytes`` (worst single executable against the grid's
+#: sum): per_device is the committed unit of the sharded pairs.
 DEFAULT_TOLERANCES: Dict[str, float] = {
+    "n_executables": 0.0,
+    "donation.donated_args": 0.0,
+    "donation.total_args": 0.0,
     "flops": 0.01,
     "bytes_accessed": 0.02,
     "transcendentals": 0.05,
     "collective_bytes": 0.02,
-    "n_executables": 0.0,
-    "memory.peak_bytes": 0.25,
     "memory.argument_bytes": 0.02,
-    "donation.donated_args": 0.0,
-    "donation.total_args": 0.0,
-    "instructions.total": 0.20,
     "instructions.convolution": 0.0,
-    "instructions.collective": 0.0,
     "instructions.dot": 0.15,
-    "instructions.fusion": 0.25,
     "instructions.custom-call": 0.25,
-    "instructions.copy": 0.50,
-    # per-device view of the worst single executable: how many devices
-    # one program spans is structural (exact), its shard-local bytes
-    # follow the memory tolerances — the ∝ 1/shards contract of a
-    # sharded entry lives here.  The byte rows deliberately MIRROR the
-    # memory.* rows (same values, same tolerances — keep them in sync):
-    # per_device is the committed semantic unit of the sharded pairs,
-    # memory the raw extraction; only n_devices and the max-vs-sum
-    # collective_bytes carry new information today
     "per_device.n_devices": 0.0,
     "per_device.argument_bytes": 0.02,
-    "per_device.peak_bytes": 0.25,
     "per_device.collective_bytes": 0.02,
 }
 
@@ -174,6 +178,22 @@ def _lookup(report: dict, dotted: str):
     return cur
 
 
+def budgeted(report: dict) -> dict:
+    """The rows of ``report`` that ``DEFAULT_TOLERANCES`` names, nested
+    as the report nests them: what a golden commits."""
+    out: dict = {}
+    for metric in sorted(DEFAULT_TOLERANCES):
+        value = _lookup(report, metric)
+        if value is None:
+            continue
+        *sections, leaf = metric.split(".")
+        cur = out
+        for part in sections:
+            cur = cur.setdefault(part, {})
+        cur[leaf] = value
+    return out
+
+
 def diff_report(report: dict, golden: dict) -> List[MetricRow]:
     """Per-metric comparison of a fresh report against a golden's.
     Tolerances: golden ``tolerances`` override ``DEFAULT_TOLERANCES``
@@ -255,6 +275,11 @@ def check_entry(name: str, root, use_cache: bool = False,
             f"{built.census} — the golden no longer matches the "
             f"signature grid")
     res.rows = diff_report(res.report, golden)
+    if not res.ok and golden.get("jax_version") != env["jax_version"]:
+        res.problems.append(
+            f"golden cut under jax {golden.get('jax_version')}, running "
+            f"{env['jax_version']}: if the rows above moved with the bump "
+            f"and not with the code, regenerate (regen_budgets.py)")
     return res
 
 

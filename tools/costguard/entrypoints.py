@@ -108,31 +108,6 @@ def _mesh_and_opt(opt_name="sgd", dp=None, **opt_kw):
     return mesh, mx.optimizer.create(opt_name, **opt_kw)
 
 
-def resnet50_train_step(batch=8, fused=False, layout="NHWC",
-                        grad_reduce="f32"):
-    """The headline ResNet-50 train step, AOT only — shared by the
-    ``resnet50_nhwc_train`` budget entry and ``benchmark/hlo_costs.py``
-    (which parameterizes batch/fused for the fused-conv A/B).  Returns
-    ``(step, x, y)`` with the sample batch as HOST arrays: nothing is
-    placed or executed until the caller decides."""
-    import ml_dtypes
-    import numpy as np
-
-    from mxnet_tpu import gluon, parallel
-    from mxnet_tpu.gluon.model_zoo.vision import resnet50_v1
-
-    net = resnet50_v1(layout=layout, fused=fused)
-    net.initialize()
-    net.cast("bfloat16")
-    mesh, opt = _mesh_and_opt("sgd", learning_rate=0.1, momentum=0.9,
-                              wd=1e-4)
-    step = parallel.TrainStep(net, gluon.loss.SoftmaxCrossEntropyLoss(),
-                              opt, mesh=mesh, grad_reduce=grad_reduce)
-    x = np.zeros((batch, 224, 224, 3), ml_dtypes.bfloat16)
-    y = np.zeros((batch,), np.int32)
-    return step, x, y
-
-
 def _train_step_build(name, step, x, y, meta) -> EntryBuild:
     import jax
 
@@ -149,7 +124,22 @@ def _train_step_build(name, step, x, y, meta) -> EntryBuild:
 def build_resnet50_nhwc_train(batch=8):
     """ResNet-50 v1 NHWC bf16 train step (fwd+bwd+SGD momentum, one XLA
     program on the dp mesh) — the PERF.md headline workload."""
-    step, x, y = resnet50_train_step(batch=batch)
+    import ml_dtypes
+    import numpy as np
+
+    from mxnet_tpu import gluon, parallel
+    from mxnet_tpu.gluon.model_zoo.vision import resnet50_v1
+
+    net = resnet50_v1(layout="NHWC")
+    net.initialize()
+    net.cast("bfloat16")
+    mesh, opt = _mesh_and_opt("sgd", learning_rate=0.1, momentum=0.9,
+                              wd=1e-4)
+    step = parallel.TrainStep(net, gluon.loss.SoftmaxCrossEntropyLoss(),
+                              opt, mesh=mesh)
+    # the sample batch as HOST arrays: nothing is placed or executed
+    x = np.zeros((batch, 224, 224, 3), ml_dtypes.bfloat16)
+    y = np.zeros((batch,), np.int32)
     return _train_step_build(
         "resnet50_nhwc_train", step, x, y,
         {"model": "resnet50_v1", "layout": "NHWC", "dtype": "bfloat16",

@@ -1,12 +1,16 @@
-"""Normalized cost reports of compiled XLA programs.
+"""Normalized cost reports of lowered and compiled XLA programs.
 
-Static analysis of the compiled program — ``lower().compile()`` then
-``cost_analysis()`` — is a count of what XLA compiled, not a device
-measurement; it gates structure, not speed.  This module turns one compiled executable into a
-*normalized report* — FLOPs, bytes accessed, compiled-buffer memory,
-entry-computation instruction counts by category, donation coverage —
-and merges per-executable reports into one per-entry-point record that
-``budget.py`` diffs against committed goldens.
+Static analysis of a program is a count, not a device measurement; it
+gates structure, not speed.  This module turns one program unit into a
+*normalized report* and merges per-executable reports into one
+per-entry-point record that ``budget.py`` diffs against committed
+goldens.  FLOPs, bytes accessed and transcendentals are the LOWERED
+module's, before XLA optimises it: what the compiled module reports of
+them is XLA:CPU's fusion and scheduling at toy shapes (bytes moved up to
++168% over one jax bump with no change of ours), what the lowered
+module reports is the program we wrote.  Argument bytes, donation coverage,
+collective payload bytes, the device count and the entry computation's
+instruction categories need the compiled module.
 
 Nothing here ever executes a step: the inputs are AOT ``Lowered`` /
 ``Compiled`` objects (``TrainStep.lower()`` or ``jax.jit(f).lower``),
@@ -21,7 +25,7 @@ from typing import Dict, List, Optional
 #: bump when the report schema or extraction logic changes — it keys the
 #: report cache AND is recorded in budget goldens, so a stale cached
 #: report (or a golden from an older schema) can never pass silently
-REPORT_VERSION = "1.2"
+REPORT_VERSION = "2.0"
 
 # HloModule header attribute stamped by the SPMD partitioner: how many
 # devices one copy of this program spans (1 when absent — a
@@ -168,37 +172,29 @@ def program_num_partitions(hlo_text: str) -> int:
     return 1
 
 
-def unit_report(compiled, n_args: int) -> dict:
-    """Normalized report of ONE compiled executable.
+def unit_report(lowered, n_args: int) -> dict:
+    """Normalized report of ONE program unit: compiles ``lowered``.
 
     Post-SPMD HLO is the PER-DEVICE program: shapes are shard shapes,
     ``memory_analysis`` accounts one device's buffers.  The
     ``per_device`` section makes that semantic explicit (and budgetable
     — a sharded entry commits that these numbers scale as 1/shards),
-    alongside the device count the partitioner stamped."""
-    costs = compiled.cost_analysis()
-    if isinstance(costs, list):
-        costs = costs[0] if costs else {}
+    alongside the device count the partitioner stamped.  The three
+    ``cost_analysis`` rows are the lowered module's, so they count the
+    whole program before it is partitioned."""
+    costs = lowered.cost_analysis() or {}
+    compiled = lowered.compile()
     text = compiled.as_text()
-    mem = {}
     try:
         ma = compiled.memory_analysis()
-        peak = (ma.argument_size_in_bytes + ma.output_size_in_bytes
-                + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
         mem = {"argument_bytes": int(ma.argument_size_in_bytes),
-               "output_bytes": int(ma.output_size_in_bytes),
-               "temp_bytes": int(ma.temp_size_in_bytes),
-               "alias_bytes": int(ma.alias_size_in_bytes),
-               "generated_code_bytes": int(ma.generated_code_size_in_bytes),
-               "peak_bytes": int(peak)}
+               "peak_bytes": int(ma.argument_size_in_bytes
+                                 + ma.output_size_in_bytes
+                                 + ma.temp_size_in_bytes
+                                 - ma.alias_size_in_bytes)}
     except Exception:   # noqa: BLE001 — some backends can't account memory
         mem = {}        # absent, not fabricated: the diff skips it
     wire = float(collective_payload_bytes(text))
-    per_device = {"n_devices": program_num_partitions(text),
-                  "collective_bytes": wire}
-    if mem:
-        per_device["argument_bytes"] = mem["argument_bytes"]
-        per_device["peak_bytes"] = mem["peak_bytes"]
     return {
         "n_executables": 1,
         "flops": float(costs.get("flops", 0.0)),
@@ -206,7 +202,8 @@ def unit_report(compiled, n_args: int) -> dict:
         "transcendentals": float(costs.get("transcendentals", 0.0)),
         "collective_bytes": wire,
         "memory": mem,
-        "per_device": per_device,
+        "per_device": dict(mem, n_devices=program_num_partitions(text),
+                           collective_bytes=wire),
         "donation": donation_counts(text, n_args),
         "instructions": instruction_counts(text),
     }
@@ -292,7 +289,7 @@ def report_for_programs(programs: List[Program], root=None,
         if rec is not None:
             units.append(rec["report"])
             continue
-        u = unit_report(prog.lowered.compile(), prog.n_args)
+        u = unit_report(prog.lowered, prog.n_args)
         units.append(u)
         if cache is not None:
             cache.put(prog.name, key, {"relpath": prog.name, "report": u})

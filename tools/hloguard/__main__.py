@@ -61,7 +61,7 @@ def main(argv=None) -> int:
 
     if args.list:
         for name in surfaces.names():
-            kind = ("tpu-export" if name in surfaces.EXPORT_SURFACES
+            kind = ("tpu-export" if name in surfaces.export_names()
                     else "entrypoint")
             print(f"{name:28s} {kind}")
         return 0

@@ -8,7 +8,9 @@ The run contract mirrors costguard's ``budget.run_check``:
   the rules run over facts every time;
 * a surface gates only when its golden's recorded backend/device-count
   environment matches (CPU-vs-TPU lowering differs structurally — a
-  golden from one bring-up must not fail the other);
+  golden from one bring-up must not fail the other); the jax version is
+  not part of that key, and a failing surface whose golden was cut under
+  another jax says so;
 * both directions fail: an unsuppressed finding AND a stale golden /
   stale suppression — the audited surface stays audited.
 
@@ -265,6 +267,12 @@ def check_entry(name: str, root, use_cache: bool = False,
                  census_findings(name, golden.get("census") or {},
                                  res.census))
     res.findings = _apply_suppressions(found, golden, name, path)
+    if not res.ok and golden.get("jax_version") != env["jax_version"]:
+        res.findings.append(_finding(
+            "hlo-structure", "warning",
+            f"{name}: golden cut under jax {golden.get('jax_version')}, "
+            f"running {env['jax_version']}: if the census moved with the "
+            f"bump and not with the code, regenerate", path))
     return res
 
 

@@ -24,7 +24,7 @@ from . import hlo
 #: bump when facts extraction or any rule's logic changes — keys the
 #: .hloguard_cache signature AND is recorded in structural goldens, so
 #: neither a stale cached record nor an old-schema golden can pass
-REPORT_VERSION = "1.0"
+REPORT_VERSION = "1.1"
 
 #: a parameter smaller than this never raises donation-gap — tiny
 #: scalars/counters are not worth donation plumbing (64 KiB)
@@ -42,6 +42,13 @@ _DTYPE_BYTES = {
     "f8e4m3fn": 1, "f8e5m2": 1,
     "s64": 8, "u64": 8, "f64": 8, "i64": 8, "c64": 8, "c128": 16,
 }
+
+#: custom calls that are jax's own lowering of ``shard_map`` and sharding
+#: constraints (markers for the SPMD partitioner), not part of the program
+#: we wrote: how many it emits is a jax version's business (58 in the tp
+#: decode step under 0.4.37, none under 0.9.0), so the census leaves them out
+_PARTITIONER_MARKERS = frozenset(
+    {"Sharding", "SPMDFullToShardShape", "SPMDShardToFullShape"})
 
 RULES = {
     "donation-gap": (
@@ -159,7 +166,8 @@ def extract_facts(text: str) -> dict:
                     coll_in_while += 1
             elif op.kind in copies:
                 copies[op.kind] += 1
-            if op.kind == "custom_call":
+            if (op.kind == "custom_call"
+                    and op.target not in _PARTITIONER_MARKERS):
                 tgt = op.target or "?"
                 cc_targets[tgt] = cc_targets.get(tgt, 0) + 1
                 if tgt == "tpu_custom_call" and op.payload is not None:
