@@ -17,6 +17,23 @@ v2 design:
 - **Attention-probability dropout runs inside the kernel**: a counter-based
   integer hash (SplitMix32 finaliser) of (head, q-pos, k-pos, seed) drawn
   identically in forward and backward, so no mask is ever materialised.
+
+The block bodies (PR 30; PERF.md 6 has Mosaic's schedules and the chip's
+times):
+- **Operands meet the matrix unit in the dtype they arrive in**
+  (``dot_general`` contracting the last dimensions, float32 out; ``p`` and
+  ``ds`` cast to that dtype for their products), so a bf16 caller multiplies
+  bf16 and a float32 caller float32.  Scores, statistics, ``exp`` and the
+  accumulators are float32 either way.
+- **Row statistics live in the layout they are used in**: ``m`` and ``l`` (and
+  the dq kernel's ``lse`` and ``delta``) as lane-replicated ``(block_q, 128)``
+  scratch, turned from or into the ``(1, block_q)`` rows that cross HBM once a
+  query block; the dk/dv kernel holds the score block transposed, where the
+  rows are what it needs.
+- **Causal pairs**: of a head's block pairs those wholly in the future are
+  skipped and name the block already held, so they fetch nothing; the mask is
+  applied only where the diagonal crosses a pair (at 4,096 positions in
+  blocks of 512: 36 of 64 pairs computed, 8 of them masked).
 """
 from __future__ import annotations
 
@@ -30,6 +47,16 @@ from jax.experimental.pallas import tpu as pltpu
 from ...context import on_tpu
 
 _NEG_INF = -1e30
+_LANES = 128
+# contract the last dimension of both operands: ``a @ b.T`` with no transpose
+_NT = (((1,), (1,)), ((), ()))
+
+
+def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
+    """A product on the matrix unit: operands in the dtype they arrive in,
+    float32 out."""
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
 
 
 def _uniform01(h_idx, q_pos, k_pos, seed):
@@ -52,10 +79,41 @@ def _uniform01(h_idx, q_pos, k_pos, seed):
     return bits.astype(jnp.float32) * (1.0 / 16777216.0)
 
 
-def _positions(bq, bk, qi, kj, block_q, block_k):
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    k_pos = kj * block_k + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+def _positions(shape, qi, kj, block_q, block_k, q_axis=0):
+    """Sequence positions of a score block's queries and keys; the queries
+    run along ``q_axis`` (the dk/dv kernel holds the block transposed)."""
+    q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    k_pos = kj * block_k + jax.lax.broadcasted_iota(jnp.int32, shape,
+                                                    1 - q_axis)
     return q_pos, k_pos
+
+
+def _scores(a, b, scale, masked, dropout, qi, kj, block_q, block_k, q_axis=0):
+    """``scale * a b^T`` in float32, the causal mask applied if the diagonal
+    crosses this pair, and the block's (query, key) positions where the
+    mask or dropout needs them (else None)."""
+    s = _dot(a, b, _NT) * scale
+    if not masked and dropout == 0.0:
+        return s, None
+    q_pos, k_pos = _positions(s.shape, qi, kj, block_q, block_k, q_axis)
+    if masked:
+        s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+    return s, (q_pos, k_pos)
+
+
+def _keep(head, pos, seed, dropout):
+    """The dropout mask of a block's positions (None without dropout): the
+    same draw in the forward and both backward kernels."""
+    if dropout == 0.0:
+        return None
+    return _uniform01(head, *pos, seed) >= dropout
+
+
+def _dropped(x, keep, dropout):
+    """``x`` under the dropout mask ``keep``, rescaled."""
+    if keep is None:
+        return x
+    return jnp.where(keep, x, 0.0) * (1.0 / (1.0 - dropout))
 
 
 def _row_spec(block_q, index_map):
@@ -68,6 +126,16 @@ def _row_spec(block_q, index_map):
 
 
 # ------------------------------------------------------------- forward ------
+def _across(stat, n):
+    """A row statistic kept lane-replicated ``(rows, 128)``, as ``(rows, n)``:
+    whole registers repeated, so nothing moves between lanes."""
+    if n % _LANES == 0:
+        return pltpu.repeat(stat, n // _LANES, axis=1)
+    if n < _LANES:
+        return stat[:, :n]
+    return jnp.broadcast_to(stat[:, :1], (stat.shape[0], n))
+
+
 def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
                 m_ref, l_ref, *, scale, causal, block_q, block_k, n_k,
                 dropout):
@@ -81,52 +149,59 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    def _compute():
-        q = q_ref[0].astype(jnp.float32) * scale      # (bq, D)
-        k = k_ref[0].astype(jnp.float32)              # (bk, D)
-        v = v_ref[0].astype(jnp.float32)
-        s = q @ k.T                                   # (bq, bk)
-        q_pos, k_pos = _positions(s.shape[0], s.shape[1], qi, kj,
-                                  block_q, block_k)
-        if causal:
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-
-        m_prev = m_ref[...]
-        l_prev = l_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
+    def _compute(masked):
+        v = v_ref[0]
+        s, pos = _scores(q_ref[0], k_ref[0], scale, masked, dropout, qi, kj,
+                         block_q, block_k)            # (bq, bk) float32
+        m_prev = m_ref[...]                           # (bq, 128)
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
+        p = jnp.exp(s - _across(m_new, s.shape[1]))
         # l tracks the TRUE softmax normaliser (pre-dropout), so lse is exact
-        l_new = l_prev * alpha + p.sum(axis=-1)
-        if dropout > 0.0:
-            keep = _uniform01(b, q_pos, k_pos, seed_ref[0]) >= dropout
-            p = jnp.where(keep, p, 0.0) * (1.0 / (1.0 - dropout))
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + p @ v
+        l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
         m_ref[...] = m_new
-        l_ref[...] = l_new
+        p = _dropped(p, _keep(b, pos, seed_ref[0], dropout), dropout)
+        acc_ref[...] = (acc_ref[...] * _across(alpha, v.shape[1])
+                        + _dot(p.astype(v.dtype), v))
 
-    if causal:
-        # skip fully-masked future blocks: ~2x fewer matmuls at long S
-        pl.when(kj * block_k <= qi * block_q + block_q - 1)(_compute)
-    else:
-        _compute()
+    _causal_pairs(_compute, causal, qi, kj, block_q, block_k)
 
     @pl.when(kj == n_k - 1)
     def _finish():
         l = l_ref[...]
-        o_ref[0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
-        lse_ref[0, 0, 0] = m_ref[...] + jnp.log(l)
+        o_ref[0] = (acc_ref[...] / _across(l, acc_ref.shape[1])
+                    ).astype(o_ref.dtype)
+        lse_ref[0, 0] = (m_ref[...] + jnp.log(l)).T[:1]
 
 
-def _kv_row(q, k):
-    """Grouped-query heads: ``q`` holds ``group`` times the rows of ``k``
-    (rows are batch-major, then heads, so query row ``b`` reads K/V row
-    ``b // group``).  Returns the map from a query row to its K/V row."""
-    group, rest = divmod(q.shape[0], k.shape[0])
-    assert rest == 0, (q.shape, k.shape)
-    return (lambda b: b) if group == 1 else (lambda b: b // group)
+def _causal_pairs(compute, causal, qi, kj, block_q, block_k):
+    """Run ``compute(masked)`` on the block pair ``(qi, kj)``: not at all
+    where every key lies in the future of every query, with the mask
+    where the diagonal crosses the pair, and without it below."""
+    if not causal:
+        return compute(False)
+    first_q, last_q = qi * block_q, qi * block_q + block_q - 1
+    first_k, last_k = kj * block_k, kj * block_k + block_k - 1
+    pl.when(last_k <= first_q)(lambda: compute(False))
+    pl.when((first_k <= last_q) & (last_k > first_q))(lambda: compute(True))
 
 
+def _needed(causal, block_q, block_k):
+    """Index maps clamped to the blocks a causal pair can use: the last key
+    block of query block ``i`` and the first query block of key block ``j``.
+    A grid step past them names the block it already holds, and fetches
+    nothing."""
+    if not causal:
+        return (lambda i, j: j), (lambda i, j: i)
+    return (lambda i, j: jnp.minimum(j, (i * block_q + block_q - 1)
+                                     // block_k),
+            lambda i, j: jnp.maximum(i, j * block_k // block_q))
+
+
+# jitted, so that a program traces and lowers each kernel once however many
+# layers (and recomputations) call it: twelve calls a step in the benchmark's
+# cell
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8, 9))
 def _flash_fwd(q, k, v, seed, scale, causal, block_q, block_k, interpret,
                dropout):
     bh, s, d = q.shape
@@ -134,7 +209,14 @@ def _flash_fwd(q, k, v, seed, scale, causal, block_q, block_k, interpret,
     block_k = min(block_k, s)
     assert s % block_q == 0 and s % block_k == 0, (s, block_q, block_k)
     n_k = s // block_k
-    kv = _kv_row(q, k)
+    # grouped-query heads: ``q`` holds ``group`` times the rows of ``k``
+    # (batch-major, then heads), so query row ``b`` reads K/V row b // group
+    group, rest = divmod(bh, k.shape[0])
+    assert rest == 0, (q.shape, k.shape)
+    last_k, _ = _needed(causal, block_q, block_k)
+    kv_spec = pl.BlockSpec((1, block_k, d),
+                           lambda b, i, j: (b // group, last_k(i, j), 0))
+    q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, block_q=block_q,
         block_k=block_k, n_k=n_k, dropout=dropout)
@@ -143,12 +225,10 @@ def _flash_fwd(q, k, v, seed, scale, causal, block_q, block_k, interpret,
         grid=(bh, s // block_q, n_k),
         in_specs=[
             pl.BlockSpec((1,), lambda b, i, j: (0,)),
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (kv(b), j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (kv(b), j, 0)),
+            q_spec, kv_spec, kv_spec,
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+            q_spec,
             _row_spec(block_q, lambda b, i, j: (b, i, 0, 0)),
         ],
         out_shape=[
@@ -158,8 +238,8 @@ def _flash_fwd(q, k, v, seed, scale, causal, block_q, block_k, interpret,
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, d), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
         name="flash_attention_fwd",
         interpret=interpret,
@@ -168,23 +248,18 @@ def _flash_fwd(q, k, v, seed, scale, causal, block_q, block_k, interpret,
 
 
 # ------------------------------------------------------------ backward ------
-def _recompute_p(q_ref, k_ref, lse_ref, b, qi, kj, scale, causal,
-                 block_q, block_k):
-    q = q_ref[0].astype(jnp.float32) * scale
-    k = k_ref[0].astype(jnp.float32)
-    s = q @ k.T
-    q_pos, k_pos = _positions(s.shape[0], s.shape[1], qi, kj,
-                              block_q, block_k)
-    if causal:
-        s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-    # true softmax probs (pre-dropout)
-    p = jnp.exp(s - lse_ref[0, 0, 0][:, None])
-    return p, q_pos, k_pos
+def _columns(row):
+    """A ``(1, n)`` row of per-query statistics as ``(n, 128)``, lane-
+    replicated: one transpose where the registers allow it."""
+    n = row.shape[1]
+    if n % _LANES == 0:
+        return jnp.broadcast_to(row, (_LANES, n)).T
+    return jnp.broadcast_to(row.reshape(n, 1), (n, _LANES))
 
 
 def _dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-               dq_ref, dq_acc, *, scale, causal, block_q, block_k, n_k,
-               dropout):
+               dq_ref, dq_acc, lse_col, delta_col, *, scale, causal,
+               block_q, block_k, n_k, dropout):
     b = pl.program_id(0)
     qi = pl.program_id(1)
     kj = pl.program_id(2)
@@ -192,135 +267,122 @@ def _dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     @pl.when(kj == 0)
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
+        # the statistics arrive as rows; every pair of this query block
+        # uses them as columns
+        lse_col[...] = _columns(lse_ref[0, 0])
+        delta_col[...] = _columns(delta_ref[0, 0])
 
-    def _compute():
-        p, q_pos, k_pos = _recompute_p(q_ref, k_ref, lse_ref, b, qi, kj,
-                                       scale, causal, block_q, block_k)
-        do = do_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        dp = do @ v.T                                 # (bq, bk)
-        if dropout > 0.0:
-            keep = _uniform01(b, q_pos, k_pos, seed_ref[0]) >= dropout
-            dp = jnp.where(keep, dp, 0.0) * (1.0 / (1.0 - dropout))
-        ds = p * (dp - delta_ref[0, 0, 0][:, None])
-        dq_acc[...] += (ds @ k_ref[0].astype(jnp.float32)) * scale
+    def _compute(masked):
+        k = k_ref[0]
+        s, pos = _scores(q_ref[0], k, scale, masked, dropout, qi, kj,
+                         block_q, block_k)            # (bq, bk) float32
+        # true softmax probs (pre-dropout)
+        p = jnp.exp(s - _across(lse_col[...], s.shape[1]))
+        dp = _dropped(_dot(do_ref[0], v_ref[0], _NT),
+                      _keep(b, pos, seed_ref[0], dropout), dropout)
+        ds = p * (dp - _across(delta_col[...], s.shape[1]))
+        dq_acc[...] += _dot(ds.astype(k.dtype), k)
 
-    if causal:
-        pl.when(kj * block_k <= qi * block_q + block_q - 1)(_compute)
-    else:
-        _compute()
+    _causal_pairs(_compute, causal, qi, kj, block_q, block_k)
 
     @pl.when(kj == n_k - 1)
     def _finish():
-        dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
+        dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
 def _dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal, block_q,
-                block_k, n_q, dropout):
-    b = pl.program_id(0)
+                block_k, group, n_q, dropout):
+    """The score block TRANSPOSED, keys down and queries across: the row
+    statistics are rows as they arrive, and ``p^T do`` and ``ds^T q`` are
+    plain products.  A K/V head accumulates over its ``group`` query
+    heads."""
+    bkv = pl.program_id(0)
     kj = pl.program_id(1)
-    qi = pl.program_id(2)
+    g = pl.program_id(2)
+    qi = pl.program_id(3)
 
-    @pl.when(qi == 0)
+    @pl.when((g == 0) & (qi == 0))
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    def _compute():
-        p, q_pos, k_pos = _recompute_p(q_ref, k_ref, lse_ref, b, qi, kj,
-                                       scale, causal, block_q, block_k)
-        do = do_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        if dropout > 0.0:
-            keep = _uniform01(b, q_pos, k_pos, seed_ref[0]) >= dropout
-            pd = jnp.where(keep, p, 0.0) * (1.0 / (1.0 - dropout))
-        else:
-            pd = p
-        dv_acc[...] += pd.T @ do
-        dp = do @ v.T
-        if dropout > 0.0:
-            dp = jnp.where(keep, dp, 0.0) * (1.0 / (1.0 - dropout))
-        ds = p * (dp - delta_ref[0, 0, 0][:, None])
-        dk_acc[...] += (ds.T @ (q_ref[0].astype(jnp.float32))) * scale
+    def _compute(masked):
+        q = q_ref[0]
+        do = do_ref[0]
+        st, pos = _scores(k_ref[0], q, scale, masked, dropout, qi, kj,
+                          block_q, block_k, q_axis=1)  # (bk, bq) float32
+        pt = jnp.exp(st - lse_ref[0, 0])
+        dpt = _dot(v_ref[0], do, _NT)
+        keep = _keep(bkv * group + g, pos, seed_ref[0], dropout)
+        dv_acc[...] += _dot(_dropped(pt, keep, dropout).astype(do.dtype), do)
+        dst = pt * (_dropped(dpt, keep, dropout) - delta_ref[0, 0])
+        dk_acc[...] += _dot(dst.astype(q.dtype), q)
 
-    if causal:
-        pl.when(kj * block_k <= qi * block_q + block_q - 1)(_compute)
-    else:
-        _compute()
+    _causal_pairs(_compute, causal, qi, kj, block_q, block_k)
 
-    @pl.when(qi == n_q - 1)
+    @pl.when((g == group - 1) & (qi == n_q - 1))
     def _finish():
-        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+        dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
+@functools.partial(jax.jit, static_argnums=(7, 8, 9, 10, 11, 12))
 def _flash_bwd(q, k, v, seed, o, lse, do, scale, causal, block_q, block_k,
                interpret, dropout):
     bh, s, d = q.shape
     block_q = min(block_q, s)
     block_k = min(block_k, s)
     n_q, n_k = s // block_q, s // block_k
-    kv = _kv_row(q, k)
+    group = bh // k.shape[0]
+    last_k, first_q = _needed(causal, block_q, block_k)
     delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
     lse = lse.reshape(bh, n_q, 1, block_q)
     delta = delta.reshape(bh, n_q, 1, block_q)
 
+    q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
+    kv_spec = pl.BlockSpec((1, block_k, d),
+                           lambda b, i, j: (b // group, last_k(i, j), 0))
+    row_spec = _row_spec(block_q, lambda b, i, j: (b, i, 0, 0))
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, n_k=n_k,
                           dropout=dropout),
         grid=(bh, n_q, n_k),
-        in_specs=[
-            pl.BlockSpec((1,), lambda b, i, j: (0,)),
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (kv(b), j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (kv(b), j, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            _row_spec(block_q, lambda b, i, j: (b, i, 0, 0)),
-            _row_spec(block_q, lambda b, i, j: (b, i, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+        in_specs=[pl.BlockSpec((1,), lambda b, i, j: (0,)),
+                  q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
+                        pltpu.VMEM((block_q, _LANES), jnp.float32),
+                        pltpu.VMEM((block_q, _LANES), jnp.float32)],
         name="flash_attention_bwd_dq",
         interpret=interpret,
     )(seed, q, k, v, do, lse, delta)
 
-    # grouped-query heads: the kernel writes one float32 part per QUERY
-    # head, and a K/V head's gradient is the sum over its group
-    grouped = k.shape[0] != bh
-    part = jnp.float32 if grouped else k.dtype
+    # grouped-query heads: a K/V head's gradient is the sum over its group
+    # of query heads, which the grid walks before it moves to the next block
+    q_spec = pl.BlockSpec(
+        (1, block_q, d),
+        lambda b, j, g, i: (b * group + g, first_q(i, j), 0))
+    kv_spec = pl.BlockSpec((1, block_k, d), lambda b, j, g, i: (b, j, 0))
+    row_spec = _row_spec(
+        block_q, lambda b, j, g, i: (b * group + g, first_q(i, j), 0, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, n_q=n_q,
-                          dropout=dropout),
-        grid=(bh, n_k, n_q),
-        in_specs=[
-            pl.BlockSpec((1,), lambda b, j, i: (0,)),
-            pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (kv(b), j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (kv(b), j, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
-            _row_spec(block_q, lambda b, j, i: (b, i, 0, 0)),
-            _row_spec(block_q, lambda b, j, i: (b, i, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, s, d), part),
-            jax.ShapeDtypeStruct((bh, s, d), part),
-        ],
+                          block_q=block_q, block_k=block_k, group=group,
+                          n_q=n_q, dropout=dropout),
+        grid=(k.shape[0], n_k, group, n_q),
+        in_specs=[pl.BlockSpec((1,), lambda b, j, g, i: (0,)),
+                  q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        out_specs=[kv_spec, kv_spec],
+        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         name="flash_attention_bwd_dkv",
         interpret=interpret,
     )(seed, q, k, v, do, lse, delta)
-    if grouped:
-        dk, dv = (g.reshape(k.shape[0], -1, s, d).sum(axis=1).astype(k.dtype)
-                  for g in (dk, dv))
     return dq, dk, dv
 
 

@@ -252,3 +252,138 @@ def test_bert_flash_dropout_trains():
             loss = (out ** 2).mean()
         loss.backward()
     assert np.isfinite(float(loss.asnumpy()))
+
+
+# ---------------------------------------------------------------------------
+# the block bodies of PR 30: operands in the dtype they arrive in, the row
+# statistics lane-replicated, the mask only where the diagonal crosses a
+# pair, K/V index maps clamped to the blocks a causal pair can use
+# ---------------------------------------------------------------------------
+def _dense_f32(q, k, v, causal, scale=None):
+    """softmax(q k^T) v in float32 on upcast operands, (B*H, S, D) with
+    grouped-query K/V repeated: the formulation the kernels round from."""
+    import jax
+    import jax.numpy as jnp
+    q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
+    group = q.shape[0] // k.shape[0]
+    k, v = (jnp.repeat(a, group, axis=0) for a in (k, v))
+    scale = 1.0 / np.sqrt(q.shape[-1]) if scale is None else scale
+    s = jnp.einsum("bqd,bkd->bqk", q, k) * scale
+    if causal:
+        n = s.shape[-1]
+        s = jnp.where(jnp.tril(jnp.ones((n, n), bool)), s, -1e30)
+    return jnp.einsum("bqk,bkd->bqd", jax.nn.softmax(s, axis=-1), v)
+
+
+def _out_and_grads(fn, q, k, v, w):
+    import jax
+    import jax.numpy as jnp
+    out = fn(q, k, v)
+    grads = jax.grad(lambda *a: (fn(*a).astype(jnp.float32) * w).sum(),
+                     (0, 1, 2))(q, k, v)
+    return dict(zip(("out", "dq", "dk", "dv"), (out,) + tuple(grads)))
+
+
+def _rel(got, want):
+    got, want = (np.asarray(a, np.float32) for a in (got, want))
+    return np.linalg.norm(got - want) / np.linalg.norm(want)
+
+
+@pytest.fixture(scope="module")
+def bf16_against_f32():
+    """bf16 q, k, v through the kernels (two blocks each way, causal) and
+    the same operands upcast through dense float32 attention."""
+    import jax.numpy as jnp
+    from mxnet_tpu.ops.pallas.flash_attention import flash_attention
+    rng = np.random.RandomState(7)
+    q, k, v = (jnp.asarray(rng.randn(4, 64, 16) * 0.5, jnp.bfloat16)
+               for _ in range(3))
+    w = jnp.asarray(rng.randn(4, 64, 16), jnp.float32)
+    got = _out_and_grads(
+        lambda *a: flash_attention(*a, causal=True, block_q=32, block_k=32),
+        q, k, v, w)
+    want = _out_and_grads(lambda *a: _dense_f32(*a, True), q, k, v, w)
+    return got, want
+
+
+@pytest.mark.parametrize("which", ["out", "dq", "dk", "dv"])
+def test_flash_bf16_operands_round_the_float32_formulation(
+        bf16_against_f32, which):
+    """The kernels multiply bf16 operands as they arrive (probabilities and
+    ds rounded to bf16 for their products, float32 accumulation): within
+    bf16 rounding of the float32 formulation, and of the input's dtype."""
+    got, want = bf16_against_f32
+    assert str(got[which].dtype) == "bfloat16"
+    assert _rel(got[which], want[which]) < 1e-2
+
+
+@pytest.mark.parametrize("block_q,block_k", [(16, 32), (32, 16), (64, 16),
+                                             (16, 64), (128, 64)])
+def test_flash_causal_rectangular_blocks(block_q, block_k):
+    """The diagonal crossing a rectangular block: pairs wholly in the past
+    run unmasked, pairs the diagonal crosses masked, pairs wholly in the
+    future not at all and their index clamped; forward and gradients."""
+    import jax.numpy as jnp
+    from mxnet_tpu.ops.pallas.flash_attention import flash_attention
+    rng = np.random.RandomState(8)
+    q, k, v, w = (jnp.asarray(rng.randn(2, 128, 8) * 0.5, jnp.float32)
+                  for _ in range(4))
+    got = _out_and_grads(
+        lambda *a: flash_attention(*a, causal=True, block_q=block_q,
+                                   block_k=block_k), q, k, v, w)
+    want = _out_and_grads(lambda *a: _dense_f32(*a, True), q, k, v, w)
+    for name in got:
+        np.testing.assert_allclose(got[name], want[name],
+                                   **_tol(2e-4, 2e-5, 1e-2), err_msg=name)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_grouped_40_20_heads_lane_replicated_statistics(causal):
+    """phi4_mini_flash's 40 query and 20 K/V heads at blocks of 128, where
+    the statistics are whole registers (``_across`` repeats, ``_columns``
+    transposes) and dk/dv accumulate over a group inside the kernel."""
+    import jax.numpy as jnp
+    from mxnet_tpu.ops.pallas.flash_attention import flash_attention
+    rng = np.random.RandomState(9)
+    q, w = (jnp.asarray(rng.randn(40, 256, 8) * 0.5, jnp.float32)
+            for _ in range(2))
+    k, v = (jnp.asarray(rng.randn(20, 256, 8) * 0.5, jnp.float32)
+            for _ in range(2))
+    got = _out_and_grads(
+        lambda *a: flash_attention(*a, causal=causal, block_q=128,
+                                   block_k=128), q, k, v, w)
+    want = _out_and_grads(lambda *a: _dense_f32(*a, causal), q, k, v, w)
+    assert got["dk"].shape == k.shape and got["dv"].shape == v.shape
+    for name in got:
+        np.testing.assert_allclose(got[name], want[name],
+                                   **_tol(2e-4, 2e-5, 1e-2), err_msg=name)
+
+
+# what the parent's kernels (float32 blocks, q scaled before the product)
+# gave for these float32 inputs on XLA:CPU: sums of |.| of the output and the
+# three gradients of test_flash_float32_input_gives_todays_result
+_FLOAT32_PARENT = {"out": 167.4639434814453, "dq": 39.43153762817383,
+                   "dk": 29.73274803161621, "dv": 152.4369659423828}
+
+
+@pytest.mark.parametrize("which", ["out", "dq", "dk", "dv"])
+def test_flash_float32_input_gives_todays_result(which):
+    """A float32 caller keeps float32 operands: no bf16 value anywhere in
+    the three kernels, and the numbers the parent's kernels gave."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops.pallas.flash_attention import flash_attention
+    rng = np.random.RandomState(10)
+    q, k, v, w = (jnp.asarray(rng.randn(2, 64, 16) * 0.5, jnp.float32)
+                  for _ in range(4))
+    fn = lambda *a: flash_attention(*a, causal=True, block_q=32,  # noqa: E731
+                                    block_k=16)
+    got = _out_and_grads(fn, q, k, v, w)[which]
+    assert got.dtype == jnp.float32
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda *a: (fn(*a) * w).sum(), (0, 1, 2)))(q, k, v))
+    assert "bf16" not in text
+    if mx.context.on_tpu():
+        return      # the sums are XLA:CPU's
+    np.testing.assert_allclose(float(jnp.abs(got).sum()),
+                               _FLOAT32_PARENT[which], rtol=2e-6)

@@ -499,8 +499,8 @@ def test_cli_end_to_end_json():
     # two layers, ONE lowering of each kernel: _scan_fwd and _scan_bwd
     # are jax.jits (PR 28), so the second layer calls the first's
     ("pallas_selective_scan_tpu", 2, 2),
-    # plain functions: each layer lowers its own fwd, dq and dk/dv
-    ("pallas_flash_attention_tpu", 6, 3),
+    # the same for flash attention's fwd, dq and dk/dv (PR 30)
+    ("pallas_flash_attention_tpu", 3, 3),
     # the fused tower repeats one 3x3 geometry
     ("pallas_fused_conv_tpu", 3, 2),
     # paged attention runs two geometries of one kernel

@@ -436,6 +436,29 @@ def test_scan_kernels_lower_for_the_tpu_at_the_cell_shape(name, grad):
     assert f'kernel_name = "{name}"' in text
 
 
+@pytest.mark.parametrize("name", ["flash_attention_fwd",
+                                  "flash_attention_bwd_dq",
+                                  "flash_attention_bwd_dkv"])
+def test_flash_kernels_lower_for_the_tpu_at_the_cell_shape(name):
+    """The same for the flash kernels: 40 query and 20 K/V heads of 64 over
+    4,096 positions in bf16, causal, the block ``sambay.py`` gives (whole
+    registers of row statistics, their transposes, the clamped index
+    maps)."""
+    from mxnet_tpu.gluon.model_zoo.sambay import _flash_block
+    from mxnet_tpu.ops.pallas.flash_attention import flash_attention
+    q = jax.ShapeDtypeStruct((40, 4096, 64), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((20, 4096, 64), jnp.bfloat16)
+    block = _flash_block(4096)
+
+    def total(q, k, v):
+        return flash_attention(q, k, v, causal=True, block_q=block,
+                               block_k=block, interpret=False
+                               ).astype(jnp.float32).sum()
+    text = jax.jit(jax.grad(total, (0, 1, 2))).trace(q, kv, kv).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert f'kernel_name = "{name}"' in text
+
+
 def _scope_paths(lowered):
     return set(re.findall(r'loc\("([^"]*)"', lowered.as_text(debug_info=True)))
 

@@ -16,12 +16,14 @@ if not on_tpu():
 
 from test_flash_attention import *   # noqa: F401,F403,E402
 
+from mxnet_tpu.gluon.model_zoo.sambay import _flash_block  # noqa: E402
 from mxnet_tpu.ops.pallas.flash_attention import flash_attention  # noqa: E402
 
 
 def _dense(q, k, v, causal):
     """float32 reference of softmax(QK^T/sqrt(d))V on (B*H, S, D)."""
     q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
+    k, v = (jnp.repeat(a, q.shape[0] // k.shape[0], axis=0) for a in (k, v))
     s = jnp.einsum("bqd,bkd->bqk", q, k) / np.sqrt(q.shape[-1])
     if causal:
         n = s.shape[-1]
@@ -30,19 +32,22 @@ def _dense(q, k, v, causal):
 
 
 # BERT-base at batch 64 (12 heads x 64, seq 128); a causal long-sequence
-# shape; both bf16 — what bench_bert / bert_pretrain.py would feed it
-@pytest.mark.parametrize("bh,s,d,causal,dropout", [
-    (768, 128, 64, False, 0.0),
-    (768, 128, 64, False, 0.1),
-    (96, 512, 64, True, 0.0),
+# shape; phi4_mini_flash.train_s4096's layer (40 query and 20 K/V heads of
+# 64, causal, the block sambay.py gives) at the 2,048 positions whose dense
+# float32 reference and its backward fit beside it; all bf16
+@pytest.mark.parametrize("bh,bh_kv,s,d,causal,dropout,block", [
+    (768, 768, 128, 64, False, 0.0, 128),
+    (768, 768, 128, 64, False, 0.1, 128),
+    (96, 96, 512, 64, True, 0.0, 128),
+    (40, 20, 2048, 64, True, 0.0, _flash_block(2048)),
 ])
-def test_flash_real_shapes_compiled(bh, s, d, causal, dropout):
+def test_flash_real_shapes_compiled(bh, bh_kv, s, d, causal, dropout, block):
     """Forward and backward compile on Mosaic at the real shapes (three
     custom calls: fwd, dq, dk/dv — not the interpreter), and without
     dropout agree with dense float32 attention to bf16 accuracy."""
     rng = np.random.RandomState(0)
-    q, k, v = (jnp.asarray(rng.randn(bh, s, d) * 0.5, jnp.bfloat16)
-               for _ in range(3))
+    q, k, v = (jnp.asarray(rng.randn(n, s, d) * 0.5, jnp.bfloat16)
+               for n in (bh, bh_kv, bh_kv))
     seed = jnp.asarray([11], jnp.int32)
 
     def loss(fn):
@@ -50,6 +55,7 @@ def test_flash_real_shapes_compiled(bh, s, d, causal, dropout):
 
     flash = jax.jit(jax.value_and_grad(loss(
         lambda q, k, v: flash_attention(q, k, v, causal=causal,
+                                        block_q=block, block_k=block,
                                         dropout=dropout, seed=seed)),
         argnums=(0, 1, 2)))
     assert flash.lower(q, k, v).as_text().count("tpu_custom_call") == 3

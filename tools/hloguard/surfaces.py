@@ -124,8 +124,9 @@ def _two_layer_step(layer):
     geometry="q bf16[40,256,64], k/v bf16[20,256,64], blocks of 128: "
              "phi4_mini_flash's 40/20 heads of 64 at a short sequence")
 def _flash_attention_programs():
-    """The kernels are plain functions, so each of the two layers lowers
-    its own three: the census reads total 6, unique 3."""
+    """``_flash_fwd`` and ``_flash_bwd`` are ``jax.jit``s, so two layers
+    share one lowering of each of the three kernels (PR 30): total 3,
+    unique 3.  A fourth payload is a kernel traced again."""
     import functools
 
     import jax
