@@ -6,4 +6,5 @@ from . import ssd
 from . import language_model
 from . import causal_lm
 from . import sambay
+from . import moe_decoder
 from .vision import get_model

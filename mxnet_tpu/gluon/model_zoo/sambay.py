@@ -36,7 +36,9 @@ from ... import initializer as init_mod
 from ... import random as _random
 from ...base import dtype_np
 from ..block import HybridBlock
-from ..nn import Dense, Embedding, LayerNorm
+from ..nn import Embedding, LayerNorm
+from ._attention import (_Attention, _causal_attention, _dense,  # noqa: F401
+                         _flash_block)
 
 __all__ = ["SambaY", "SambaYLayer", "layer_kinds", "KINDS"]
 
@@ -59,12 +61,6 @@ def layer_kinds(n):
             return "full"
         return "gmu" if i % 2 == 0 else "cross"
     return [kind(i) for i in range(n)]
-
-
-def _flash_block(t):
-    """Query and key block of the flash kernel: the largest of 512, 256,
-    128 that divides the sequence, else the sequence whole."""
-    return next((b for b in (512, 256, 128) if t % b == 0), t)
 
 
 class _Everywhere(init_mod.Initializer):
@@ -96,11 +92,6 @@ class _DtBias(_Everywhere):
         dt = jnp.exp(u * (math.log(self.hi) - math.log(self.lo))
                      + math.log(self.lo))
         return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype_np(dtype))
-
-
-def _dense(units, in_units):
-    return Dense(units, use_bias=False, flatten=False, in_units=in_units,
-                 weight_initializer=init_mod.Normal(0.02))
 
 
 class _Mamba(HybridBlock):
@@ -143,37 +134,6 @@ class _Mamba(HybridBlock):
             y = F.selective_scan(xc, dt, -F.exp(a_log.astype("float32")),
                                  b, c, d)
         return self.out_proj(y * F.silu(z)), y
-
-
-class _Attention(HybridBlock):
-    """``window`` (an int) or ``full`` (None) self-attention, grouped-query,
-    one fused QKV projection laid out [Q; K; V]."""
-
-    def __init__(self, hidden, heads, kv_heads, window, **kwargs):
-        super().__init__(**kwargs)
-        self._heads, self._kv_heads, self._window = heads, kv_heads, window
-        self._hidden, self._kv = hidden, hidden // heads * kv_heads
-        with self.name_scope():
-            self.qkv = _dense(hidden + 2 * self._kv, hidden)
-            self.out_proj = _dense(hidden, hidden)
-
-    def forward(self, u):
-        from ... import ndarray as F
-        q, k, v = F.split_v2(
-            self.qkv(u), axis=-1,
-            indices=(self._hidden, self._hidden + self._kv))
-        if self._window is not None:
-            return self.out_proj(F.window_attention(
-                q, k, v, heads=self._heads, kv_heads=self._kv_heads,
-                window=self._window))
-        return self.out_proj(_causal_attention(
-            F, q, k, v, self._heads, self._kv_heads)), k, v
-
-
-def _causal_attention(F, q, k, v, heads, kv_heads):
-    block = _flash_block(q.shape[1])
-    return F.flash_attention(q, k, v, heads=heads, kv_heads=kv_heads,
-                             causal=True, block_q=block, block_k=block)
 
 
 class _GMU(HybridBlock):

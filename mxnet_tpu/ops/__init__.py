@@ -20,6 +20,7 @@ from . import loss  # noqa: F401
 from . import rnn  # noqa: F401
 from . import attention  # noqa: F401
 from . import state_space  # noqa: F401
+from . import rotary  # noqa: F401
 from . import paged_attention  # noqa: F401
 from . import image  # noqa: F401
 from . import multibox  # noqa: F401

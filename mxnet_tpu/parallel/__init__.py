@@ -14,7 +14,8 @@ from .mesh import (AXES, MeshScope, current_mesh, default_mesh, make_mesh,
 from .sharding import (ShardingRules, batch_spec, causal_lm_tp_rules,
                        fsdp_rules, param_sharding, tp_dense_rules)
 from .functional import functional_call, param_names_and_values
-from .moe import MoEFFN, moe_dispatch
+from .moe import (DroplessMoEFFN, MoEFFN, moe_dispatch, publish_load,
+                  route_topk)
 from .pipeline import PipelineStack, gpipe
 from .sequence import ring_attention, sp_attention, ulysses_attention
 from .prefetch import DevicePrefetcher
@@ -43,7 +44,7 @@ __all__ = [
     "functional_call", "param_names_and_values",
     "ring_attention", "sp_attention", "ulysses_attention",
     "PipelineStack", "gpipe",
-    "MoEFFN", "moe_dispatch",
+    "MoEFFN", "moe_dispatch", "DroplessMoEFFN", "route_topk", "publish_load",
     "EvalStep", "TrainStep", "DevicePrefetcher",
     "add_transfer_hook", "remove_transfer_hook",
     "GRAD_REDUCE_MODES", "quantize_chunked", "dequantize_chunked",

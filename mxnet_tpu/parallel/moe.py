@@ -1,19 +1,35 @@
-"""Mixture-of-Experts with expert parallelism over the ``ep`` mesh axis.
+"""Mixture-of-Experts layers, two paths.
 
-The reference has NO MoE (SURVEY.md §2.3: EP absent).  TPU-native design: the
-whole layer is dense einsums over fixed shapes — top-k gating, capacity-
-bounded one-hot dispatch/combine tensors (the Mesh-TensorFlow / GShard
-formulation), stacked expert weights with leading dim E annotated onto the
-``ep`` axis.  GSPMD partitions the einsums and inserts the all-to-alls; no
-hand-written collectives needed, and the whole thing jits into the fused
-train step like any other layer.
+**The one-hot path** (``moe_dispatch``, ``MoEFFN``; GShard / Mesh-TensorFlow):
+top-k gating into capacity-bounded one-hot dispatch and combine tensors ``(N,
+E, C)``, dense einsums over fixed shapes, stacked GELU experts with biases
+whose leading dim E is annotated onto the ``ep`` axis: GSPMD partitions the
+einsums and inserts the all-to-alls.  Tokens beyond an expert's capacity are
+DROPPED, and the dispatch einsums cost more than the experts once groups are
+thousands of tokens and experts dozens.  It is the path of the small ``ep``
+demos (``tests/test_moe.py``, ``__graft_entry__.py``), kept for them.
+
+**The dropless path** (``route_topk``, the op ``moe_dropless_ffn``,
+``DroplessMoEFFN``): a softmax router over all ``num_experts``, top-k,
+re-normalised; the block is told which experts it HOLDS (``first_expert``,
+``held``: one chip's share under expert parallelism), sorts the token-expert
+assignments that land on its own experts to the front, runs two grouped
+matrix products (``jax.lax.ragged_dot``) over them with ``silu(g) * u``
+between (gated experts, no bias), and sums its experts' weighted results back
+per token.  No capacity, no dropped token whatever the imbalance: the row
+buffer holds all ``N * k`` assignments.  What the absent experts would have
+added is left out; on one chip the layer runs without an exchange.  This is
+the path for a model whose experts are routed per token at real widths.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-__all__ = ["moe_dispatch", "MoEFFN"]
+from ..ops.registry import register_op
+
+__all__ = ["moe_dispatch", "MoEFFN", "route_topk", "DroplessMoEFFN",
+           "publish_load"]
 
 
 def moe_dispatch(gate_logits, num_experts, capacity, k=2, valid=None):
@@ -108,8 +124,6 @@ def _moe_ffn_op(tokens, gate_w, w1, b1, w2, b2, num_experts=1, capacity=1,
     return out[:n], aux
 
 
-from ..ops.registry import register_op  # noqa: E402
-
 register_op("moe_ffn", _moe_ffn_op)
 
 
@@ -178,3 +192,192 @@ def _make_moe_ffn():
 
 
 MoEFFN = _make_moe_ffn()
+
+# ---------------------------------------------------------------- dropless --
+# the router's logits, softmax and gates, whatever the tokens' type: top-k
+# over 64 near-equal probabilities does not survive bf16
+_ROUTER_DTYPE = jnp.float32
+
+
+def route_topk(logits, k, renormalise=True):
+    """``softmax(logits)`` over the experts in float32, its ``k`` largest
+    per token and their experts: ``(gates (N, k) float32, experts (N, k)
+    int32)``.  ``renormalise`` divides the gates by their sum over the chosen
+    k (``norm_topk_prob``)."""
+    probs = jax.nn.softmax(logits.astype(_ROUTER_DTYPE), axis=-1)
+    gates, experts = jax.lax.top_k(probs, k)
+    if renormalise:
+        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    return gates, experts.astype(jnp.int32)
+
+
+@jax.custom_vjp
+def _gather_rows(x, source, back):
+    """``x[source]``, for ``source`` (M,) that reads every one of x's N rows
+    exactly M / N times and ``back`` (N, M / N) listing, row of x by row, the
+    places that read it.  The backward pass is then a gather too (``dy[back]``
+    summed per row), where autodiff would scatter-add M rows."""
+    return x[source]
+
+
+def _gather_rows_fwd(x, source, back):
+    return x[source], back
+
+
+def _gather_rows_bwd(back, dy):
+    return jnp.sum(dy[back], axis=1).astype(dy.dtype), None, None
+
+
+_gather_rows.defvjp(_gather_rows_fwd, _gather_rows_bwd)
+
+
+@register_op("moe_dropless_ffn")
+def _moe_dropless_ffn(tokens, router, gate_up, down, num_experts=1,
+                      first_expert=0, k=1, renormalise=True):
+    """Dropless top-k expert layer on ``tokens`` (N, d) for the experts
+    ``first_expert .. first_expert + held`` of ``num_experts``: ``router``
+    (d, E), ``gate_up`` (held, d, 2F), ``down`` (held, F, d).  Returns the
+    held experts' part of ``sum_{e in top-k} w_e W_down,e (silu(W_gate,e u)
+    * W_up,e u)`` (N, d), ``w_e`` normalised over all k chosen whether held
+    or not, and the assignments per expert (E,) int32 of this call.
+
+    The N * k assignments are sorted by held expert, the unheld behind the
+    held; the held rows run as two grouped products whose groups are the
+    experts' loads.  Rows past the groups' sum belong to no held expert:
+    they are zeroed on the way in and on the way out (a grouped product
+    need not visit them), in the backward pass too."""
+    n, d = tokens.shape
+    held = gate_up.shape[0]
+    with jax.named_scope("router"):
+        logits = jnp.dot(tokens, router.astype(tokens.dtype),
+                         preferred_element_type=_ROUTER_DTYPE)
+        gates, experts = route_topk(logits, k, renormalise)
+        flat = experts.reshape(n * k)
+        load = jnp.sum(flat[:, None] == jnp.arange(num_experts)[None, :],
+                       axis=0, dtype=jnp.int32)
+    with jax.named_scope("dispatch"):
+        local = flat - first_expert
+        key = jnp.where((local >= 0) & (local < held), local, held)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        at = jnp.arange(n * k, dtype=jnp.int32)
+        inverse = jnp.zeros_like(order).at[order].set(at, unique_indices=True)
+        sizes = load[first_expert:first_expert + held]
+        mine = (at < jnp.sum(sizes))[:, None]
+        rows = jnp.where(mine, _gather_rows(tokens, order // k,
+                                            inverse.reshape(n, k)), 0)
+    with jax.named_scope("experts"):
+        gate, up = jnp.split(jax.lax.ragged_dot(rows, gate_up, sizes), 2,
+                             axis=-1)
+        out = jax.lax.ragged_dot(jax.nn.silu(gate) * up, down, sizes)
+        out = jnp.where(mine, out, 0)
+    with jax.named_scope("combine"):
+        out = _gather_rows(out, inverse, order[:, None]).reshape(n, k, d)
+        out = jnp.sum(out.astype(jnp.float32) * gates[..., None], axis=1)
+    return out.astype(tokens.dtype), load
+
+
+def _make_dropless_moe_ffn():
+    from .. import initializer as init_mod
+    from ..gluon.block import HybridBlock
+    from ..ndarray import NDArray
+    from .sharding import ShardingRules
+    import re
+
+    class DroplessMoEFFN(HybridBlock):
+        """Dropless top-k gated experts, the share of them one chip holds.
+
+        ``forward(x: (B, T, d) | (N, d)) -> (out, load)``: the held experts'
+        part of the layer's result and this call's assignments per expert
+        (``num_experts``,) int32.  Parameters: ``router`` (d, E),
+        ``gate_up`` (held, d, 2F), ``down`` (held, F, d), no bias; ``load``
+        (E,) int32 is aux state (``grad_req`` null) that ``record_load``
+        writes, the way BatchNorm writes its running statistics.  The block
+        does NOT write it itself: a block that rewrites aux state cannot sit
+        inside ``Block.recompute()``, so whoever calls from outside any
+        recomputed block hands the load to ``record_load`` (the decoder of
+        ``gluon/model_zoo/moe_decoder.py`` does, in its own forward).
+        ``held`` defaults to all ``num_experts``; the stacked experts shard
+        over ``ep`` via ``sharding_rules()``."""
+
+        def __init__(self, units, hidden_size, num_experts, k, held=None,
+                     first_expert=0, renormalise=True, prefix=None,
+                     params=None):
+            super().__init__(prefix=prefix, params=params)
+            held = num_experts if held is None else held
+            if not 0 <= first_expert <= first_expert + held <= num_experts:
+                raise ValueError(
+                    f"experts {first_expert} .. {first_expert + held} are "
+                    f"not among {num_experts}")
+            if not 1 <= k <= num_experts:
+                raise ValueError(f"top-{k} of {num_experts} experts")
+            self._e, self._k, self._first = num_experts, k, first_expert
+            self._held, self._renormalise = held, renormalise
+            normal = init_mod.Normal(0.02)
+            self.router = self.params.get(
+                "router", shape=(units, num_experts), init=normal)
+            self.gate_up = self.params.get(
+                "gate_up", shape=(held, units, 2 * hidden_size),
+                init=normal)
+            self.down = self.params.get(
+                "down", shape=(held, hidden_size, units), init=normal)
+            self.load = self.params.get(
+                "load", shape=(num_experts,), dtype="int32", init="zeros",
+                differentiable=False)
+
+        def sharding_rules(self):
+            return ShardingRules(rules=[
+                (re.escape(self.gate_up.name), ("ep",)),
+                (re.escape(self.down.name), ("ep",))])
+
+        def cast(self, dtype):
+            super().cast(dtype)
+            self.load.cast("int32")         # a count, whatever the compute type
+            return self
+
+        def record_load(self, load):
+            """Keep ``load`` (what ``forward`` returned) as this block's
+            aux state, to be read back after the step."""
+            self.load._data = NDArray(jax.lax.stop_gradient(load._data))
+
+        def held_range(self):
+            return self._first, self._first + self._held
+
+        def hybrid_forward(self, F, x, router, gate_up, down, load):
+            shape = x.shape
+            out, load = F.moe_dropless_ffn(
+                x.reshape((-1, shape[-1])), router, gate_up, down,
+                num_experts=self._e, first_expert=self._first, k=self._k,
+                renormalise=self._renormalise)
+            return out.reshape(shape), load
+
+    return DroplessMoEFFN
+
+
+DroplessMoEFFN = _make_dropless_moe_ffn()
+
+
+def publish_load(net):
+    """Read the ``load`` of every ``DroplessMoEFFN`` under ``net`` (after a
+    step: ``TrainStep.sync_params_to_net()`` first) and publish, over all of
+    them, the gauges ``moe.load_max_over_mean`` (the fullest expert's
+    assignments over the mean: 1 is even routing) and ``moe.held_share`` (the
+    share of assignments that landed on held experts: ``held / num_experts``
+    under even routing).  Returns ``{gauge: value}``; both are 0 before any
+    step."""
+    import numpy as np
+    from .. import telemetry
+    blocks = []
+    net.apply(lambda b: blocks.append(b)
+              if isinstance(b, DroplessMoEFFN) else None)
+    loads = [np.asarray(b.load.data()._data, np.float64) for b in blocks]
+    total = sum(l.sum() for l in loads)
+    values = {"moe.load_max_over_mean": 0.0, "moe.held_share": 0.0}
+    if total:
+        values["moe.load_max_over_mean"] = float(
+            max(l.max() / l.mean() for l in loads if l.sum()))
+        values["moe.held_share"] = float(sum(
+            l[slice(*b.held_range())].sum()
+            for b, l in zip(blocks, loads)) / total)
+    for name, v in values.items():
+        telemetry.registry().gauge(name).set(v)
+    return values

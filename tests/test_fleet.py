@@ -1295,6 +1295,12 @@ def test_autoscaler_failed_action_is_logged_and_backed_off():
                     pass                      # at the cap — pressure made
             t0 = time.time()
             while scaler.stats["failures"] < 1 and time.time() - t0 < 30:
+                # keep the pressure up: on a loaded machine the six
+                # requests above can finish between two of the scaler's ticks
+                try:
+                    reqs.append(fleet.submit(_ex(1)))
+                except RejectedError:
+                    pass
                 time.sleep(0.02)
             assert scaler.stats["failures"] >= 1
             assert len(fleet.replicas) == 1       # nothing half-added

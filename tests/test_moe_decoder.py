@@ -1,0 +1,683 @@
+"""The mixture-of-experts decoder (``gluon/model_zoo/moe_decoder.py``), the
+dropless expert layer (``parallel/moe.py``), the rotary op
+(``ops/rotary.py``) and their benchmark family against the plain reference
+kept with the benchmark (``chipbench/reference/moe_decoder.py``): float32,
+small widths, seeded weights, more positions than five windows and two blocks
+of the flash kernel."""
+import json
+import math
+import os
+import re
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import mxnet_tpu as mx
+from mxnet_tpu import gluon, parallel, telemetry
+from mxnet_tpu.gluon.block import _flatten_nd
+from mxnet_tpu.gluon.model_zoo import moe_decoder
+from mxnet_tpu.ndarray import NDArray
+from mxnet_tpu.ops.registry import get_op
+from mxnet_tpu.ops.rotary import rope_frequencies
+from mxnet_tpu.parallel.functional import (FunctionalState, functional_call,
+                                           param_names_and_values)
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from chipbench import manifest                                  # noqa: E402
+from chipbench.families import moe_decoder as family            # noqa: E402
+from chipbench.reference import moe_decoder as reference        # noqa: E402
+
+CONFIG = manifest.load_json(ROOT, "chipbench/configs/mellum2_12b_a2_5b.json")
+CELL = "mellum2_12b_a2_5b.train_s8192"
+CUT = ["window", "window", "window", "full"]
+YARN = CONFIG["rope_parameters"]["full_attention"]
+PLAIN = CONFIG["rope_parameters"]["sliding_attention"]
+# the toy's YaRN table: an original context of 64 puts the ramp over the
+# first rotary pairs of a head of 16 (low 0, high 2)
+TOY_ROPE = {"sliding_attention": PLAIN,
+            "full_attention": dict(YARN, original_max_position_embeddings=64)}
+# 8 experts of which 4 are held (2 .. 5), top-3; heads of 16 on a hidden
+# size of 32, so the query width (64) is not the hidden size
+TOY = dict(layers=CUT, vocab_size=50, sequence_length=256, hidden_size=32,
+           num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+           moe_intermediate_size=24, routed_experts=8, num_experts=4,
+           first_expert=2, num_experts_per_tok=3, norm_topk_prob=True,
+           sliding_window=48, rms_norm_eps=1e-6, embedding_std=0.5,
+           rope_parameters=TOY_ROPE)
+REF = dict(heads=4, kv_heads=2, head_dim=16, window=48, eps=1e-6, k=3,
+           first_expert=2,
+           rope={"window": TOY_ROPE["sliding_attention"],
+                 "full": TOY_ROPE["full_attention"]})
+VOCAB, T = TOY["vocab_size"], TOY["sequence_length"]
+
+
+def _batch(seed=0, n=2, t=T):
+    ids = np.random.RandomState(seed).randint(0, VOCAB, (n, t))
+    return ids.astype(np.int32), np.roll(ids, -1, axis=1).astype(np.int32)
+
+
+def _net(model=TOY, seed=3, recompute=False):
+    mx.random.seed(seed)
+    net = family.make_net(model)
+    if recompute:
+        for layer in net.layers:
+            layer.recompute()
+    net.initialize()
+    return net
+
+
+def _loss_and_grads(net, ids, labels):
+    """The net's loss and gradients as ``TrainStep`` takes them: ``jax.grad``
+    over ``functional_call``.  Gradients come back under the reference's
+    structural names, the trained leaves alone."""
+    names, plist, arrays = param_names_and_values(net)
+    structural = {p.name: n
+                  for n, p in net._collect_params_with_prefix().items()}
+    trained = [i for i, p in enumerate(plist) if p.grad_req != "null"]
+    loss_fn = gluon.loss.SoftmaxCrossEntropyLoss(axis=-1)
+    leaves, tree = _flatten_nd((NDArray(jnp.asarray(ids)),))
+
+    def loss_of(some):
+        full = list(arrays)
+        for i, a in zip(trained, some):
+            full[i] = a
+        outs = functional_call(net, plist, full, tree,
+                               [l._data for l in leaves], jax.random.key(0),
+                               True, FunctionalState())
+        return jnp.mean(loss_fn(NDArray(outs[0]),
+                                NDArray(jnp.asarray(labels)))._data)
+
+    with jax.default_matmul_precision("highest"):
+        loss, grads = jax.jit(jax.value_and_grad(loss_of))(
+            [arrays[i] for i in trained])
+    return float(loss), {structural[names[i]]: g
+                         for i, g in zip(trained, grads)}
+
+
+def _one_device():
+    return parallel.make_mesh(dp=1, devices=jax.devices()[:1])
+
+
+def _worst(got, want):
+    """Largest error of any leaf, relative to that leaf's largest entry."""
+    assert set(got) == set(want)
+    return max(float(jnp.abs(got[n] - want[n]).max()
+                     / (jnp.abs(want[n]).max() + 1e-30)) for n in want)
+
+
+# ------------------------------------------------------ net and reference --
+@pytest.fixture(scope="module")
+def cut():
+    """The cut's net, one batch, and both sides' loss and gradients."""
+    net = _net()
+    ids, labels = _batch()
+    params = reference.params_from_net(net)
+    got = _loss_and_grads(net, ids, labels)
+    want = reference.loss_and_grads(params, CUT, jnp.asarray(ids),
+                                    jnp.asarray(labels), **REF)
+    return net, ids, params, got, want
+
+
+def test_logits_match_the_reference(cut):
+    net, ids, params, _, _ = cut
+    got = net(mx.nd.array(ids, dtype="int32"))
+    assert got.shape == (2, T, VOCAB) and got.dtype == np.float32
+    with jax.default_matmul_precision("highest"):
+        want = reference.forward(params, CUT, jnp.asarray(ids), **REF)
+    assert float(jnp.abs(want).max()) > 0.3
+    np.testing.assert_allclose(got.asnumpy(), np.asarray(want), atol=2e-6)
+
+
+def test_loss_matches_the_reference(cut):
+    _, _, _, (got, _), (want, _) = cut
+    assert abs(got - float(want)) < 1e-5 and got > 3.0
+
+
+def test_every_gradient_leaf_matches_the_reference(cut):
+    _, _, params, (_, got), (_, want) = cut
+    # a layer: qkv, out_proj, two norms, router, gate_up, down; the
+    # embedding, the last norm and the untied head
+    assert len(want) == len(params) == 4 * 7 + 3
+    assert all(float(jnp.abs(g).max()) > 0 for g in want.values())
+    assert _worst(got, want) < 2e-5
+
+
+@pytest.mark.parametrize("layers", [["window"], ["full"]], ids=lambda l: l[0])
+def test_each_kind_of_layer_alone(layers):
+    net = _net(dict(TOY, layers=layers), seed=5)
+    ids, labels = _batch(1)
+    params = reference.params_from_net(net)
+    loss, grads = _loss_and_grads(net, ids, labels)
+    want, want_grads = reference.loss_and_grads(
+        params, layers, jnp.asarray(ids), jnp.asarray(labels), **REF)
+    assert abs(loss - float(want)) < 1e-5
+    assert _worst(grads, want_grads) < 2e-5
+
+
+def test_a_kind_without_a_rotary_entry_gets_no_rotary_step():
+    model = dict(TOY, layers=["full"], rope_parameters={})
+    net = _net(model, seed=6)
+    ids, _ = _batch(2)
+    got = net(mx.nd.array(ids, dtype="int32"))
+    want = reference.forward(reference.params_from_net(net), ["full"],
+                             jnp.asarray(ids), **dict(REF, rope={}))
+    np.testing.assert_allclose(got.asnumpy(), np.asarray(want), atol=2e-6)
+
+
+def test_unknown_kinds_and_tables_raise():
+    with pytest.raises(ValueError, match="kind"):
+        _net(dict(TOY, layers=["window", "mamba"]))
+    with pytest.raises(ValueError, match="rope_type"):
+        rope_frequencies({"rope_type": "linear", "rope_theta": 1e4}, 16)
+    with pytest.raises(ValueError, match="whole periods"):
+        moe_decoder.published_layers(6)
+    with pytest.raises(ValueError, match="not among"):
+        parallel.DroplessMoEFFN(8, 8, num_experts=8, k=2, held=4,
+                                first_expert=6)
+
+
+def test_recomputed_layers_give_equal_gradients(cut):
+    _, ids, _, (loss, grads), _ = cut
+    _, labels = _batch()
+    again, marked = _loss_and_grads(_net(recompute=True), ids, labels)
+    assert again == loss
+    assert _worst(marked, grads) < 1e-6
+
+
+# -------------------------------------------------------- the expert layer --
+E, HELD, K, D, F = 64, 16, 8, 24, 12
+
+
+def _expert_weights(seed=0):
+    rng = np.random.RandomState(seed)
+    f = lambda *s: jnp.asarray(rng.randn(*s), jnp.float32)     # noqa: E731
+    return f(D, E), f(E, D, 2 * F) * 0.3, f(E, F, D) * 0.3
+
+
+def _share(tokens, router, gate_up, down, first, held=HELD, k=K):
+    return get_op("moe_dropless_ffn")(
+        tokens, router, gate_up[first:first + held],
+        down[first:first + held], num_experts=E, first_expert=first, k=k)
+
+
+def _reference_share(tokens, router, gate_up, down, first, held=HELD, k=K):
+    with jax.default_matmul_precision("highest"):
+        return reference.moe(tokens, router, gate_up[first:first + held],
+                             down[first:first + held], k, first)
+
+
+def test_the_four_shares_add_up_to_the_uncut_layer():
+    """64 experts, top-8, four chips of 16: the parts the shares compute sum
+    to what the uncut reference gives for the whole layer, and every share
+    counts the same assignments."""
+    router, gate_up, down = _expert_weights()
+    tokens = jnp.asarray(np.random.RandomState(1).randn(96, D), jnp.float32)
+    whole = _reference_share(tokens, router, gate_up, down, 0, held=E)
+    parts, loads = zip(*[_share(tokens, router, gate_up, down, first)
+                         for first in (0, 16, 32, 48)])
+    for first, part in zip((0, 16, 32, 48), parts):
+        np.testing.assert_allclose(
+            part, _reference_share(tokens, router, gate_up, down, first),
+            rtol=2e-5, atol=2e-6)
+    assert float(jnp.abs(whole).max()) > 0.1
+    np.testing.assert_allclose(sum(parts), whole, rtol=2e-5, atol=5e-6)
+    assert all(np.array_equal(l, loads[0]) for l in loads)
+    assert int(loads[0].sum()) == 96 * K and loads[0].dtype == jnp.int32
+
+
+@pytest.mark.parametrize("favoured,held_rows", [
+    (tuple(range(3, 11)), 96 * 8), (tuple(range(40, 48)), 0),
+    (tuple(range(12, 20)), 96 * 4)],
+    ids=["all_held", "none_held", "half_held"])
+def test_dropless_under_forced_imbalance(favoured, held_rows):
+    """A router forced to send every token to the same eight experts: all
+    held here (every one of the N x k rows is a held row: no buffer sized
+    for an even split would hold them), none held (the layer returns zero),
+    half held.  Values and every gradient match the reference; no token is
+    dropped."""
+    router, gate_up, down = _expert_weights(2)
+    router = router * 0.01 + 20.0 * jnp.zeros((D, E)).at[
+        0, jnp.asarray(favoured)].set(jnp.linspace(1.0, 1.7, 8))
+    tokens = jnp.asarray(np.random.RandomState(3).randn(96, D), jnp.float32)
+    tokens = tokens.at[:, 0].set(1.0)
+
+    def args(gu, dn):
+        return tokens, router, gu, dn
+    out, load = _share(*args(gate_up, down), 0)
+    assert int(load[jnp.asarray(favoured)].sum()) == 96 * K
+    assert int(load[:HELD].sum()) == held_rows
+    want = _reference_share(*args(gate_up, down), 0)
+    np.testing.assert_allclose(out, want, rtol=2e-5, atol=2e-6)
+    if not held_rows:
+        assert float(jnp.abs(out).max()) == 0.0
+
+    def total(fn):
+        return lambda t, r, gu, dn: (fn(t, r, gu, dn, 0) ** 2).sum()
+    got = jax.grad(total(lambda *a: _share(*a)[0]), range(4))(
+        *args(gate_up, down))
+    ref = jax.grad(total(_reference_share), range(4))(*args(gate_up, down))
+    for g, w in zip(got, ref):
+        assert g.shape == w.shape
+        assert np.isfinite(np.asarray(g)).all()
+        np.testing.assert_allclose(g, w, atol=2e-5 * float(
+            jnp.abs(w).max() + 1e-30) + 1e-7)
+
+
+def test_one_expert_takes_every_token():
+    """top-1 with one held expert that every token chooses, and then one
+    that none chooses."""
+    router, gate_up, down = _expert_weights(4)
+    router = router.at[:, 5].add(100.0 * jnp.sign(router[:, 5]))
+    tokens = jnp.abs(jnp.asarray(
+        np.random.RandomState(5).randn(33, D), jnp.float32)) \
+        * jnp.sign(router[:, 5])
+    for first, rows in ((5, 33), (6, 0)):
+        out, load = _share(tokens, router, gate_up, down, first, held=1, k=1)
+        assert int(load[5]) == 33 and int(load[first]) == rows
+        np.testing.assert_allclose(out, _reference_share(
+            tokens, router, gate_up, down, first, held=1, k=1), atol=2e-5)
+
+
+def test_route_topk_renormalises_over_the_chosen():
+    logits = jnp.asarray(np.random.RandomState(6).randn(10, E), jnp.float32)
+    gates, experts = parallel.route_topk(logits, K)
+    probs = jax.nn.softmax(logits, axis=-1)
+    assert gates.shape == experts.shape == (10, K)
+    assert experts.dtype == jnp.int32 and gates.dtype == jnp.float32
+    np.testing.assert_allclose(gates.sum(-1), 1.0, atol=1e-6)
+    order = np.argsort(-np.asarray(probs), axis=-1)[:, :K]
+    assert np.array_equal(np.sort(order, -1), np.sort(experts, -1))
+    chosen = np.take_along_axis(np.asarray(probs), np.asarray(experts), -1)
+    np.testing.assert_allclose(gates, chosen / chosen.sum(-1, keepdims=True),
+                               rtol=1e-6)
+    raw, _ = parallel.route_topk(logits, K, renormalise=False)
+    np.testing.assert_allclose(raw, chosen, rtol=1e-6)
+    want, _ = reference.route(logits, jnp.eye(E), K)
+    np.testing.assert_allclose(
+        np.take_along_axis(np.asarray(want), np.asarray(experts), -1), gates,
+        rtol=1e-5)
+
+
+def test_block_holds_its_share_and_shards_it_over_ep():
+    mx.random.seed(2)
+    block = parallel.DroplessMoEFFN(D, F, num_experts=E, k=K, held=HELD,
+                                    first_expert=32)
+    block.initialize()
+    assert block.router.shape == (D, E)
+    assert block.gate_up.shape == (HELD, D, 2 * F)
+    assert block.down.shape == (HELD, F, D)
+    assert block.load.grad_req == "null" and block.held_range() == (32, 48)
+    x = mx.nd.array(np.random.RandomState(0).randn(2, 20, D))
+    out, load = block(x)
+    assert out.shape == (2, 20, D) and load.shape == (E,)
+    want = reference.moe(x._data, block.router.data()._data,
+                         block.gate_up.data()._data, block.down.data()._data,
+                         K, 32)
+    np.testing.assert_allclose(out.asnumpy(), want, atol=2e-6)
+    rules = block.sharding_rules()
+    mesh = parallel.make_mesh(ep=4, devices=jax.devices()[:4])
+    specs = {p.name: rules.spec_for(p.name, p.shape, mesh)
+             for p in block.collect_params().values()}
+    assert tuple(specs[block.gate_up.name])[0] == "ep"
+    assert tuple(specs[block.down.name])[0] == "ep"
+    assert not any(tuple(specs[n]) for n in (block.router.name,
+                                             block.load.name))
+    block.cast("bfloat16")
+    assert block.load.data().dtype == np.int32
+    assert block.router.data().dtype == jnp.bfloat16
+
+
+# -------------------------------------------------------------- the rotary --
+def test_yarn_table_against_hand_values():
+    f, m = rope_frequencies(YARN, 128)
+    assert len(f) == 64 and m == pytest.approx(1.2772588722239782, abs=1e-12)
+    assert m == pytest.approx(0.1 * math.log(16) + 1, abs=1e-12)
+    c = lambda r: 128 * math.log(8192 / (2 * math.pi * r)) \
+        / (2 * math.log(500000))                             # noqa: E731
+    assert (math.floor(c(32)), math.ceil(c(1))) == (18, 35)
+    assert c(32) == pytest.approx(18.081, abs=1e-3)
+    assert c(1) == pytest.approx(34.984, abs=1e-3)
+    e = [500000 ** (-2 * i / 128) for i in range(64)]
+    # up to pair 18 the plain table, from pair 35 on a sixteenth of it
+    assert f[0] == 1.0
+    np.testing.assert_allclose(f[:19], e[:19], rtol=1e-6)
+    np.testing.assert_allclose(f[35:], np.asarray(e[35:]) / 16, rtol=1e-6)
+    assert f[63] == pytest.approx(500000 ** (-126 / 128) / 16, rel=1e-6)
+    ramp = (27 - 18) / (35 - 18)
+    assert f[27] == pytest.approx(e[27] / 16 * ramp + e[27] * (1 - ramp),
+                                  rel=1e-6)
+    no_factor = dict(YARN)
+    del no_factor["attention_factor"]
+    assert rope_frequencies(no_factor, 128)[1] == pytest.approx(m, abs=1e-12)
+    ref_f, ref_m = reference.rope_table(YARN, 128)
+    assert np.array_equal(np.asarray(ref_f), np.asarray(f, np.float32))
+    assert ref_m == m
+
+
+def test_plain_table():
+    f, m = rope_frequencies(PLAIN, 128)
+    assert m == 1.0 and len(f) == 64
+    np.testing.assert_allclose(
+        f, [500000 ** (-2 * i / 128) for i in range(64)], rtol=1e-6)
+    assert f[63] == pytest.approx(500000 ** (-126 / 128), rel=1e-6)
+    ref_f, ref_m = reference.rope_table(PLAIN, 128)
+    assert np.array_equal(np.asarray(ref_f), np.asarray(f, np.float32))
+    assert ref_m == 1.0
+    assert rope_frequencies({"rope_theta": 500000}, 128) == (f, m)
+
+
+@pytest.mark.parametrize("table", [PLAIN, YARN], ids=["default", "yarn"])
+def test_rotary_keeps_position_0_and_scores_depend_on_distance(table):
+    f, m = rope_frequencies(table, 16)
+    rope = lambda x, heads: get_op("rotary_embedding")(      # noqa: E731
+        x, inv_freq=f, heads=heads, factor=m)
+    rng = np.random.RandomState(0)
+    x = jnp.asarray(rng.randn(2, 40, 3 * 16), jnp.float32)
+    y = rope(x, 3)
+    assert y.shape == x.shape and y.dtype == x.dtype
+    np.testing.assert_allclose(y[:, 0], m * x[:, 0], rtol=1e-6)
+    want = reference._rotate(x.reshape(2, 40, 3, 16),
+                             reference.rope_table(table, 16))
+    np.testing.assert_allclose(y, want.reshape(2, 40, 48), atol=1e-6)
+    # the same q and k at every position: the score of (t, s) is a function
+    # of t - s alone
+    q = jnp.broadcast_to(jnp.asarray(rng.randn(16), jnp.float32), (1, 40, 16))
+    k = jnp.broadcast_to(jnp.asarray(rng.randn(16), jnp.float32), (1, 40, 16))
+    scores = np.asarray(rope(q, 1)[0] @ rope(k, 1)[0].T)
+    for lag in (0, 1, 7, 25):
+        diagonal = np.diagonal(scores, -lag)
+        np.testing.assert_allclose(diagonal, diagonal[0], atol=2e-5)
+    assert abs(scores[7, 0] - scores[25, 0]) > 1e-3
+    low = rope(x.astype(jnp.bfloat16), 3)
+    assert low.dtype == jnp.bfloat16
+    assert float(jnp.abs(low.astype(jnp.float32) - y).max()) < 0.05
+    with pytest.raises(ValueError, match="inverse frequencies"):
+        get_op("rotary_embedding")(x, inv_freq=f[:4], heads=3)
+
+
+def test_the_window_counts_1024_keys_with_the_query():
+    """Uniform scores, a value that is 1 at key 0 alone: query t reads 1 /
+    (keys it sees) while key 0 is among them: 1,024 at t = 1,023, and
+    nothing from t = 1,024 on."""
+    window = CONFIG["model"]["sliding_window"]
+    assert window == CONFIG["sliding_window"] == 1024
+    t = 2 * window + 50
+    zeros = jnp.zeros((1, t, 8), jnp.float32)
+    v = zeros.at[0, 0, :].set(1.0)
+    out = np.asarray(get_op("window_attention")(
+        zeros, zeros, v, heads=1, kv_heads=1, window=window))[0, :, 0]
+    np.testing.assert_allclose(out[:window],
+                               1.0 / np.arange(1, window + 1), rtol=1e-5)
+    assert out[window - 1] == pytest.approx(1.0 / 1024, rel=1e-5)
+    assert np.all(out[window:] == 0.0)
+
+
+# --------------------------------------------- the configuration, the count --
+def _trained_parameters(**changes):
+    net = family.make_net(dict(CONFIG["model"], **changes))
+    return sum(int(np.prod(p.shape)) for p in net.collect_params().values()
+               if p.grad_req != "null")
+
+
+def test_published_layers_is_the_configs_list():
+    kinds = moe_decoder.published_layers(CONFIG["num_hidden_layers"])
+    assert [{"window": "sliding_attention", "full": "full_attention"}[k]
+            for k in kinds] == CONFIG["layer_types"]
+    assert set(CONFIG["mlp_layer_types"]) == {"sparse"}
+    assert (kinds.count("window"), kinds.count("full")) == (21, 7)
+    assert CONFIG["model"]["layers"] == moe_decoder.published_layers(4) == CUT
+
+
+def test_the_published_config_counts_12b_and_the_cut_595m():
+    layer = 2304 * (4096 + 2 * 512) + 4096 * 2304 + 2 * 2304 + 2304 * 64
+    expert = 3 * 2304 * 896
+    assert (layer, expert) == (21_385_728, 6_193_152)
+    published = _trained_parameters(
+        layers=moe_decoder.published_layers(28), num_experts=64,
+        vocab_size=CONFIG["vocab_size"])
+    assert published == 28 * (layer + 64 * expert) \
+        + 2 * 98304 * 2304 + 2304 == 12_149_915_904
+    active = published - 28 * 56 * expert
+    # ISSUE 31 wrote ...051,264: the last norm's 2,304 left out
+    assert active == 2_439_053_568
+    assert _trained_parameters() == 4 * (layer + 16 * expert) \
+        + 2 * 24576 * 2304 + 2304 == 595_153_152
+
+
+def test_configuration_keeps_every_published_width():
+    """The catalog's ``config`` verbatim at the top level; ``model`` repeats
+    the widths it needs and changes depth, the experts held and the
+    vocabulary alone."""
+    catalog = {
+        "attention_bias": False, "head_dim": 128, "hidden_act": "silu",
+        "hidden_size": 2304, "intermediate_size": 7168,
+        "max_position_embeddings": 131072, "max_window_layers": 0,
+        "model_type": "mellum", "moe_intermediate_size": 896,
+        "norm_topk_prob": True, "num_attention_heads": 32, "num_experts": 64,
+        "num_experts_per_tok": 8, "num_hidden_layers": 28,
+        "num_key_value_heads": 4, "rms_norm_eps": 1e-06,
+        "sliding_window": 1024, "tie_word_embeddings": False,
+        "vocab_size": 98304, "use_sliding_window": True,
+        "rope_parameters": {
+            "full_attention": {
+                "rope_type": "yarn", "rope_theta": 500000, "factor": 16,
+                "original_max_position_embeddings": 8192, "beta_fast": 32,
+                "beta_slow": 1, "attention_factor": 1.2772588722239782},
+            "sliding_attention": {"rope_type": "default",
+                                  "rope_theta": 500000}}}
+    assert {k: CONFIG[k] for k in catalog} == catalog
+    assert len(CONFIG["layer_types"]) == len(CONFIG["mlp_layer_types"]) == 28
+    model = CONFIG["model"]
+    for key in set(model) & set(catalog) - {"vocab_size", "num_experts"}:
+        assert model[key] == catalog[key], key
+    assert CONFIG["reduced"] == ["layers", "num_experts", "vocab_size"]
+    assert model["routed_experts"] == catalog["num_experts"]
+    assert model["num_experts"] * 4 == catalog["num_experts"]
+    assert model["vocab_size"] * 4 == catalog["vocab_size"]
+    assert model["first_expert"] == 0 and model["sequence_length"] == 8192
+    assert set(CONFIG["assumed"]) >= {"qk_norm", "intermediate_size",
+                                      "initialisation", "load_balancing_loss",
+                                      "learning_rate"}
+    assert any("multi-token" in d for d in CONFIG["departures"])
+    assert "4 chips share each layer" in CONFIG["deployment"]
+    assert CONFIG["check"]["loss_atol"] > 0 and CONFIG["check"]["why"]
+    entry = next(c for c in manifest.load(ROOT)["configs"]
+                 if c["name"] == "mellum2_12b_a2_5b")
+    assert entry["reduced"] == CONFIG["reduced"]
+    assert entry["file"] == "chipbench/configs/mellum2_12b_a2_5b.json"
+    assert not any(manifest.WIDTH.search(k) for k in entry["reduced"])
+
+
+def test_train_flops_against_a_hand_count():
+    """The ISSUE's arithmetic, a sequence of 8,192: the layers' attention and
+    router matrices and the 2 of 16 held experts a token meets under even
+    routing, the attention products over the pairs causality and the window
+    leave, the untied head over the slice."""
+    model = CONFIG["model"]
+    t = model["sequence_length"]
+    products = 2 * t * 4 * (21_233_664 + 147_456 + 2 * 6_193_152)
+    band = sum(min(i + 1, 1024) for i in range(t))
+    full = t * (t + 1) // 2
+    assert (band, full) == (7_864_832, 33_558_528)
+    attention = 2 * 2 * 4096 * (3 * band + full)
+    head = 2 * t * 24576 * 2304
+    assert family.train_flops(model) == 3 * (products + attention + head)
+    assert round(products / 1e12, 3) == 2.213
+    assert round(attention / 1e12, 3) == 0.936
+    assert round(head / 1e12, 3) == 0.928
+    assert round(family.train_flops(model) / 1e12, 2) == 12.23
+    # the held experts' share of the required operations, here and as a
+    # deployed chip fed by four sees them (the cell's why)
+    experts = 2 * t * 4 * 2 * 6_193_152
+    here = experts / (products + attention + head)
+    fed_by_four = 4 * experts / (products + attention + head + 3 * experts)
+    assert 0.19 < here < 0.21 and 0.49 < fed_by_four < 0.51
+
+
+def test_grouped_product_counts():
+    model = CONFIG["model"]
+    rows = 8192 * 8 // 4              # even routing: a quarter of the rows
+    assert family.grouped_product_flops(model, rows) \
+        == 2 * rows * 2304 * 1792 + 2 * rows * 896 * 2304
+    assert family.grouped_product_bytes(model, rows, 16) == 2 * (
+        rows * 2304 + 16 * 2304 * 1792 + rows * 1792
+        + rows * 896 + 16 * 896 * 2304 + rows * 2304)
+    assert family.grouped_product_bytes(model, 0, 16) == 2 * 16 * 6_193_152
+
+
+# -------------------------------------------------- through the benchmark --
+@pytest.fixture
+def toy_benchmark(tmp_path):
+    """A throw-away benchmark holding the new configuration and cell at
+    toy sizes, added as files and entries beside none."""
+    root, src = str(tmp_path), os.path.join(ROOT, "chipbench")
+    real = manifest.load(ROOT)
+    for d in ("configs", "workloads", "layer_metrics"):
+        os.makedirs(os.path.join(root, "chipbench", d))
+    cfg = dict(CONFIG, model=dict(TOY, sequence_length=64),
+               compute_dtype="float32", check={"loss_atol": 0.02},
+               optimizer={"name": "adamw", "args": {"learning_rate": 1e-3}})
+    with open(os.path.join(root, "chipbench/configs/mellum2_12b_a2_5b.json"),
+              "w") as f:
+        json.dump(cfg, f)
+    wl = manifest.load_json(src, f"workloads/{CELL}.json")
+    wl.update(trace_s=1.0, batch_per_chip=1)
+    with open(os.path.join(root, manifest.workload_file(CELL)), "w") as f:
+        json.dump(wl, f)
+    m = dict(real, run_seconds=2)
+    m["configs"] = [c for c in real["configs"]
+                    if c["name"] == "mellum2_12b_a2_5b"]
+    m["workloads"] = [w for w in real["workloads"] if w["name"] == CELL]
+    for section in ("end_to_end", "per_layer"):
+        m[section] = [dict(r, workloads=[CELL]) if "workloads" in r else r
+                      for r in real[section]
+                      if CELL in r.get("workloads", [CELL])]
+    for r in m["per_layer"]:
+        with open(os.path.join(src, "layer_metrics", r["name"] + ".json")) as f:
+            spec = f.read()
+        with open(os.path.join(root, manifest.metric_file(r["name"])),
+                  "w") as f:
+            f.write(spec)
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(m, f)
+    assert manifest.validate(m, root) == []
+    return manifest.cell(m, root, CELL)
+
+
+@pytest.mark.parametrize("trace", [0, 1])
+def test_family_rehearsed_through_the_benchmark(toy_benchmark, capsys, trace):
+    from chipbench import run
+    res = run.run_cell(toy_benchmark, jax.devices()[:1], 2**31 + 11, 2.0,
+                       trace)
+    fails = [l for l in capsys.readouterr().out.splitlines() if "[FAIL]" in l]
+    # a CPU run can never pass for a result, and that is its only fault
+    assert len(fails) == 1 and "runs on a TPU" in fails[0], fails
+    assert res["correct"] is False and res["device"]["platform"] == "cpu"
+    assert res["attempted"] > 0 and res["failed"] == 0
+    want = {"step_ms_p50", "compile_ms_total"} if trace \
+        else {"setup_s", "train_samples_per_s"}
+    assert set(res["metrics"]) == want
+
+
+def test_the_real_benchmark_holds_the_cell():
+    real = manifest.load(ROOT)
+    assert manifest.validate(real, ROOT) == []
+    assert [w["name"] for w in real["workloads"]][-1] == CELL
+    view = manifest.cell(real, ROOT, CELL)
+    assert view["chips"] == 1 and view["cfg"]["family"] == "moe_decoder"
+    assert view["cfg"]["kind"] == "train"
+    assert view["wl"]["driver"] == "train_loop"
+    assert view["wl"]["batch_per_chip"] in (1, 2, 4)
+    assert [m["name"] for m in view["end_to_end"]] == [
+        "train_samples_per_s", "setup_s"]
+    assert {m["name"] for m in view["per_layer"]} == {
+        "compile_ms_total", "step_ms_p50", "mfu_pct", "device_idle_pct.train"}
+    entry = real["workloads"][-1]
+    assert len(entry["why"]) <= 200 and "four times" in view["wl"]["why"]
+
+
+def test_family_draws_its_batch_from_the_seed():
+    _, _, batch = family.build(dict(TOY, sequence_length=64))
+    (a,), (la,) = batch(np.random.default_rng(5), 3)
+    (b,), _ = batch(np.random.default_rng(5), 3)
+    (c,), _ = batch(np.random.default_rng(6), 3)
+    assert a.shape == la.shape == (3, 64) and a.dtype == la.dtype == np.int32
+    assert 0 <= a.min() and a.max() < VOCAB
+    assert np.array_equal(a, b) and not np.array_equal(a, c)
+    assert np.array_equal(la[:, :-1], a[:, 1:])
+    made, = family.check_labels(NDArray(jnp.eye(4)[None]))
+    assert made.tolist() == [[0, 1, 2, 3]]
+
+
+# ------------------------------------------------- the scopes and the load --
+@pytest.fixture(scope="module")
+def stepped():
+    """The family's toy net (every layer recomputed, bf16) under TrainStep
+    with AdamW on a one-device mesh, and its batch."""
+    net, loss_fn, batch = family.build(dict(TOY, sequence_length=64))
+    mx.random.seed(1)
+    net.initialize()
+    net.cast("bfloat16")
+    step = parallel.TrainStep(
+        net, loss_fn, mx.optimizer.create("adamw", learning_rate=1e-3),
+        mesh=_one_device())
+    (ids,), (labels,) = batch(np.random.default_rng(0), 2)
+    return net, step, ids, labels
+
+
+def test_train_step_program_names_every_new_scope(stepped):
+    _, step, ids, labels = stepped
+    text = step.lower(ids, labels).as_text(debug_info=True)
+    paths = set(re.findall(r'loc\("([^"]*)"', text))
+    scopes = ["embed", "head"]
+    for i, kind in enumerate(CUT):
+        attention = "window_attention" if kind == "window" else "attention"
+        scopes += [f"layer{i}/{attention}", f"layer{i}/{attention}/rope",
+                   f"layer{i}/moe"]
+        scopes += [f"layer{i}/moe/{part}"
+                   for part in ("router", "dispatch", "experts", "combine")]
+    for scope in scopes:
+        forward = [p for p in paths
+                   if f"/{scope}/" in p and "jvp(forward)" in p]
+        assert forward, scope
+        if not scope.endswith(("/router", "/dispatch")) or "router" in scope:
+            assert any("transpose(jvp(forward))" in p for p in forward), scope
+    assert "rematted_computation" in text
+    for kernel in ("flash_attention_fwd", "flash_attention_bwd_dq",
+                   "flash_attention_bwd_dkv"):
+        assert kernel in text
+    assert "ragged_dot" in text
+
+
+def test_the_load_reads_back_after_a_step(stepped):
+    net, step, ids, labels = stepped
+    before = parallel.publish_load(net)
+    assert before == {"moe.load_max_over_mean": 0.0, "moe.held_share": 0.0}
+    loss = float(step(ids, labels).asnumpy())
+    assert np.isfinite(loss)
+    step.sync_params_to_net()
+    loads = [np.asarray(l.moe.load.data()._data) for l in net.layers]
+    for load in loads:
+        assert load.dtype == np.int32 and load.shape == (8,)
+        assert load.sum() == ids.size * 3       # every assignment, none lost
+    got = parallel.publish_load(net)
+    held = sum(l[2:6].sum() for l in loads) / sum(l.sum() for l in loads)
+    assert got["moe.held_share"] == pytest.approx(held)
+    assert got["moe.load_max_over_mean"] == pytest.approx(
+        max(l.max() / l.mean() for l in loads))
+    assert 0.2 < got["moe.held_share"] < 0.8
+    assert got["moe.load_max_over_mean"] >= 1.0
+    gauges = telemetry.registry().snapshot()["gauges"]
+    assert gauges["moe.held_share"] == got["moe.held_share"]
+    assert gauges["moe.load_max_over_mean"] == got["moe.load_max_over_mean"]
+    # every trained leaf moved, the router among them
+    names = [n for n, p in zip(step._names, step._plist)
+             if p.grad_req != "null"]
+    assert any(n.endswith("router") for n in names)
+    assert sum("load" in n for n in step._names) == 4

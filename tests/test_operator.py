@@ -944,6 +944,8 @@ COVERED_ELSEWHERE = {
     "window_attention": "tests/test_sambay.py",
     "selective_scan": "tests/test_sambay.py",
     "causal_conv1d": "tests/test_sambay.py",
+    "moe_dropless_ffn": "tests/test_moe_decoder.py",
+    "rotary_embedding": "tests/test_moe_decoder.py",
     "paged_decode_attention": "tests/test_generate.py",
     "dense_decode_attention": "tests/test_generate.py",
     "quantized_conv": "tests/test_misc_subsystems.py",

@@ -72,3 +72,60 @@ def test_scan_kernels_compile_for_v5e(one_chip, no_compile_cache, state):
     assert "selective_scan_fwd" in text and "selective_scan_bwd" in text
     assert text.count("tpu_custom_call") >= 2
     assert compiled.memory_analysis().temp_size_in_bytes < t * n * dim * 4 / 4
+
+
+# mellum2_12b_a2_5b.train_s8192: one sequence of 8,192 positions, 32 query
+# and 4 K/V heads of 128, 8 assignments a token over 16 held experts of 64
+def test_flash_kernels_compile_for_v5e_at_heads_of_128(one_chip,
+                                                       no_compile_cache):
+    """Forward and both backward kernels with a group of 8 query heads a K/V
+    head and heads of 128, the block the decoders give, bf16."""
+    from mxnet_tpu.gluon.model_zoo._attention import _flash_block
+    from mxnet_tpu.ops.pallas import flash_attention
+    t, block = 8192, _flash_block(8192)
+
+    def shape(heads):
+        return jax.ShapeDtypeStruct((heads, t, 128), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def total(q, k, v):
+        return flash_attention(
+            q, k, v, causal=True, block_q=block, block_k=block,
+            interpret=False).astype(jnp.float32).sum()
+    compiled = jax.jit(jax.grad(total, range(3))).trace(
+        shape(32), shape(4), shape(4)).lower(
+        lowering_platforms=("tpu",)).compile()
+    text = compiled.as_text()
+    for kernel in ("flash_attention_fwd", "flash_attention_bwd_dq",
+                   "flash_attention_bwd_dkv"):
+        assert kernel in text
+
+
+@pytest.mark.parametrize("router", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bf16_control"])
+def test_dropless_expert_layer_compiles_for_v5e(one_chip, no_compile_cache,
+                                                monkeypatch, router):
+    """Routing, sort, the two grouped products and their gradients at the
+    cell's shape: ``ragged_dot`` has to have a lowering for the TPU forward
+    and for both gradients.  The bf16 router is the precision control of
+    ``tests_tpu/test_moe_decoder_tpu.py``."""
+    from mxnet_tpu.ops.registry import get_op
+    from mxnet_tpu.parallel import moe
+    monkeypatch.setattr(moe, "_ROUTER_DTYPE", router)
+    n, d, f, held, routed, k = 8192, 2304, 896, 16, 64, 8
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=one_chip)
+
+    def total(tokens, router, gate_up, down):
+        out, _ = get_op("moe_dropless_ffn")(
+            tokens, router, gate_up, down, num_experts=routed,
+            first_expert=0, k=k)
+        return out.astype(jnp.float32).sum()
+    compiled = jax.jit(jax.grad(total, range(4))).trace(
+        shape(n, d), shape(d, routed), shape(held, d, 2 * f),
+        shape(held, f, d)).lower(lowering_platforms=("tpu",)).compile()
+    # the N x k rows through both products, forward and backward, and no
+    # (N, E, C) dispatch tensor: under 3 GB of temporaries
+    assert compiled.memory_analysis().temp_size_in_bytes < 3e9
+    assert "ragged-dot" in compiled.as_text()

@@ -34,12 +34,16 @@ def _dense(q, k, v, causal):
 # BERT-base at batch 64 (12 heads x 64, seq 128); a causal long-sequence
 # shape; phi4_mini_flash.train_s4096's layer (40 query and 20 K/V heads of
 # 64, causal, the block sambay.py gives) at the 2,048 positions whose dense
-# float32 reference and its backward fit beside it; all bf16
+# float32 reference and its backward fit beside it;
+# mellum2_12b_a2_5b.train_s8192's full layer (32 query and 4 K/V heads of
+# 128: a group of EIGHT query heads summed into each K/V head's dk/dv in the
+# kernel's grid) at the same 2,048 positions; all bf16
 @pytest.mark.parametrize("bh,bh_kv,s,d,causal,dropout,block", [
     (768, 768, 128, 64, False, 0.0, 128),
     (768, 768, 128, 64, False, 0.1, 128),
     (96, 96, 512, 64, True, 0.0, 128),
     (40, 20, 2048, 64, True, 0.0, _flash_block(2048)),
+    (32, 4, 2048, 128, True, 0.0, _flash_block(2048)),
 ])
 def test_flash_real_shapes_compiled(bh, bh_kv, s, d, causal, dropout, block):
     """Forward and backward compile on Mosaic at the real shapes (three
