@@ -1,8 +1,11 @@
 """The self-attention block the decoders of this zoo share (``sambay.py``,
 ``moe_decoder.py``): grouped-query causal softmax attention behind one fused
-QKV projection, over a sliding window (``ops.window_attention``, banded XLA)
-or the whole prefix (``ops.flash_attention``, the Pallas kernels), with an
-optional rotary step on Q and K in front of either."""
+QKV projection, over a sliding window (``ops.window_attention``) or the whole
+prefix (``ops.flash_attention``): the same Pallas kernels either way, the
+window's on a grid that walks the band and under names of their own
+(``window_attention_fwd`` / ``_bwd_dq`` / ``_bwd_dkv``), which picks its block
+itself because it pads what the block does not divide.  An optional rotary
+step on Q and K stands in front of either."""
 from __future__ import annotations
 
 import jax

@@ -86,6 +86,25 @@ def _multi_head_attention(q, k, v, mask=None, heads=1, dropout=0.0,
     return jnp.transpose(out, (0, 2, 1, 3)).reshape(b, sq, hd)
 
 
+def _flash(q, k, v, heads, kv_heads, causal, block_q, block_k, dropout=0.0,
+           seed=None, window=None):
+    """(B, S, H*D) projections, k and v (B, S, kv_heads*D; None: as many
+    as ``heads``), through the Pallas kernels, which take (B*H, S, D), and
+    back."""
+    from .pallas import flash_attention
+    b, sq, hd = q.shape
+    d = hd // heads
+    kv_heads = heads if kv_heads is None else kv_heads
+    def to_bhsd(x, h=heads):
+        return jnp.transpose(x.reshape(b, -1, h, d),
+                             (0, 2, 1, 3)).reshape(b * h, -1, d)
+    out = flash_attention(to_bhsd(q), to_bhsd(k, kv_heads),
+                          to_bhsd(v, kv_heads), None, causal, block_q,
+                          block_k, None, dropout, seed, window)
+    out = out.reshape(b, heads, sq, d)
+    return jnp.transpose(out, (0, 2, 1, 3)).reshape(b, sq, hd)
+
+
 @register_op("flash_attention")
 def _flash_attention_op(q, k, v, heads=1, causal=False, block_q=128,
                         block_k=128, dropout=0.0, training=None,
@@ -99,67 +118,43 @@ def _flash_attention_op(q, k, v, heads=1, causal=False, block_q=128,
     serves ``heads // kv_heads`` query heads."""
     from .. import autograd as _autograd
     from .. import random as _random
-    from .pallas import flash_attention
     if training is None:
         training = _autograd.is_training()
-    b, sq, hd = q.shape
-    d = hd // heads
-    def to_bhsd(x, h=heads):
-        return jnp.transpose(x.reshape(b, -1, h, d),
-                             (0, 2, 1, 3)).reshape(b * h, -1, d)
-    kvh = heads if kv_heads is None else kv_heads
     drop = float(dropout) if training else 0.0
     seed = None
     if drop > 0.0:
         seed = jax.random.randint(_random.next_key(), (1,), 0, 2 ** 31 - 1)
-    out = flash_attention(to_bhsd(q), to_bhsd(k, kvh), to_bhsd(v, kvh), None,
-                          causal, block_q, block_k, None, drop, seed)
-    out = out.reshape(b, heads, sq, d)
-    return jnp.transpose(out, (0, 2, 1, 3)).reshape(b, sq, hd)
+    return _flash(q, k, v, heads, kv_heads, causal, block_q, block_k, drop,
+                  seed)
+
+
+def _window_block(s):
+    """Query and key block of the windowed flash kernels, from the sequence
+    alone: 512, or a shorter sequence whole in lanes of 128.  The window
+    does not move it: on the chip a layer's four kernel calls take
+    15.6 ms at 512 x 512 against 18.4-25.4 at ``block_k = window`` and
+    five other shapes (a window of 1,024, heads of 128, 8,192 positions)
+    and 3.4 against 4.0-11.4 (512, heads of 64, 4,096): PERF.md 6, PR 32."""
+    return min(512, -(-s // 128) * 128)
 
 
 @register_op("window_attention")
 def _window_attention(q, k, v, heads=1, kv_heads=None, window=512):
     """Causal sliding-window attention on (B, S, H*D) projections, k and v
     (B, S, kv_heads*D): query t sees keys t-window+1 .. t, itself included.
-    Exact, in XLA: the sequence is cut into blocks of ``window`` queries,
-    and a block attends to itself and to its predecessor under the band
-    mask, so the scores are (window, 2*window) a block and never (S, S).
-    One block at a time (``lax.map``), recomputed in the backward pass."""
-    b, s, hd = q.shape
-    kvh = heads if kv_heads is None else kv_heads
-    d, group = hd // heads, heads // kvh
-    w = min(window, s)
-    nb = -(-s // w)
-    pad = nb * w - s     # padded keys lie after every real query: masked
-
-    def blocks(x, h):    # (B, S, h*D) -> (blocks, B, h, w, D)
-        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0))).reshape(b, nb, w, h, d)
-        return jnp.transpose(x, (1, 0, 3, 2, 4))
-
-    def with_previous(x):                   # -> (blocks, B, h, 2w, D)
-        return jnp.concatenate(
-            [jnp.concatenate([jnp.zeros_like(x[:1]), x[:-1]]), x], axis=3)
-
-    qi = jnp.arange(w)[:, None] + w         # a query's place among its 2w keys
-    kj = jnp.arange(2 * w)[None, :]
-    band = (kj <= qi) & (kj > qi - window)
-    scale = 1.0 / (d ** 0.5)
-
-    def one(args):
-        i, qb, kb, vb = args
-        scores = jnp.einsum("bhgqd,bhkd->bhgqk", qb, kb,
-                            preferred_element_type=jnp.float32) * scale
-        # the first block has no predecessor
-        scores = jnp.where(band & ((kj >= w) | (i > 0)), scores, -1e30)
-        p = jax.nn.softmax(scores, axis=-1).astype(vb.dtype)
-        return jnp.einsum("bhgqk,bhkd->bhgqd", p, vb)
-
-    out = jax.lax.map(jax.checkpoint(one), (
-        jnp.arange(nb), blocks(q, heads).reshape(nb, b, kvh, group, w, d),
-        with_previous(blocks(k, kvh)), with_previous(blocks(v, kvh))))
-    out = jnp.transpose(out.reshape(nb, b, heads, w, d), (1, 0, 3, 2, 4))
-    return out.reshape(b, nb * w, hd)[:, :s]
+    Exact, through the Pallas flash kernels with a window
+    (ops/pallas/flash_attention.py, ``window_attention_fwd`` / ``_bwd_dq`` /
+    ``_bwd_dkv`` in a trace): their grid walks the band's block pairs and
+    no others, and the scores never leave VMEM.  A sequence that the block
+    does not divide is padded at the end (padded keys lie after every real
+    query: causality masks them) and cut back."""
+    s = q.shape[1]
+    block = _window_block(s)
+    pad = -s % block
+    if pad:
+        q, k, v = (jnp.pad(x, ((0, 0), (0, pad), (0, 0))) for x in (q, k, v))
+    out = _flash(q, k, v, heads, kv_heads, True, block, block, window=window)
+    return out[:, :s] if pad else out
 
 
 @register_op("div_sqrt_dim", aliases=("_contrib_div_sqrt_dim",))

@@ -34,6 +34,16 @@ times):
   skipped and name the block already held, so they fetch nothing; the mask is
   applied only where the diagonal crosses a pair (at 4,096 positions in
   blocks of 512: 36 of 64 pairs computed, 8 of them masked).
+
+With a ``window`` (PR 32; query ``t`` sees keys ``t-window+1 .. t``) the same
+three bodies run under the names ``window_attention_fwd`` / ``_bwd_dq`` /
+``_bwd_dkv``, and **the grid's inner axis walks the band, not the sequence**:
+``_band_steps`` key blocks a query block (and query blocks a key block), the
+block of each step worked out in the index map, a step off the sequence's
+edge clamped onto it and skipped.  The mask also cuts a pair that the
+window's far edge crosses; ``band_pairs`` counts both (at 8,192 positions,
+blocks of 512 and a window of 1,024: 45 pairs a head, 30 of them masked,
+where the causal kernels compute 136).
 """
 from __future__ import annotations
 
@@ -88,16 +98,20 @@ def _positions(shape, qi, kj, block_q, block_k, q_axis=0):
     return q_pos, k_pos
 
 
-def _scores(a, b, scale, masked, dropout, qi, kj, block_q, block_k, q_axis=0):
-    """``scale * a b^T`` in float32, the causal mask applied if the diagonal
-    crosses this pair, and the block's (query, key) positions where the
-    mask or dropout needs them (else None)."""
+def _scores(a, b, scale, masked, dropout, qi, kj, block_q, block_k, window,
+            q_axis=0):
+    """``scale * a b^T`` in float32, the mask applied if the diagonal or the
+    window's far edge crosses this pair, and the block's (query, key)
+    positions where the mask or dropout needs them (else None)."""
     s = _dot(a, b, _NT) * scale
     if not masked and dropout == 0.0:
         return s, None
     q_pos, k_pos = _positions(s.shape, qi, kj, block_q, block_k, q_axis)
     if masked:
-        s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+        seen = q_pos >= k_pos
+        if window is not None:
+            seen &= k_pos > q_pos - window
+        s = jnp.where(seen, s, _NEG_INF)
     return s, (q_pos, k_pos)
 
 
@@ -138,12 +152,14 @@ def _across(stat, n):
 
 def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
                 m_ref, l_ref, *, scale, causal, block_q, block_k, n_k,
-                dropout):
+                dropout, window, seq):
     b = pl.program_id(0)
     qi = pl.program_id(1)
-    kj = pl.program_id(2)
+    step = pl.program_id(2)
+    kj = step if window is None else _band_key_block(
+        qi, step, block_q, block_k, n_k)
 
-    @pl.when(kj == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
@@ -152,9 +168,12 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
     def _compute(masked):
         v = v_ref[0]
         s, pos = _scores(q_ref[0], k_ref[0], scale, masked, dropout, qi, kj,
-                         block_q, block_k)            # (bq, bk) float32
+                         block_q, block_k, window)    # (bq, bk) float32
         m_prev = m_ref[...]                           # (bq, 128)
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        # a row the window hides from a whole pair holds no score yet: its
+        # alpha is exp(-1e30 - m) = 0 at the first key it sees, so what it
+        # summed until then is dropped
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - _across(m_new, s.shape[1]))
         # l tracks the TRUE softmax normaliser (pre-dropout), so lse is exact
@@ -164,9 +183,9 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
         acc_ref[...] = (acc_ref[...] * _across(alpha, v.shape[1])
                         + _dot(p.astype(v.dtype), v))
 
-    _causal_pairs(_compute, causal, qi, kj, block_q, block_k)
+    _visible_pairs(_compute, causal, qi, kj, block_q, block_k, window, seq)
 
-    @pl.when(kj == n_k - 1)
+    @pl.when(step == n_k - 1)
     def _finish():
         l = l_ref[...]
         o_ref[0] = (acc_ref[...] / _across(l, acc_ref.shape[1])
@@ -174,52 +193,134 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
         lse_ref[0, 0] = (m_ref[...] + jnp.log(l)).T[:1]
 
 
-def _causal_pairs(compute, causal, qi, kj, block_q, block_k):
+def _whole(first_q, last_q, first_k, last_k, window, s):
+    """Every query of the pair sees every key of it: no mask."""
+    whole = last_k <= first_q
+    if window is None:
+        return whole
+    return (whole & (first_k > last_q - window)
+            & (first_k >= 0) & (first_q < s))
+
+
+def _crossed(first_q, last_q, first_k, last_k, window, s):
+    """Some query of the pair sees some key of it, and the diagonal or the
+    window's far edge crosses it: computed under the mask."""
+    if window is None:
+        return (first_k <= last_q) & (last_k > first_q)
+    return ((first_k <= last_q) & (last_k > first_q - window)
+            & ((last_k > first_q) | (first_k <= last_q - window))
+            & (first_k >= 0) & (first_q < s))       # else a clamped step
+
+
+def _edges(qi, kj, block_q, block_k):
+    """First and last position of the pair's queries, then of its keys."""
+    return (qi * block_q, qi * block_q + block_q - 1,
+            kj * block_k, kj * block_k + block_k - 1)
+
+
+def _visible_pairs(compute, causal, qi, kj, block_q, block_k, window, s):
     """Run ``compute(masked)`` on the block pair ``(qi, kj)``: not at all
-    where every key lies in the future of every query, with the mask
-    where the diagonal crosses the pair, and without it below."""
+    where no query sees a key (every key in the future, or behind the
+    window of every query, or the pair a step off the sequence's edge),
+    with the mask where the diagonal or the window's far edge crosses the
+    pair, and without it between them."""
     if not causal:
         return compute(False)
-    first_q, last_q = qi * block_q, qi * block_q + block_q - 1
-    first_k, last_k = kj * block_k, kj * block_k + block_k - 1
-    pl.when(last_k <= first_q)(lambda: compute(False))
-    pl.when((first_k <= last_q) & (last_k > first_q))(lambda: compute(True))
+    edges = _edges(qi, kj, block_q, block_k)
+    pl.when(_whole(*edges, window, s))(lambda: compute(False))
+    pl.when(_crossed(*edges, window, s))(lambda: compute(True))
 
 
-def _needed(causal, block_q, block_k):
-    """Index maps clamped to the blocks a causal pair can use: the last key
-    block of query block ``i`` and the first query block of key block ``j``.
-    A grid step past them names the block it already holds, and fetches
-    nothing."""
+def band_pairs(s, block_q, block_k, window):
+    """``(computed, masked)`` block pairs of one head under a causal
+    ``window`` (None: causal alone), by the rule the kernels branch on: how
+    often the mechanism engages is static."""
+    pairs = [_edges(i, j, block_q, block_k)
+             for i in range(s // block_q) for j in range(s // block_k)]
+    masked = sum(bool(_crossed(*e, window, s)) for e in pairs)
+    return sum(bool(_whole(*e, window, s)) for e in pairs) + masked, masked
+
+
+def _band_steps(s, block_q, block_k, window):
+    """Steps of the grid's inner axis under a window: the most key blocks a
+    query block's band touches (keys ``first_q - window + 1 .. last_q``),
+    and the most query blocks that see a key block (queries ``first_k ..
+    last_k + window - 1``), the sequence's edges cutting both.  Where
+    ``block_k`` divides ``block_q`` the first is ``ceil((block_q + window -
+    1) / block_k)``: 3 at blocks of 512 and a window of 1,024, 2 at 512."""
+    n_q, n_k = s // block_q, s // block_k
+    keys = max((i * block_q + block_q - 1) // block_k
+               - max(i * block_q - window + 1, 0) // block_k + 1
+               for i in range(n_q))
+    queries = max(min((j * block_k + block_k + window - 2) // block_q,
+                      n_q - 1) - j * block_k // block_q + 1
+                  for j in range(n_k))
+    return keys, queries
+
+
+def _band_key_block(qi, step, block_q, block_k, n_steps):
+    """The key block of a step of query block ``qi``'s walk, which ends on
+    the block that holds its last query; negative before the sequence."""
+    return (qi * block_q + block_q - 1) // block_k - (n_steps - 1) + step
+
+
+def _band_query_block(kj, step, block_q, block_k):
+    """The query block of a step of key block ``kj``'s walk, which starts
+    on the block that holds its first key; past ``s`` after the sequence."""
+    return kj * block_k // block_q + step
+
+
+def _needed(causal, block_q, block_k, window, s):
+    """``(key steps, key block of (i, j), query steps, query block of
+    (i, j))``: the length of the grid's inner axis in the forward and dq
+    kernels and in the dk/dv kernel, and their index maps, ``i`` on the
+    query side and ``j`` on the key side.  Causal: the whole sequence,
+    clamped to the last key block of query block ``i`` and the first query
+    block of key block ``j``; a grid step past them names the block it
+    already holds, and fetches nothing.  With a window: the band alone,
+    clamped to the sequence."""
+    n_q, n_k = s // block_q, s // block_k
     if not causal:
-        return (lambda i, j: j), (lambda i, j: i)
-    return (lambda i, j: jnp.minimum(j, (i * block_q + block_q - 1)
-                                     // block_k),
-            lambda i, j: jnp.maximum(i, j * block_k // block_q))
+        return n_k, (lambda i, j: j), n_q, (lambda i, j: i)
+    if window is None:
+        return (n_k, lambda i, j: jnp.minimum(j, (i * block_q + block_q - 1)
+                                              // block_k),
+                n_q, lambda i, j: jnp.maximum(i, j * block_k // block_q))
+    k_steps, q_steps = _band_steps(s, block_q, block_k, window)
+    return (k_steps, lambda i, j: jnp.maximum(_band_key_block(
+                i, j, block_q, block_k, k_steps), 0),
+            q_steps, lambda i, j: jnp.minimum(_band_query_block(
+                j, i, block_q, block_k), n_q - 1))
+
+
+def _name(kernel, window):
+    """A kernel's name in the program and in a device trace: the windowed
+    calls under their own, so that a reader tells a window layer's from a
+    full layer's at the same operand shape."""
+    return ("flash" if window is None else "window") + "_attention_" + kernel
 
 
 # jitted, so that a program traces and lowers each kernel once however many
 # layers (and recomputations) call it: twelve calls a step in the benchmark's
 # cell
-@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8, 9))
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8, 9, 10))
 def _flash_fwd(q, k, v, seed, scale, causal, block_q, block_k, interpret,
-               dropout):
+               dropout, window=None):
     bh, s, d = q.shape
     block_q = min(block_q, s)
     block_k = min(block_k, s)
     assert s % block_q == 0 and s % block_k == 0, (s, block_q, block_k)
-    n_k = s // block_k
     # grouped-query heads: ``q`` holds ``group`` times the rows of ``k``
     # (batch-major, then heads), so query row ``b`` reads K/V row b // group
     group, rest = divmod(bh, k.shape[0])
     assert rest == 0, (q.shape, k.shape)
-    last_k, _ = _needed(causal, block_q, block_k)
+    n_k, key_block, _, _ = _needed(causal, block_q, block_k, window, s)
     kv_spec = pl.BlockSpec((1, block_k, d),
-                           lambda b, i, j: (b // group, last_k(i, j), 0))
+                           lambda b, i, j: (b // group, key_block(i, j), 0))
     q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, block_q=block_q,
-        block_k=block_k, n_k=n_k, dropout=dropout)
+        block_k=block_k, n_k=n_k, dropout=dropout, window=window, seq=s)
     out, lse = pl.pallas_call(
         kernel,
         grid=(bh, s // block_q, n_k),
@@ -241,7 +342,7 @@ def _flash_fwd(q, k, v, seed, scale, causal, block_q, block_k, interpret,
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
-        name="flash_attention_fwd",
+        name=_name("fwd", window),
         interpret=interpret,
     )(seed, q, k, v)
     return out, lse.reshape(bh, s)
@@ -259,12 +360,14 @@ def _columns(row):
 
 def _dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                dq_ref, dq_acc, lse_col, delta_col, *, scale, causal,
-               block_q, block_k, n_k, dropout):
+               block_q, block_k, n_k, dropout, window, seq):
     b = pl.program_id(0)
     qi = pl.program_id(1)
-    kj = pl.program_id(2)
+    step = pl.program_id(2)
+    kj = step if window is None else _band_key_block(
+        qi, step, block_q, block_k, n_k)
 
-    @pl.when(kj == 0)
+    @pl.when(step == 0)
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
         # the statistics arrive as rows; every pair of this query block
@@ -275,7 +378,7 @@ def _dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _compute(masked):
         k = k_ref[0]
         s, pos = _scores(q_ref[0], k, scale, masked, dropout, qi, kj,
-                         block_q, block_k)            # (bq, bk) float32
+                         block_q, block_k, window)    # (bq, bk) float32
         # true softmax probs (pre-dropout)
         p = jnp.exp(s - _across(lse_col[...], s.shape[1]))
         dp = _dropped(_dot(do_ref[0], v_ref[0], _NT),
@@ -283,16 +386,16 @@ def _dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         ds = p * (dp - _across(delta_col[...], s.shape[1]))
         dq_acc[...] += _dot(ds.astype(k.dtype), k)
 
-    _causal_pairs(_compute, causal, qi, kj, block_q, block_k)
+    _visible_pairs(_compute, causal, qi, kj, block_q, block_k, window, seq)
 
-    @pl.when(kj == n_k - 1)
+    @pl.when(step == n_k - 1)
     def _finish():
         dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
 def _dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal, block_q,
-                block_k, group, n_q, dropout):
+                block_k, group, n_q, dropout, window, seq):
     """The score block TRANSPOSED, keys down and queries across: the row
     statistics are rows as they arrive, and ``p^T do`` and ``ds^T q`` are
     plain products.  A K/V head accumulates over its ``group`` query
@@ -300,9 +403,11 @@ def _dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     bkv = pl.program_id(0)
     kj = pl.program_id(1)
     g = pl.program_id(2)
-    qi = pl.program_id(3)
+    step = pl.program_id(3)
+    qi = step if window is None else _band_query_block(
+        kj, step, block_q, block_k)
 
-    @pl.when((g == 0) & (qi == 0))
+    @pl.when((g == 0) & (step == 0))
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
@@ -311,7 +416,7 @@ def _dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         q = q_ref[0]
         do = do_ref[0]
         st, pos = _scores(k_ref[0], q, scale, masked, dropout, qi, kj,
-                          block_q, block_k, q_axis=1)  # (bk, bq) float32
+                          block_q, block_k, window, q_axis=1)  # (bk, bq)
         pt = jnp.exp(st - lse_ref[0, 0])
         dpt = _dot(v_ref[0], do, _NT)
         keep = _keep(bkv * group + g, pos, seed_ref[0], dropout)
@@ -319,36 +424,36 @@ def _dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dst = pt * (_dropped(dpt, keep, dropout) - delta_ref[0, 0])
         dk_acc[...] += _dot(dst.astype(q.dtype), q)
 
-    _causal_pairs(_compute, causal, qi, kj, block_q, block_k)
+    _visible_pairs(_compute, causal, qi, kj, block_q, block_k, window, seq)
 
-    @pl.when((g == group - 1) & (qi == n_q - 1))
+    @pl.when((g == group - 1) & (step == n_q - 1))
     def _finish():
         dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnums=(7, 8, 9, 10, 11, 12))
+@functools.partial(jax.jit, static_argnums=(7, 8, 9, 10, 11, 12, 13))
 def _flash_bwd(q, k, v, seed, o, lse, do, scale, causal, block_q, block_k,
-               interpret, dropout):
+               interpret, dropout, window=None):
     bh, s, d = q.shape
     block_q = min(block_q, s)
     block_k = min(block_k, s)
-    n_q, n_k = s // block_q, s // block_k
     group = bh // k.shape[0]
-    last_k, first_q = _needed(causal, block_q, block_k)
+    n_k, key_block, n_q, query_block = _needed(causal, block_q, block_k,
+                                               window, s)
     delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
-    lse = lse.reshape(bh, n_q, 1, block_q)
-    delta = delta.reshape(bh, n_q, 1, block_q)
+    lse = lse.reshape(bh, s // block_q, 1, block_q)
+    delta = delta.reshape(bh, s // block_q, 1, block_q)
 
     q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
     kv_spec = pl.BlockSpec((1, block_k, d),
-                           lambda b, i, j: (b // group, last_k(i, j), 0))
+                           lambda b, i, j: (b // group, key_block(i, j), 0))
     row_spec = _row_spec(block_q, lambda b, i, j: (b, i, 0, 0))
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, n_k=n_k,
-                          dropout=dropout),
-        grid=(bh, n_q, n_k),
+                          dropout=dropout, window=window, seq=s),
+        grid=(bh, s // block_q, n_k),
         in_specs=[pl.BlockSpec((1,), lambda b, i, j: (0,)),
                   q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
         out_specs=q_spec,
@@ -356,7 +461,7 @@ def _flash_bwd(q, k, v, seed, o, lse, do, scale, causal, block_q, block_k,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
                         pltpu.VMEM((block_q, _LANES), jnp.float32),
                         pltpu.VMEM((block_q, _LANES), jnp.float32)],
-        name="flash_attention_bwd_dq",
+        name=_name("bwd_dq", window),
         interpret=interpret,
     )(seed, q, k, v, do, lse, delta)
 
@@ -364,15 +469,15 @@ def _flash_bwd(q, k, v, seed, o, lse, do, scale, causal, block_q, block_k,
     # of query heads, which the grid walks before it moves to the next block
     q_spec = pl.BlockSpec(
         (1, block_q, d),
-        lambda b, j, g, i: (b * group + g, first_q(i, j), 0))
+        lambda b, j, g, i: (b * group + g, query_block(i, j), 0))
     kv_spec = pl.BlockSpec((1, block_k, d), lambda b, j, g, i: (b, j, 0))
     row_spec = _row_spec(
-        block_q, lambda b, j, g, i: (b * group + g, first_q(i, j), 0, 0))
+        block_q, lambda b, j, g, i: (b * group + g, query_block(i, j), 0, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, group=group,
-                          n_q=n_q, dropout=dropout),
-        grid=(k.shape[0], n_k, group, n_q),
+                          n_q=n_q, dropout=dropout, window=window, seq=s),
+        grid=(k.shape[0], s // block_k, group, n_q),
         in_specs=[pl.BlockSpec((1,), lambda b, j, g, i: (0,)),
                   q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
         out_specs=[kv_spec, kv_spec],
@@ -380,23 +485,24 @@ def _flash_bwd(q, k, v, seed, o, lse, do, scale, causal, block_q, block_k,
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
-        name="flash_attention_bwd_dkv",
+        name=_name("bwd_dkv", window),
         interpret=interpret,
     )(seed, q, k, v, do, lse, delta)
     return dq, dk, dv
 
 
 # ----------------------------------------------------------- public api -----
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
 def _flash_attention_core(q, k, v, seed, scale, causal, block_q, block_k,
-                          interpret, dropout):
+                          interpret, dropout, window):
     out, _ = _flash_fwd_rule(q, k, v, seed, scale, causal, block_q, block_k,
-                             interpret, dropout)
+                             interpret, dropout, window)
     return out
 
 
 def flash_attention(q, k, v, scale=None, causal=False, block_q=128,
-                    block_k=128, interpret=None, dropout=0.0, seed=None):
+                    block_k=128, interpret=None, dropout=0.0, seed=None,
+                    window=None):
     """softmax(scale · Q Kᵀ [, causal]) V without materialising S×S.
 
     q: (B*H, S, D); k, v the same, or (B*H_kv, S, D) for grouped-query
@@ -407,13 +513,18 @@ def flash_attention(q, k, v, scale=None, causal=False, block_q=128,
     forward AND backward — never stored).  ``seed`` may be a traced int32
     scalar so each training step draws a fresh mask without retracing.
     ``interpret=None`` auto-selects the Pallas interpreter off-TPU (CPU-mesh
-    tests) and the compiled kernel on TPU."""
+    tests) and the compiled kernel on TPU.  ``window=w`` (causal only):
+    query ``t`` sees keys ``t-w+1 .. t``, itself included, and the kernels'
+    grids walk that band alone."""
+    if window is not None and (not causal or window < 1):
+        raise ValueError(
+            f"window={window!r} needs causal=True and at least one key")
     if seed is None:
         seed = jnp.zeros((1,), jnp.int32)
     else:
         seed = jnp.asarray(seed, jnp.int32).reshape((1,))
     return _flash_attention_core(q, k, v, seed, scale, causal, block_q,
-                                 block_k, interpret, dropout)
+                                 block_k, interpret, dropout, window)
 
 
 def _resolve(scale, d, interpret):
@@ -425,19 +536,20 @@ def _resolve(scale, d, interpret):
 
 
 def _flash_fwd_rule(q, k, v, seed, scale, causal, block_q, block_k,
-                    interpret, dropout):
+                    interpret, dropout, window):
     scale, interpret = _resolve(scale, q.shape[-1], interpret)
     out, lse = _flash_fwd(q, k, v, seed, scale, causal, block_q, block_k,
-                          interpret, float(dropout))
+                          interpret, float(dropout), window)
     return out, (q, k, v, seed, out, lse)
 
 
 def _flash_bwd_rule(scale, causal, block_q, block_k, interpret, dropout,
-                    res, do):
+                    window, res, do):
     q, k, v, seed, o, lse = res
     scale, interpret = _resolve(scale, q.shape[-1], interpret)
     dq, dk, dv = _flash_bwd(q, k, v, seed, o, lse, do, scale, causal,
-                            block_q, block_k, interpret, float(dropout))
+                            block_q, block_k, interpret, float(dropout),
+                            window)
     return dq, dk, dv, None
 
 

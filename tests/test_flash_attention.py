@@ -259,9 +259,10 @@ def test_bert_flash_dropout_trains():
 # statistics lane-replicated, the mask only where the diagonal crosses a
 # pair, K/V index maps clamped to the blocks a causal pair can use
 # ---------------------------------------------------------------------------
-def _dense_f32(q, k, v, causal, scale=None):
+def _dense_f32(q, k, v, causal, scale=None, window=None):
     """softmax(q k^T) v in float32 on upcast operands, (B*H, S, D) with
-    grouped-query K/V repeated: the formulation the kernels round from."""
+    grouped-query K/V repeated: the formulation the kernels round from.
+    With a ``window`` query t sees keys t-window+1 .. t."""
     import jax
     import jax.numpy as jnp
     q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
@@ -270,8 +271,11 @@ def _dense_f32(q, k, v, causal, scale=None):
     scale = 1.0 / np.sqrt(q.shape[-1]) if scale is None else scale
     s = jnp.einsum("bqd,bkd->bqk", q, k) * scale
     if causal:
-        n = s.shape[-1]
-        s = jnp.where(jnp.tril(jnp.ones((n, n), bool)), s, -1e30)
+        at = jnp.arange(s.shape[-1])
+        seen = at[None, :] <= at[:, None]
+        if window is not None:
+            seen &= at[None, :] > at[:, None] - window
+        s = jnp.where(seen, s, -1e30)
     return jnp.einsum("bqk,bkd->bqd", jax.nn.softmax(s, axis=-1), v)
 
 
@@ -387,3 +391,119 @@ def test_flash_float32_input_gives_todays_result(which):
         return      # the sums are XLA:CPU's
     np.testing.assert_allclose(float(jnp.abs(got).sum()),
                                _FLOAT32_PARENT[which], rtol=2e-6)
+
+
+# ---------------------------------------------------------------------------
+# the window of PR 32: the same bodies under a mask that the window's far
+# edge cuts too, on a grid whose inner axis walks the band's blocks alone
+# ---------------------------------------------------------------------------
+# (positions, block_q, block_k, window, query heads, K/V heads): a window
+# below, equal to and above a block, the query alone, the whole sequence and
+# more, rectangular blocks either way, grouped heads 8:1 and 2:1
+_WINDOWS = [(128, 32, 32, 16, 2, 2), (128, 32, 32, 32, 2, 2),
+            (128, 32, 32, 48, 2, 2), (128, 32, 32, 65, 2, 2),
+            (128, 32, 32, 1, 2, 2), (128, 32, 32, 128, 2, 2),
+            (128, 32, 32, 200, 2, 2), (128, 16, 64, 40, 2, 2),
+            (128, 64, 16, 40, 2, 2), (128, 32, 16, 33, 2, 2),
+            (64, 32, 32, 40, 8, 1), (64, 16, 32, 24, 4, 2)]
+
+
+@pytest.mark.parametrize("s,block_q,block_k,window,heads,kv_heads", _WINDOWS)
+def test_flash_window_is_the_banded_softmax(s, block_q, block_k, window,
+                                            heads, kv_heads):
+    """Output and all three gradients of ``flash_attention(window=w)``
+    against the dense band: the pairs behind the window skipped, the pairs
+    its far edge crosses masked, the steps clamped onto the sequence's edge
+    skipped, a K/V head's gradient summed over its group."""
+    import jax.numpy as jnp
+    from mxnet_tpu.ops.pallas.flash_attention import flash_attention
+    rng = np.random.RandomState(11)
+    q, w = (jnp.asarray(rng.randn(heads, s, 8) * 0.5, jnp.float32)
+            for _ in range(2))
+    k, v = (jnp.asarray(rng.randn(kv_heads, s, 8) * 0.5, jnp.float32)
+            for _ in range(2))
+    got = _out_and_grads(
+        lambda *a: flash_attention(*a, causal=True, block_q=block_q,
+                                   block_k=block_k, window=window),
+        q, k, v, w)
+    want = _out_and_grads(
+        lambda *a: _dense_f32(*a, True, window=window), q, k, v, w)
+    assert got["dk"].shape == k.shape and got["dv"].shape == v.shape
+    for name in got:
+        np.testing.assert_allclose(got[name], want[name],
+                                   **_tol(2e-4, 2e-5, 1e-2), err_msg=name)
+
+
+def _live_pairs(s, block_q, block_k, window):
+    """(computed, masked) by looking at every (query, key) of every pair."""
+    at = np.arange(s)
+    seen = (at[None, :] <= at[:, None]) & (at[None, :] > at[:, None] - window)
+    pairs = seen.reshape(s // block_q, block_q, s // block_k, block_k)
+    some, every = pairs.any(axis=(1, 3)), pairs.all(axis=(1, 3))
+    return int(some.sum()), int((some & ~every).sum())
+
+
+@pytest.mark.parametrize("s,block_q,block_k,window", sorted(
+    {c[:4] for c in _WINDOWS} | {(8192, 512, 512, 1024), (4096, 512, 512, 512),
+                                 (4096, 256, 256, 512), (8192, 512, 1024, 1024)}))
+def test_band_pairs_counts_the_live_pairs(s, block_q, block_k, window):
+    """How often the mechanism engages is static: ``band_pairs`` (the rule
+    the kernels branch on) against a count over every position, and the
+    walk's steps never fewer than a block's live pairs."""
+    from mxnet_tpu.ops.pallas.flash_attention import _band_steps, band_pairs
+    assert band_pairs(s, block_q, block_k, window) == _live_pairs(
+        s, block_q, block_k, window)
+    k_steps, q_steps = _band_steps(s, block_q, block_k, window)
+    computed, _ = band_pairs(s, block_q, block_k, window)
+    assert computed <= min(k_steps * (s // block_q), q_steps * (s // block_k))
+
+
+def test_band_pairs_at_the_cells_shapes():
+    """mellum2_12b_a2_5b.train_s8192: 45 pairs a head where the causal
+    kernels compute 136, three steps a query block; phi4_mini_flash.
+    train_s4096: 15 for 36, two steps, every pair masked."""
+    from mxnet_tpu.ops.pallas.flash_attention import _band_steps, band_pairs
+    assert band_pairs(8192, 512, 512, 1024) == (45, 30)
+    assert band_pairs(8192, 512, 512, None) == (136, 16)
+    assert _band_steps(8192, 512, 512, 1024) == (3, 3)
+    assert band_pairs(4096, 512, 512, 512) == (15, 15)
+    assert band_pairs(4096, 512, 512, None) == (36, 8)
+    assert _band_steps(4096, 512, 512, 512) == (2, 2)
+
+
+@pytest.mark.parametrize("which", ["out", "dq", "dk", "dv"])
+def test_flash_window_over_the_whole_sequence_is_causal_bit_for_bit(which):
+    """A window that hides nothing walks every block in the causal
+    kernels' order and does their arithmetic: equal bits, from kernels of
+    another name; ``window=None`` is the call without it."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops.pallas.flash_attention import flash_attention
+    rng = np.random.RandomState(10)
+    q, k, v, w = (jnp.asarray(rng.randn(2, 64, 16) * 0.5, jnp.float32)
+                  for _ in range(4))
+
+    def fn(**window):
+        return lambda *a: flash_attention(*a, causal=True, block_q=32,
+                                          block_k=16, **window)
+    causal = _out_and_grads(fn(), q, k, v, w)[which]
+    for window in (None, 64, 1000):
+        got = _out_and_grads(fn(window=window), q, k, v, w)[which]
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(causal))
+        text = str(jax.make_jaxpr(jax.grad(
+            lambda *a: (fn(window=window)(*a) * w).sum(), (0, 1, 2)))(q, k, v))
+        assert ("window_attention_" in text) == (window is not None)
+        assert ("flash_attention_" in text) == (window is None)
+    if not mx.context.on_tpu():     # today's numbers: the sums are XLA:CPU's
+        np.testing.assert_allclose(float(jnp.abs(causal).sum()),
+                                   _FLOAT32_PARENT[which], rtol=2e-6)
+
+
+def test_flash_window_needs_causal_and_a_key():
+    import jax.numpy as jnp
+    from mxnet_tpu.ops.pallas.flash_attention import flash_attention
+    q = jnp.zeros((1, 32, 8), jnp.float32)
+    with pytest.raises(ValueError, match="window"):
+        flash_attention(q, q, q, window=8)
+    with pytest.raises(ValueError, match="window"):
+        flash_attention(q, q, q, causal=True, window=0)

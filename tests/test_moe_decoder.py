@@ -650,7 +650,8 @@ def test_train_step_program_names_every_new_scope(stepped):
             assert any("transpose(jvp(forward))" in p for p in forward), scope
     assert "rematted_computation" in text
     for kernel in ("flash_attention_fwd", "flash_attention_bwd_dq",
-                   "flash_attention_bwd_dkv"):
+                   "flash_attention_bwd_dkv", "window_attention_fwd",
+                   "window_attention_bwd_dq", "window_attention_bwd_dkv"):
         assert kernel in text
     assert "ragged_dot" in text
 

@@ -348,8 +348,36 @@ def test_recomputation_is_in_the_program_only_when_asked(cut):
             mx.optimizer.create("adamw", learning_rate=1e-3),
             mesh=_one_device())
         return step.lower(ids, labels).as_text().count("optimization_barrier")
-    # the unmarked net has the scan's and the window attention's own
+    # the unmarked net has the scan's own
     assert remats(_marked()) > remats(plain)
+
+
+@pytest.mark.parametrize("marked", [False, True], ids=["plain", "marked"])
+def test_a_window_layer_is_the_windowed_kernels_and_recomputes_nothing(
+        marked):
+    """What took the banded loop's place (PR 32): the three windowed flash
+    kernels by name, each lowered once for the two layers, and no
+    recomputation of the layer's own inside the one ``recompute()`` asks
+    for."""
+    net = _net(["window", "window"])
+    if marked:
+        for layer in net.layers:
+            layer.recompute()
+    ids, labels = _batch()
+    step = parallel.TrainStep(
+        net, gluon.loss.SoftmaxCrossEntropyLoss(axis=-1),
+        mx.optimizer.create("adamw", learning_rate=1e-3), mesh=_one_device())
+    text = step.lower(ids, labels).as_text(debug_info=True)
+    for kernel in ("window_attention_fwd", "window_attention_bwd_dq",
+                   "window_attention_bwd_dkv"):
+        assert kernel in text
+        assert kernel.replace("window", "flash") not in text
+    # two layers call one lowering of each jitted kernel (the recomputed
+    # forward, whose residuals are read, is a second of ``_flash_fwd``)
+    lowered = re.findall(r"func.func private @(_flash_[a-z]+)", text)
+    assert sorted(lowered) == ["_flash_bwd"] + ["_flash_fwd"] * (1 + marked)
+    assert len(re.findall(r"call @_flash_bwd\b", text)) == 2
+    assert ("optimization_barrier" in text) == marked
 
 
 def test_recomputation_refuses_rewritten_aux_state():
@@ -568,7 +596,9 @@ def test_train_step_program_names_every_layer_scope():
                    if f"/{scope}/" in p and "jvp(forward)" in p]
         assert forward, scope
         assert any("transpose(jvp(forward))" in p for p in forward), scope
-    # the kernels carry their names into the program
+    # the kernels carry their names into the program, the window layer's
+    # under their own
     for kernel in ("flash_attention_fwd", "flash_attention_bwd_dq",
-                   "flash_attention_bwd_dkv"):
+                   "flash_attention_bwd_dkv", "window_attention_fwd",
+                   "window_attention_bwd_dq", "window_attention_bwd_dkv"):
         assert kernel in text

@@ -101,6 +101,40 @@ def test_flash_kernels_compile_for_v5e_at_heads_of_128(one_chip,
         assert kernel in text
 
 
+# the two decoders' window layers: mellum2_12b_a2_5b.train_s8192 (32 query and
+# 4 K/V heads of 128, a window of 1,024 over 8,192 positions) and
+# phi4_mini_flash.train_s4096 (40 and 20 heads of 64, 512 over 4,096)
+@pytest.mark.parametrize("heads,kv_heads,t,d,window", [
+    (32, 4, 8192, 128, 1024), (40, 20, 4096, 64, 512)],
+    ids=["mellum2_heads_of_128", "phi4_heads_of_64"])
+def test_window_kernels_compile_for_v5e(one_chip, no_compile_cache, heads,
+                                        kv_heads, t, d, window):
+    """The three windowed kernels, whose grid walks the band, at the block
+    ``ops.window_attention`` gives, bf16: the index arithmetic of their
+    block maps and the steps skipped at the sequence's edge are Mosaic's to
+    accept."""
+    from mxnet_tpu.ops.attention import _window_block
+    from mxnet_tpu.ops.pallas import flash_attention
+    block = _window_block(t)
+
+    def shape(n):
+        return jax.ShapeDtypeStruct((n, t, d), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def total(q, k, v):
+        return flash_attention(
+            q, k, v, causal=True, block_q=block, block_k=block,
+            interpret=False, window=window).astype(jnp.float32).sum()
+    compiled = jax.jit(jax.grad(total, range(3))).trace(
+        shape(heads), shape(kv_heads), shape(kv_heads)).lower(
+        lowering_platforms=("tpu",)).compile()
+    text = compiled.as_text()
+    for kernel in ("window_attention_fwd", "window_attention_bwd_dq",
+                   "window_attention_bwd_dkv"):
+        assert kernel in text
+    assert "flash_attention_" not in text
+
+
 @pytest.mark.parametrize("router", [jnp.float32, jnp.bfloat16],
                          ids=["float32", "bf16_control"])
 def test_dropless_expert_layer_compiles_for_v5e(one_chip, no_compile_cache,

@@ -15,20 +15,11 @@ if not on_tpu():
                 allow_module_level=True)
 
 from test_flash_attention import *   # noqa: F401,F403,E402
+from test_flash_attention import _dense_f32  # noqa: E402
 
 from mxnet_tpu.gluon.model_zoo.sambay import _flash_block  # noqa: E402
+from mxnet_tpu.ops.attention import _window_block  # noqa: E402
 from mxnet_tpu.ops.pallas.flash_attention import flash_attention  # noqa: E402
-
-
-def _dense(q, k, v, causal):
-    """float32 reference of softmax(QK^T/sqrt(d))V on (B*H, S, D)."""
-    q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
-    k, v = (jnp.repeat(a, q.shape[0] // k.shape[0], axis=0) for a in (k, v))
-    s = jnp.einsum("bqd,bkd->bqk", q, k) / np.sqrt(q.shape[-1])
-    if causal:
-        n = s.shape[-1]
-        s = jnp.where(jnp.tril(jnp.ones((n, n), bool)), s, -1e30)
-    return jnp.einsum("bqk,bkd->bqd", jax.nn.softmax(s, axis=-1), v)
 
 
 # BERT-base at batch 64 (12 heads x 64, seq 128); a causal long-sequence
@@ -37,18 +28,25 @@ def _dense(q, k, v, causal):
 # float32 reference and its backward fit beside it;
 # mellum2_12b_a2_5b.train_s8192's full layer (32 query and 4 K/V heads of
 # 128: a group of EIGHT query heads summed into each K/V head's dk/dv in the
-# kernel's grid) at the same 2,048 positions; all bf16
-@pytest.mark.parametrize("bh,bh_kv,s,d,causal,dropout,block", [
-    (768, 768, 128, 64, False, 0.0, 128),
-    (768, 768, 128, 64, False, 0.1, 128),
-    (96, 96, 512, 64, True, 0.0, 128),
-    (40, 20, 2048, 64, True, 0.0, _flash_block(2048)),
-    (32, 4, 2048, 128, True, 0.0, _flash_block(2048)),
+# kernel's grid) at the same 2,048 positions; then the two cells' WINDOW
+# layers (512 at heads of 64, 1,024 at heads of 128: the windowed kernels,
+# whose grid walks the band, at the block ``ops.window_attention`` gives)
+# against the dense band; all bf16
+@pytest.mark.parametrize("bh,bh_kv,s,d,causal,dropout,block,window", [
+    (768, 768, 128, 64, False, 0.0, 128, None),
+    (768, 768, 128, 64, False, 0.1, 128, None),
+    (96, 96, 512, 64, True, 0.0, 128, None),
+    (40, 20, 2048, 64, True, 0.0, _flash_block(2048), None),
+    (32, 4, 2048, 128, True, 0.0, _flash_block(2048), None),
+    (40, 20, 2048, 64, True, 0.0, _window_block(2048), 512),
+    (32, 4, 2048, 128, True, 0.0, _window_block(2048), 1024),
 ])
-def test_flash_real_shapes_compiled(bh, bh_kv, s, d, causal, dropout, block):
+def test_flash_real_shapes_compiled(bh, bh_kv, s, d, causal, dropout, block,
+                                    window):
     """Forward and backward compile on Mosaic at the real shapes (three
     custom calls: fwd, dq, dk/dv — not the interpreter), and without
-    dropout agree with dense float32 attention to bf16 accuracy."""
+    dropout agree with dense float32 "highest" attention to bf16
+    accuracy."""
     rng = np.random.RandomState(0)
     q, k, v = (jnp.asarray(rng.randn(n, s, d) * 0.5, jnp.bfloat16)
                for n in (bh, bh_kv, bh_kv))
@@ -60,9 +58,12 @@ def test_flash_real_shapes_compiled(bh, bh_kv, s, d, causal, dropout, block):
     flash = jax.jit(jax.value_and_grad(loss(
         lambda q, k, v: flash_attention(q, k, v, causal=causal,
                                         block_q=block, block_k=block,
-                                        dropout=dropout, seed=seed)),
+                                        dropout=dropout, seed=seed,
+                                        window=window)),
         argnums=(0, 1, 2)))
-    assert flash.lower(q, k, v).as_text().count("tpu_custom_call") == 3
+    text = flash.lower(q, k, v).as_text(debug_info=True)
+    assert text.count("tpu_custom_call") == 3
+    assert ("window_attention_fwd" in text) == (window is not None)
     val, grads = flash(q, k, v)
     assert np.isfinite(float(val))
     assert all(np.isfinite(np.asarray(g, np.float32)).all() for g in grads)
@@ -70,10 +71,16 @@ def test_flash_real_shapes_compiled(bh, bh_kv, s, d, causal, dropout, block):
         val2, _ = flash(q, k, v)      # same seed: same mask, same value
         assert float(val) == float(val2)
         return
-    ref_val, ref_grads = jax.jit(jax.value_and_grad(loss(
-        lambda q, k, v: _dense(q, k, v, causal)), argnums=(0, 1, 2)))(q, k, v)
+    with jax.default_matmul_precision("highest"):
+        ref_val, ref_grads = jax.jit(jax.value_and_grad(loss(
+            lambda q, k, v: _dense_f32(q, k, v, causal, window=window)),
+            argnums=(0, 1, 2)))(q, k, v)
     np.testing.assert_allclose(float(val), float(ref_val), rtol=2e-2)
+    readings = {"value": abs(float(val) / float(ref_val) - 1)}
     for name, g, r in zip("qkv", grads, ref_grads):
         g, r = np.asarray(g, np.float32), np.asarray(r, np.float32)
         rel = np.linalg.norm(g - r) / np.linalg.norm(r)
+        readings[f"d{name}"] = float(rel)
         assert rel < 2e-2, f"d{name}: relative error {rel:.3e}"
+    print(f"\n[flash {bh}/{bh_kv} x {s} x {d} window {window}] "
+          f"relative errors {readings}")
