@@ -4,15 +4,16 @@ QKV projection, over a sliding window (``ops.window_attention``) or the whole
 prefix (``ops.flash_attention``): the same Pallas kernels either way, the
 window's on a grid that walks the band and under names of their own
 (``window_attention_fwd`` / ``_bwd_dq`` / ``_bwd_dkv``), which picks its block
-itself because it pads what the block does not divide.  An optional rotary
-step on Q and K stands in front of either."""
+itself because it pads what the block does not divide.  An optional RMSNorm
+over each head of Q and of K, then an optional rotary step on both, stand in
+front of either."""
 from __future__ import annotations
 
 import jax
 
 from ... import initializer as init_mod
 from ..block import HybridBlock
-from ..nn import Dense
+from ..nn import Dense, RMSNorm
 
 
 def _flash_block(t):
@@ -37,24 +38,39 @@ class _Attention(HybridBlock):
     one fused QKV projection laid out [Q; K; V].  ``head_dim`` defaults to
     ``hidden / heads``; ``rope`` is a table of ``ops.rotary.
     rope_frequencies`` (``(inv_freq, factor)``) or None for no positional
-    encoding.  A window layer returns its output; a full layer hands on its
-    K and V (as attention reads them: rotated) beside it."""
+    encoding.  ``qk_norm`` (an epsilon, or None for none) puts an RMSNorm
+    over ``head_dim`` on every head of Q and of K, before the rotary step:
+    ``q_norm`` and ``k_norm``, a learned gain of ``head_dim`` each, the
+    moments float32 as in ``nn.RMSNorm``.  A window layer returns its output;
+    a full layer hands on its K and V (as attention reads them: normed and
+    rotated) beside it."""
 
     def __init__(self, hidden, heads, kv_heads, window, head_dim=None,
-                 rope=None, **kwargs):
+                 rope=None, qk_norm=None, **kwargs):
         super().__init__(**kwargs)
         self._heads, self._kv_heads, self._window = heads, kv_heads, window
-        head_dim = head_dim or hidden // heads
+        self._head_dim = head_dim = head_dim or hidden // heads
         self._q, self._kv = head_dim * heads, head_dim * kv_heads
-        self._rope = rope
+        self._rope, self._qk_norm = rope, qk_norm is not None
         with self.name_scope():
             self.qkv = _dense(self._q + 2 * self._kv, hidden)
+            if self._qk_norm:
+                self.q_norm = RMSNorm(epsilon=qk_norm, in_channels=head_dim)
+                self.k_norm = RMSNorm(epsilon=qk_norm, in_channels=head_dim)
             self.out_proj = _dense(hidden, self._q)
+
+    def _per_head(self, norm, x):
+        """``norm`` over each head's ``head_dim`` of ``x`` (B, T, H * d)."""
+        return norm(x.reshape((0, 0, -1, self._head_dim))).reshape(x.shape)
 
     def forward(self, u):
         from ... import ndarray as F
         q, k, v = F.split_v2(
             self.qkv(u), axis=-1, indices=(self._q, self._q + self._kv))
+        if self._qk_norm:
+            with jax.named_scope("qk_norm"):
+                q = self._per_head(self.q_norm, q)
+                k = self._per_head(self.k_norm, k)
         if self._rope is not None:
             inv_freq, factor = self._rope
             with jax.named_scope("rope"):

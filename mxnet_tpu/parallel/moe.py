@@ -10,16 +10,18 @@ thousands of tokens and experts dozens.  It is the path of the small ``ep``
 demos (``tests/test_moe.py``, ``__graft_entry__.py``), kept for them.
 
 **The dropless path** (``route_topk``, the op ``moe_dropless_ffn``,
-``DroplessMoEFFN``): a softmax router over all ``num_experts``, top-k,
-re-normalised; the block is told which experts it HOLDS (``first_expert``,
-``held``: one chip's share under expert parallelism), sorts the token-expert
-assignments that land on its own experts to the front, runs two grouped
-matrix products (``jax.lax.ragged_dot``) over them with ``silu(g) * u``
-between (gated experts, no bias), and sums its experts' weighted results back
-per token.  No capacity, no dropped token whatever the imbalance: the row
-buffer holds all ``N * k`` assignments.  What the absent experts would have
-added is left out; on one chip the layer runs without an exchange.  This is
-the path for a model whose experts are routed per token at real widths.
+``DroplessMoEFFN``): a router over all ``num_experts`` (softmax scores, or
+sigmoid scores chosen by score + a per-expert bias that selects and does not
+weigh), top-k, re-normalised; the block is told which experts it HOLDS
+(``first_expert``, ``held``: one chip's share under expert parallelism),
+sorts the token-expert assignments that land on its own experts to the front,
+runs two grouped matrix products (``jax.lax.ragged_dot``) over them with
+``silu(g) * u`` between (gated experts, no bias), and sums its experts'
+weighted results back per token.  No capacity, no dropped token whatever the
+imbalance: the row buffer holds all ``N * k`` assignments.  What the absent
+experts would have added is left out; on one chip the layer runs without an
+exchange.  This is the path for a model whose experts are routed per token at
+real widths.
 """
 from __future__ import annotations
 
@@ -199,15 +201,36 @@ MoEFFN = _make_moe_ffn()
 _ROUTER_DTYPE = jnp.float32
 
 
-def route_topk(logits, k, renormalise=True):
-    """``softmax(logits)`` over the experts in float32, its ``k`` largest
-    per token and their experts: ``(gates (N, k) float32, experts (N, k)
-    int32)``.  ``renormalise`` divides the gates by their sum over the chosen
-    k (``norm_topk_prob``)."""
-    probs = jax.nn.softmax(logits.astype(_ROUTER_DTYPE), axis=-1)
-    gates, experts = jax.lax.top_k(probs, k)
+def route_topk(logits, k, renormalise=True, score="softmax", bias=None,
+               eps=0.0, scale=1.0):
+    """Each token's ``k`` experts and their gates: ``(gates (N, k) float32,
+    experts (N, k) int32)``.  The scores are ``softmax(logits)`` over the
+    experts or, ``score="sigmoid"``, each expert's own ``sigmoid(logit)``, in
+    float32.  The chosen are the ``k`` largest scores, or with ``bias`` (E,)
+    the ``k`` largest of ``score + bias``: the bias SELECTS and does not
+    weigh (the gates are the unbiased scores of the chosen) and no gradient
+    reaches it.  ``renormalise`` divides the gates by ``(their sum over the
+    chosen k) + eps`` (``norm_topk_prob``); ``scale`` multiplies them
+    (``routed_scaling_factor``)."""
+    logits = logits.astype(_ROUTER_DTYPE)
+    if score == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    elif score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"score {score!r} is neither 'softmax' nor "
+                         f"'sigmoid'")
+    if bias is None:
+        gates, experts = jax.lax.top_k(scores, k)
+    else:
+        bias = jax.lax.stop_gradient(bias.astype(_ROUTER_DTYPE))
+        experts = jax.lax.top_k(scores + bias, k)[1]
+        gates = jnp.take_along_axis(scores, experts, axis=-1)
     if renormalise:
-        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+        total = jnp.sum(gates, axis=-1, keepdims=True)
+        gates = gates / (total + eps if eps else total)
+    if scale != 1.0:
+        gates = gates * scale
     return gates, experts.astype(jnp.int32)
 
 
@@ -232,14 +255,16 @@ _gather_rows.defvjp(_gather_rows_fwd, _gather_rows_bwd)
 
 
 @register_op("moe_dropless_ffn")
-def _moe_dropless_ffn(tokens, router, gate_up, down, num_experts=1,
-                      first_expert=0, k=1, renormalise=True):
+def _moe_dropless_ffn(tokens, router, gate_up, down, bias=None, num_experts=1,
+                      first_expert=0, k=1, renormalise=True, score="softmax",
+                      eps=0.0, scale=1.0):
     """Dropless top-k expert layer on ``tokens`` (N, d) for the experts
     ``first_expert .. first_expert + held`` of ``num_experts``: ``router``
     (d, E), ``gate_up`` (held, d, 2F), ``down`` (held, F, d).  Returns the
     held experts' part of ``sum_{e in top-k} w_e W_down,e (silu(W_gate,e u)
     * W_up,e u)`` (N, d), ``w_e`` normalised over all k chosen whether held
-    or not, and the assignments per expert (E,) int32 of this call.
+    or not, and the assignments per expert (E,) int32 of this call.  ``bias``
+    (E,), ``score``, ``eps`` and ``scale`` are ``route_topk``'s.
 
     The N * k assignments are sorted by held expert, the unheld behind the
     held; the held rows run as two grouped products whose groups are the
@@ -251,7 +276,8 @@ def _moe_dropless_ffn(tokens, router, gate_up, down, num_experts=1,
     with jax.named_scope("router"):
         logits = jnp.dot(tokens, router.astype(tokens.dtype),
                          preferred_element_type=_ROUTER_DTYPE)
-        gates, experts = route_topk(logits, k, renormalise)
+        gates, experts = route_topk(logits, k, renormalise, score, bias,
+                                    eps, scale)
         flat = experts.reshape(n * k)
         load = jnp.sum(flat[:, None] == jnp.arange(num_experts)[None, :],
                        axis=0, dtype=jnp.int32)
@@ -297,11 +323,19 @@ def _make_dropless_moe_ffn():
         recomputed block hands the load to ``record_load`` (the decoder of
         ``gluon/model_zoo/moe_decoder.py`` does, in its own forward).
         ``held`` defaults to all ``num_experts``; the stacked experts shard
-        over ``ep`` via ``sharding_rules()``."""
+        over ``ep`` via ``sharding_rules()``.
+
+        The router is ``route_topk``'s: ``score`` (``softmax`` | ``sigmoid``),
+        ``norm_eps`` in the re-normalisation's divisor, ``scale`` on the
+        gates.  ``selection_bias=True`` adds ``expert_bias`` (E,), aux state
+        like ``load`` (no gradient, no optimizer state, float32 whatever the
+        compute type): it is added to the scores to CHOOSE the k experts and
+        never to their gates; zeros until loaded or drawn."""
 
         def __init__(self, units, hidden_size, num_experts, k, held=None,
-                     first_expert=0, renormalise=True, prefix=None,
-                     params=None):
+                     first_expert=0, renormalise=True, score="softmax",
+                     selection_bias=False, norm_eps=0.0, scale=1.0,
+                     prefix=None, params=None):
             super().__init__(prefix=prefix, params=params)
             held = num_experts if held is None else held
             if not 0 <= first_expert <= first_expert + held <= num_experts:
@@ -312,6 +346,10 @@ def _make_dropless_moe_ffn():
                 raise ValueError(f"top-{k} of {num_experts} experts")
             self._e, self._k, self._first = num_experts, k, first_expert
             self._held, self._renormalise = held, renormalise
+            if score not in ("softmax", "sigmoid"):
+                raise ValueError(f"score {score!r} is neither 'softmax' nor "
+                                 f"'sigmoid'")
+            self._router = dict(score=score, eps=norm_eps, scale=scale)
             normal = init_mod.Normal(0.02)
             self.router = self.params.get(
                 "router", shape=(units, num_experts), init=normal)
@@ -323,6 +361,10 @@ def _make_dropless_moe_ffn():
             self.load = self.params.get(
                 "load", shape=(num_experts,), dtype="int32", init="zeros",
                 differentiable=False)
+            if selection_bias:
+                self.expert_bias = self.params.get(
+                    "expert_bias", shape=(num_experts,), dtype="float32",
+                    init="zeros", differentiable=False)
 
         def sharding_rules(self):
             return ShardingRules(rules=[
@@ -332,6 +374,8 @@ def _make_dropless_moe_ffn():
         def cast(self, dtype):
             super().cast(dtype)
             self.load.cast("int32")         # a count, whatever the compute type
+            if "expert_bias" in self._reg_params:
+                self.expert_bias.cast("float32")    # the router's own type
             return self
 
         def record_load(self, load):
@@ -342,12 +386,14 @@ def _make_dropless_moe_ffn():
         def held_range(self):
             return self._first, self._first + self._held
 
-        def hybrid_forward(self, F, x, router, gate_up, down, load):
+        def hybrid_forward(self, F, x, router, gate_up, down, load,
+                           expert_bias=None):
             shape = x.shape
+            bias = () if expert_bias is None else (expert_bias,)
             out, load = F.moe_dropless_ffn(
-                x.reshape((-1, shape[-1])), router, gate_up, down,
+                x.reshape((-1, shape[-1])), router, gate_up, down, *bias,
                 num_experts=self._e, first_expert=self._first, k=self._k,
-                renormalise=self._renormalise)
+                renormalise=self._renormalise, **self._router)
             return out.reshape(shape), load
 
     return DroplessMoEFFN

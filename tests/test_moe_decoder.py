@@ -30,6 +30,7 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 from chipbench import manifest                                  # noqa: E402
+from chipbench.families import lfm2_moe as lfm2_family          # noqa: E402
 from chipbench.families import moe_decoder as family            # noqa: E402
 from chipbench.reference import moe_decoder as reference        # noqa: E402
 
@@ -55,6 +56,26 @@ REF = dict(heads=4, kv_heads=2, head_dim=16, window=48, eps=1e-6, k=3,
            rope={"window": TOY_ROPE["sliding_attention"],
                  "full": TOY_ROPE["full_attention"]})
 VOCAB, T = TOY["vocab_size"], TOY["sequence_length"]
+# the second configuration on the same decoder (``test_lfm2_moe.py`` has its
+# own cases): one leading dense layer and a period of full, conv, conv, conv;
+# 8 experts of which 4 are held (2 .. 5), top-3 of sigmoid score + bias
+LFM2_CONFIG = manifest.load_json(ROOT, "chipbench/configs/lfm2_8b_a1b.json")
+LFM2_CELL = "lfm2_8b_a1b.train_s8192"
+LFM2_TOY = dict(layers=["conv", "full", "conv", "conv", "conv"],
+                mlp_layers=["dense", "sparse", "sparse", "sparse", "sparse"],
+                vocab_size=50, sequence_length=256, hidden_size=32,
+                num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+                intermediate_size=48, moe_intermediate_size=24,
+                routed_experts=8, num_experts=4, first_expert=2,
+                num_experts_per_tok=3, norm_topk_prob=True,
+                routed_scaling_factor=1, use_expert_bias=True, conv_L_cache=3,
+                norm_eps=1e-5, rope_theta=1e6, embedding_std=0.5,
+                expert_bias_std=0.1)
+# (family, configuration, cell, toy model) of each configuration the
+# benchmark runs on this decoder
+ON_THIS_DECODER = {
+    "mellum2_12b_a2_5b": (family, CONFIG, CELL, TOY),
+    "lfm2_8b_a1b": (lfm2_family, LFM2_CONFIG, LFM2_CELL, LFM2_TOY)}
 
 
 def _batch(seed=0, n=2, t=T):
@@ -532,32 +553,33 @@ def test_grouped_product_counts():
 
 
 # -------------------------------------------------- through the benchmark --
-@pytest.fixture
-def toy_benchmark(tmp_path):
-    """A throw-away benchmark holding the new configuration and cell at
-    toy sizes, added as files and entries beside none."""
+@pytest.fixture(params=sorted(ON_THIS_DECODER))
+def toy_benchmark(tmp_path, request):
+    """A throw-away benchmark holding one configuration and its cell at toy
+    sizes, added as files and entries beside none."""
+    name = request.param
+    _, config, cell, toy = ON_THIS_DECODER[name]
     root, src = str(tmp_path), os.path.join(ROOT, "chipbench")
     real = manifest.load(ROOT)
     for d in ("configs", "workloads", "layer_metrics"):
         os.makedirs(os.path.join(root, "chipbench", d))
-    cfg = dict(CONFIG, model=dict(TOY, sequence_length=64),
+    cfg = dict(config, model=dict(toy, sequence_length=64),
                compute_dtype="float32", check={"loss_atol": 0.02},
                optimizer={"name": "adamw", "args": {"learning_rate": 1e-3}})
-    with open(os.path.join(root, "chipbench/configs/mellum2_12b_a2_5b.json"),
+    with open(os.path.join(root, f"chipbench/configs/{name}.json"),
               "w") as f:
         json.dump(cfg, f)
-    wl = manifest.load_json(src, f"workloads/{CELL}.json")
+    wl = manifest.load_json(src, f"workloads/{cell}.json")
     wl.update(trace_s=1.0, batch_per_chip=1)
-    with open(os.path.join(root, manifest.workload_file(CELL)), "w") as f:
+    with open(os.path.join(root, manifest.workload_file(cell)), "w") as f:
         json.dump(wl, f)
     m = dict(real, run_seconds=2)
-    m["configs"] = [c for c in real["configs"]
-                    if c["name"] == "mellum2_12b_a2_5b"]
-    m["workloads"] = [w for w in real["workloads"] if w["name"] == CELL]
+    m["configs"] = [c for c in real["configs"] if c["name"] == name]
+    m["workloads"] = [w for w in real["workloads"] if w["name"] == cell]
     for section in ("end_to_end", "per_layer"):
-        m[section] = [dict(r, workloads=[CELL]) if "workloads" in r else r
+        m[section] = [dict(r, workloads=[cell]) if "workloads" in r else r
                       for r in real[section]
-                      if CELL in r.get("workloads", [CELL])]
+                      if cell in r.get("workloads", [cell])]
     for r in m["per_layer"]:
         with open(os.path.join(src, "layer_metrics", r["name"] + ".json")) as f:
             spec = f.read()
@@ -567,7 +589,7 @@ def toy_benchmark(tmp_path):
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(m, f)
     assert manifest.validate(m, root) == []
-    return manifest.cell(m, root, CELL)
+    return manifest.cell(m, root, cell)
 
 
 @pytest.mark.parametrize("trace", [0, 1])
@@ -585,25 +607,31 @@ def test_family_rehearsed_through_the_benchmark(toy_benchmark, capsys, trace):
     assert set(res["metrics"]) == want
 
 
-def test_the_real_benchmark_holds_the_cell():
+@pytest.mark.parametrize("name", sorted(ON_THIS_DECODER))
+def test_the_real_benchmark_holds_the_cell(name):
+    family_, config, cell, _ = ON_THIS_DECODER[name]
     real = manifest.load(ROOT)
     assert manifest.validate(real, ROOT) == []
-    assert [w["name"] for w in real["workloads"]][-1] == CELL
-    view = manifest.cell(real, ROOT, CELL)
-    assert view["chips"] == 1 and view["cfg"]["family"] == "moe_decoder"
-    assert view["cfg"]["kind"] == "train"
+    cells = [w["name"] for w in real["workloads"]]
+    assert cells.index(CELL) == 2 and cells.index(LFM2_CELL) == 3
+    view = manifest.cell(real, ROOT, cell)
+    assert view["chips"] == 1 and view["cfg"]["kind"] == "train"
+    assert view["cfg"]["family"] == family_.__name__.rsplit(".", 1)[1]
     assert view["wl"]["driver"] == "train_loop"
     assert view["wl"]["batch_per_chip"] in (1, 2, 4)
     assert [m["name"] for m in view["end_to_end"]] == [
         "train_samples_per_s", "setup_s"]
     assert {m["name"] for m in view["per_layer"]} == {
         "compile_ms_total", "step_ms_p50", "mfu_pct", "device_idle_pct.train"}
-    entry = real["workloads"][-1]
+    entry = real["workloads"][cells.index(cell)]
     assert len(entry["why"]) <= 200 and "four times" in view["wl"]["why"]
+    assert "4x" in entry["why"]
 
 
-def test_family_draws_its_batch_from_the_seed():
-    _, _, batch = family.build(dict(TOY, sequence_length=64))
+@pytest.mark.parametrize("name", sorted(ON_THIS_DECODER))
+def test_family_draws_its_batch_from_the_seed(name):
+    family_, _, _, toy = ON_THIS_DECODER[name]
+    _, _, batch = family_.build(dict(toy, sequence_length=64))
     (a,), (la,) = batch(np.random.default_rng(5), 3)
     (b,), _ = batch(np.random.default_rng(5), 3)
     (c,), _ = batch(np.random.default_rng(6), 3)
@@ -611,7 +639,7 @@ def test_family_draws_its_batch_from_the_seed():
     assert 0 <= a.min() and a.max() < VOCAB
     assert np.array_equal(a, b) and not np.array_equal(a, c)
     assert np.array_equal(la[:, :-1], a[:, 1:])
-    made, = family.check_labels(NDArray(jnp.eye(4)[None]))
+    made, = family_.check_labels(NDArray(jnp.eye(4)[None]))
     assert made.tolist() == [[0, 1, 2, 3]]
 
 

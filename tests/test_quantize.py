@@ -208,9 +208,13 @@ def test_all_reduce_activations_modes_and_bound():
 # ---------------------------------------------------- TrainStep grad_reduce --
 def _mlp_step(mode, seed=3, skip_nonfinite=False):
     mx.random.seed(seed)
-    net = nn.HybridSequential()
-    net.add(nn.Dense(32, activation="relu", in_units=20),
-            nn.Dense(5, in_units=32))
+    # names of its own: TrainStep orders the leaves by name, and the
+    # process-wide block counters would order "dense9_" after "dense10_" in
+    # whichever net crosses a digit (two nets built alike must reduce alike)
+    net = nn.HybridSequential(prefix="mlp_")
+    with net.name_scope():
+        net.add(nn.Dense(32, activation="relu", in_units=20),
+                nn.Dense(5, in_units=32))
     net.initialize()
     opt = mx.optimizer.create("sgd", learning_rate=0.1, momentum=0.9)
     return parallel.TrainStep(net, gluon.loss.SoftmaxCrossEntropyLoss(),

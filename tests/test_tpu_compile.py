@@ -135,30 +135,44 @@ def test_window_kernels_compile_for_v5e(one_chip, no_compile_cache, heads,
     assert "flash_attention_" not in text
 
 
+# (d, F, held, routed, k, the router's own arguments) of the two cells that
+# run the dropless expert layer
+EXPERT_LAYERS = {
+    "mellum2_12b_a2_5b": (2304, 896, 16, 64, 8, {}),
+    "lfm2_8b_a1b": (2048, 1792, 8, 32, 4,
+                    {"score": "sigmoid", "eps": 1e-6, "bias": True})}
+
+
+@pytest.mark.parametrize("config", sorted(EXPERT_LAYERS))
 @pytest.mark.parametrize("router", [jnp.float32, jnp.bfloat16],
                          ids=["float32", "bf16_control"])
 def test_dropless_expert_layer_compiles_for_v5e(one_chip, no_compile_cache,
-                                                monkeypatch, router):
-    """Routing, sort, the two grouped products and their gradients at the
-    cell's shape: ``ragged_dot`` has to have a lowering for the TPU forward
-    and for both gradients.  The bf16 router is the precision control of
-    ``tests_tpu/test_moe_decoder_tpu.py``."""
+                                                monkeypatch, router, config):
+    """Routing (softmax, or sigmoid scores chosen by score + bias), sort, the
+    two grouped products and their gradients at a cell's widths, one
+    sequence: ``ragged_dot`` has to have a lowering for the TPU forward and
+    for both gradients.  The bf16 router is the precision control of
+    ``tests_tpu/test_moe_decoder_tpu.py`` and ``test_lfm2_moe_tpu.py``."""
     from mxnet_tpu.ops.registry import get_op
     from mxnet_tpu.parallel import moe
     monkeypatch.setattr(moe, "_ROUTER_DTYPE", router)
-    n, d, f, held, routed, k = 8192, 2304, 896, 16, 64, 8
+    n = 8192
+    d, f, held, routed, k, how = EXPERT_LAYERS[config]
+    how = dict(how)
 
-    def shape(*dims):
-        return jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=one_chip)
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    bias = (shape(routed, dtype=jnp.float32),) if how.pop("bias", False) \
+        else ()
 
-    def total(tokens, router, gate_up, down):
+    def total(tokens, router, gate_up, down, *bias):
         out, _ = get_op("moe_dropless_ffn")(
-            tokens, router, gate_up, down, num_experts=routed,
-            first_expert=0, k=k)
+            tokens, router, gate_up, down, *bias, num_experts=routed,
+            first_expert=0, k=k, **how)
         return out.astype(jnp.float32).sum()
     compiled = jax.jit(jax.grad(total, range(4))).trace(
         shape(n, d), shape(d, routed), shape(held, d, 2 * f),
-        shape(held, f, d)).lower(lowering_platforms=("tpu",)).compile()
+        shape(held, f, d), *bias).lower(lowering_platforms=("tpu",)).compile()
     # the N x k rows through both products, forward and backward, and no
     # (N, E, C) dispatch tensor: under 3 GB of temporaries
     assert compiled.memory_analysis().temp_size_in_bytes < 3e9
