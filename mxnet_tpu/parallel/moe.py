@@ -28,6 +28,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ..ops.pallas.gated_rows import gated_rows
 from ..ops.registry import register_op
 
 __all__ = ["moe_dispatch", "MoEFFN", "route_topk", "DroplessMoEFFN",
@@ -234,24 +235,98 @@ def route_topk(logits, k, renormalise=True, score="softmax", bias=None,
     return gates, experts.astype(jnp.int32)
 
 
+def _row_chunk(m):
+    """Rows a trip of the bounded gather visits (``_combine_rows``'s backward
+    pass), from the buffer's rows alone: 4,096, or a smaller buffer whole.
+    On the chip, 131,072 rows of 2,048 with 34,000 / 43,000 / all of them
+    held: 2.78 / 3.23 / 8.00 ms a call in chunks of 2,048, 2.87 / 3.32 /
+    7.92 at 4,096, 3.08 / 3.51 / 7.88 at 8,192, 3.99 / 3.99 / 9.12 at 16,384
+    (a chunk that no longer stays in VMEM), against 12.19 for the whole
+    pass; with all three sorted-row passes as such loops the op's forward
+    and backward took 50.9 / 49.4 / 49.6 / 53.1 ms at a held share of 0.25
+    and 80.2 / 76.7 / 75.4 / 80.4 at 0.55 (PERF.md 6, PR 34)."""
+    return min(m, 4096)
+
+
 @jax.custom_vjp
-def _gather_rows(x, source, back):
-    """``x[source]``, for ``source`` (M,) that reads every one of x's N rows
-    exactly M / N times and ``back`` (N, M / N) listing, row of x by row, the
-    places that read it.  The backward pass is then a gather too (``dy[back]``
-    summed per row), where autodiff would scatter-add M rows."""
-    return x[source]
+def _dispatch_rows(tokens, order, back, total):
+    """Each assignment's token, in sorted order: ``tokens[order // k]``, all
+    N * k rows (a row past ``total`` holds the token of an assignment that no
+    held expert reads).  ``back`` (N, k) lists, token by token, the sorted
+    rows that read it: the backward pass is then a gather too (``dy[back]``
+    summed per token) where autodiff would scatter-add N * k rows; ``dy``'s
+    rows past ``total`` may hold anything, and the sum drops them."""
+    return tokens[order // back.shape[1]]
 
 
-def _gather_rows_fwd(x, source, back):
-    return x[source], back
+def _dispatch_rows_fwd(tokens, order, back, total):
+    return _dispatch_rows(tokens, order, back, total), (back, total)
 
 
-def _gather_rows_bwd(back, dy):
-    return jnp.sum(dy[back], axis=1).astype(dy.dtype), None, None
+def _dispatch_rows_bwd(kept, dy):
+    back, total = kept
+    held = jnp.where((back < total)[..., None], dy[back], 0)
+    return jnp.sum(held, axis=1).astype(dy.dtype), None, None, None
 
 
-_gather_rows.defvjp(_gather_rows_fwd, _gather_rows_bwd)
+_dispatch_rows.defvjp(_dispatch_rows_fwd, _dispatch_rows_bwd)
+
+
+def _weigh(out, back, total, gates):
+    """(each token's rows of ``out`` below ``total`` weighed and summed,
+    those rows (N, k, d) with the others zeroed)."""
+    held = jnp.where((back < total)[..., None], out[back], 0)
+    weighed = jnp.sum(held.astype(jnp.float32) * gates[..., None], axis=1)
+    return weighed.astype(out.dtype), held
+
+
+@jax.custom_vjp
+def _combine_rows(out, gates, order, back, total):
+    """Each token's ``k`` sorted rows of ``out`` (N * k, d), those below
+    ``total`` alone (the others may hold anything: a mask drops them, not a
+    product with zero), weighed by ``gates`` (N, k) float32 and summed in
+    float32.  The backward pass writes the sorted rows below ``total``, each
+    its token's ``dy`` times its gate, as a loop over the ``ceil(total /
+    chunk)`` chunks that hold one (``total`` is a device value and the trip
+    count, so the work follows it while every shape stays static): zeros to
+    the end of the last such chunk and nothing past it, where the rows hold
+    anything, as the product's do."""
+    return _weigh(out, back, total, gates)[0]
+
+
+def _combine_rows_fwd(out, gates, order, back, total):
+    weighed, held = _weigh(out, back, total, gates)
+    return weighed, (held, gates, order, total)
+
+
+def _combine_rows_bwd(kept, dy):
+    held, gates, order, total = kept
+    m, k = order.shape[0], gates.shape[1]
+    chunk = _row_chunk(m)
+    d_gates = jnp.sum(held.astype(jnp.float32)
+                      * dy.astype(jnp.float32)[:, None, :],
+                      axis=-1).astype(gates.dtype)
+
+    def trip(i, d_out):
+        start = jnp.minimum(i * chunk, m - chunk)
+        source = jax.lax.dynamic_slice_in_dim(order, start, chunk)
+        mine = start + jnp.arange(chunk, dtype=jnp.int32) < total
+        gate = jnp.where(mine, gates.reshape(-1)[source], 0)
+        rows = dy[source // k].astype(jnp.float32) * gate[:, None]
+        return jax.lax.dynamic_update_slice(
+            d_out, rows.astype(d_out.dtype), (start, 0))
+    # the loop writes into the kept rows' own buffer, which nothing reads
+    # once ``d_gates`` is made (the barrier says so to the scheduler: with
+    # it lfm2_8b_a1b.train_s8192 peaks 0.42 GB lower): a buffer of zeros of
+    # its own cost that step 3.9 GB of temporaries (9.28 against 5.41;
+    # PERF.md 6, PR 34), and the rows past ``total`` need not be zero
+    held = jax.lax.optimization_barrier((held, d_gates))[0]
+    d_out = jax.lax.fori_loop(0, (total + chunk - 1) // chunk, trip,
+                              held.reshape(m, -1))
+    return d_out, d_gates, None, None, None
+
+
+_combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
 
 
 @register_op("moe_dropless_ffn")
@@ -268,10 +343,21 @@ def _moe_dropless_ffn(tokens, router, gate_up, down, bias=None, num_experts=1,
 
     The N * k assignments are sorted by held expert, the unheld behind the
     held; the held rows run as two grouped products whose groups are the
-    experts' loads.  Rows past the groups' sum belong to no held expert:
-    they are zeroed on the way in and on the way out (a grouped product
-    need not visit them), in the backward pass too."""
-    n, d = tokens.shape
+    experts' loads.  Rows past the groups' sum, ``total``, belong to no held
+    expert and a grouped product does not visit them.  The passes that
+    WRITE sorted rows between and after the products stop there too:
+    ``silu(g) * u`` and its backward pass are the kernels of
+    ``ops/pallas/gated_rows.py``, whose grid steps past ``total`` fetch and
+    write nothing, the gather of the output's gradient is a loop of
+    ``ceil(total / chunk)`` trips.  (The gather in visits every row: as a
+    loop it made XLA schedule the step of ``mellum2_12b_a2_5b.train_s8192``
+    with 2 GB more of temporaries, which does not load.)  What lies past
+    ``total`` in a buffer of sorted rows may be anything: other tokens,
+    what the buffer held before, XLA:TPU's grouped product leaves garbage.
+    The token-side sums that READ sorted rows (the weighted sum out, the
+    tokens' gradient) mask those rows before they sum: no row past ``total``
+    is ever read unmasked, in the backward pass too."""
+    n = tokens.shape[0]
     held = gate_up.shape[0]
     with jax.named_scope("router"):
         logits = jnp.dot(tokens, router.astype(tokens.dtype),
@@ -286,20 +372,17 @@ def _moe_dropless_ffn(tokens, router, gate_up, down, bias=None, num_experts=1,
         key = jnp.where((local >= 0) & (local < held), local, held)
         order = jnp.argsort(key, stable=True).astype(jnp.int32)
         at = jnp.arange(n * k, dtype=jnp.int32)
-        inverse = jnp.zeros_like(order).at[order].set(at, unique_indices=True)
+        back = jnp.zeros_like(order).at[order].set(
+            at, unique_indices=True).reshape(n, k)
         sizes = load[first_expert:first_expert + held]
-        mine = (at < jnp.sum(sizes))[:, None]
-        rows = jnp.where(mine, _gather_rows(tokens, order // k,
-                                            inverse.reshape(n, k)), 0)
+        total = jnp.sum(sizes)
+        rows = _dispatch_rows(tokens, order, back, total)
     with jax.named_scope("experts"):
-        gate, up = jnp.split(jax.lax.ragged_dot(rows, gate_up, sizes), 2,
-                             axis=-1)
-        out = jax.lax.ragged_dot(jax.nn.silu(gate) * up, down, sizes)
-        out = jnp.where(mine, out, 0)
+        hidden = gated_rows(jax.lax.ragged_dot(rows, gate_up, sizes), total)
+        out = jax.lax.ragged_dot(hidden, down, sizes)
     with jax.named_scope("combine"):
-        out = _gather_rows(out, inverse, order[:, None]).reshape(n, k, d)
-        out = jnp.sum(out.astype(jnp.float32) * gates[..., None], axis=1)
-    return out.astype(tokens.dtype), load
+        out = _combine_rows(out, gates, order, back, total)
+    return out, load
 
 
 def _make_dropless_moe_ffn():
@@ -406,10 +489,13 @@ def publish_load(net):
     """Read the ``load`` of every ``DroplessMoEFFN`` under ``net`` (after a
     step: ``TrainStep.sync_params_to_net()`` first) and publish, over all of
     them, the gauges ``moe.load_max_over_mean`` (the fullest expert's
-    assignments over the mean: 1 is even routing) and ``moe.held_share`` (the
+    assignments over the mean: 1 is even routing), ``moe.held_share`` (the
     share of assignments that landed on held experts: ``held / num_experts``
-    under even routing).  Returns ``{gauge: value}``; both are 0 before any
-    step."""
+    under even routing) and ``moe.row_pass_share`` (the share of the N * k
+    sorted rows that the op's bounded gather visited: the held rows rounded
+    up to whole chunks of ``_row_chunk``, layer by layer; 1 is a pass over
+    every row).
+    Returns ``{gauge: value}``; all are 0 before any step."""
     import numpy as np
     from .. import telemetry
     blocks = []
@@ -417,13 +503,18 @@ def publish_load(net):
               if isinstance(b, DroplessMoEFFN) else None)
     loads = [np.asarray(b.load.data()._data, np.float64) for b in blocks]
     total = sum(l.sum() for l in loads)
-    values = {"moe.load_max_over_mean": 0.0, "moe.held_share": 0.0}
+    values = {"moe.load_max_over_mean": 0.0, "moe.held_share": 0.0,
+              "moe.row_pass_share": 0.0}
     if total:
+        held = [l[slice(*b.held_range())].sum()
+                for b, l in zip(blocks, loads)]
         values["moe.load_max_over_mean"] = float(
             max(l.max() / l.mean() for l in loads if l.sum()))
-        values["moe.held_share"] = float(sum(
-            l[slice(*b.held_range())].sum()
-            for b, l in zip(blocks, loads)) / total)
+        values["moe.held_share"] = float(sum(held) / total)
+        chunks = [_row_chunk(int(l.sum())) for l in loads]
+        values["moe.row_pass_share"] = float(sum(
+            min(-(-h // c) * c, l.sum())
+            for h, l, c in zip(held, loads, chunks) if c) / total)
     for name, v in values.items():
         telemetry.registry().gauge(name).set(v)
     return values

@@ -20,8 +20,11 @@ from mxnet_tpu.gluon.model_zoo._attention import _Attention
 from mxnet_tpu.ops.registry import get_op
 from mxnet_tpu.ops.rotary import rope_frequencies
 
+from mxnet_tpu.parallel import moe
+
 from test_moe_decoder import (LFM2_CONFIG as CONFIG, LFM2_TOY as TOY, ROOT,
-                              _batch, _loss_and_grads, _one_device, _worst)
+                              _batch, _loss_and_grads, _one_device, _worst,
+                              poisoned_ragged_dot)
 
 from chipbench import manifest                                  # noqa: E402
 from chipbench.families import lfm2_moe as family               # noqa: E402
@@ -115,6 +118,32 @@ def test_recomputed_layers_give_equal_gradients(cut):
     again, marked = _loss_and_grads(_net(recompute=True), ids, labels)
     assert again == loss
     assert _worst(marked, grads) < 1e-6
+
+
+@pytest.mark.parametrize("rows", [None, 100], ids=["one_chunk",
+                                                   "chunks_of_100"])
+def test_a_recomputed_layer_with_garbage_past_the_groups_sum(rows,
+                                                             monkeypatch):
+    """A recomputed conv + sparse layer (sigmoid scores chosen by score +
+    bias, 1,536 sorted rows) with a grouped product that leaves NaN in every
+    row past its groups' sum, as XLA:TPU's may: the loss and every gradient
+    are finite and bit for bit the clean product's."""
+    if rows:
+        monkeypatch.setattr(moe, "_row_chunk", lambda m: min(m, rows))
+    model = dict(TOY, layers=["conv"], mlp_layers=["sparse"])
+    ids, labels = _batch(2)
+    loss, grads = _loss_and_grads(_net(model, seed=7, recompute=True), ids,
+                                  labels)
+    monkeypatch.setattr(jax.lax, "ragged_dot", poisoned_ragged_dot)
+    again, poisoned = _loss_and_grads(_net(model, seed=7, recompute=True),
+                                      ids, labels)
+    assert np.isfinite(again) and again == loss
+    assert set(poisoned) == set(grads)
+    for name, grad in grads.items():
+        assert np.isfinite(np.asarray(poisoned[name])).all(), name
+        assert np.array_equal(poisoned[name], grad), name
+    experts = [g for name, g in grads.items() if name.endswith("gate_up")]
+    assert len(experts) == 1 and float(jnp.abs(experts[0]).max()) > 0
 
 
 def test_unknown_kinds_lists_and_tables_raise():
@@ -593,7 +622,8 @@ def test_the_load_reads_back_after_a_step_with_a_dense_first_layer(stepped):
     net, step, ids, labels = stepped
     assert [layer.sparse for layer in net.layers] == [False] + [True] * 4
     before = parallel.publish_load(net)
-    assert before == {"moe.load_max_over_mean": 0.0, "moe.held_share": 0.0}
+    assert before == {"moe.load_max_over_mean": 0.0, "moe.held_share": 0.0,
+                      "moe.row_pass_share": 0.0}
     biases = [np.asarray(l.moe.expert_bias.data()._data)
               for l in net.layers[1:]]
     loss = float(step(ids, labels).asnumpy())
@@ -611,6 +641,7 @@ def test_the_load_reads_back_after_a_step_with_a_dense_first_layer(stepped):
     assert 0.2 < got["moe.held_share"] < 0.8
     gauges = telemetry.registry().snapshot()["gauges"]
     assert gauges["moe.held_share"] == got["moe.held_share"]
+    assert got["moe.row_pass_share"] == gauges["moe.row_pass_share"] == 1.0
     # the step trains every leaf but the counts and the biases, which it
     # leaves as they were drawn, in float32 beside bf16 weights
     trained = [n for n, p in zip(step._names, step._plist)

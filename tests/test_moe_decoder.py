@@ -22,6 +22,7 @@ from mxnet_tpu.gluon.model_zoo import moe_decoder
 from mxnet_tpu.ndarray import NDArray
 from mxnet_tpu.ops.registry import get_op
 from mxnet_tpu.ops.rotary import rope_frequencies
+from mxnet_tpu.parallel import moe
 from mxnet_tpu.parallel.functional import (FunctionalState, functional_call,
                                            param_names_and_values)
 
@@ -252,16 +253,30 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
     assert int(loads[0].sum()) == 96 * K and loads[0].dtype == jnp.int32
 
 
+@pytest.fixture(params=[None, 100, 8], ids=["one_chunk", "chunks_of_100",
+                                            "chunks_of_8"])
+def chunk(request, monkeypatch):
+    """The rows a trip of the op's bounded passes visits: the rule's own
+    (every buffer here is then one chunk), or a chunk that divides neither
+    a buffer nor its held rows."""
+    if request.param:
+        monkeypatch.setattr(moe, "_row_chunk",
+                            lambda m: min(m, request.param))
+    return request.param
+
+
 @pytest.mark.parametrize("favoured,held_rows", [
     (tuple(range(3, 11)), 96 * 8), (tuple(range(40, 48)), 0),
     (tuple(range(12, 20)), 96 * 4)],
     ids=["all_held", "none_held", "half_held"])
-def test_dropless_under_forced_imbalance(favoured, held_rows):
+def test_dropless_under_forced_imbalance(favoured, held_rows, chunk):
     """A router forced to send every token to the same eight experts: all
     held here (every one of the N x k rows is a held row: no buffer sized
-    for an even split would hold them), none held (the layer returns zero),
-    half held.  Values and every gradient match the reference; no token is
-    dropped."""
+    for an even split would hold them, and the bounded passes visit every
+    chunk), none held (no trip at all: the layer returns zero and its
+    experts get zero gradients), half held (384 rows: in chunks of 100 the
+    last trip is part held).  Values and every gradient match the reference;
+    no token is dropped."""
     router, gate_up, down = _expert_weights(2)
     router = router * 0.01 + 20.0 * jnp.zeros((D, E)).at[
         0, jnp.asarray(favoured)].set(jnp.linspace(1.0, 1.7, 8))
@@ -288,21 +303,127 @@ def test_dropless_under_forced_imbalance(favoured, held_rows):
         assert np.isfinite(np.asarray(g)).all()
         np.testing.assert_allclose(g, w, atol=2e-5 * float(
             jnp.abs(w).max() + 1e-30) + 1e-7)
+    if not held_rows:
+        assert all(float(jnp.abs(g).max()) == 0.0 for g in got)
 
 
-def test_one_expert_takes_every_token():
-    """top-1 with one held expert that every token chooses, and then one
-    that none chooses."""
+@pytest.mark.parametrize("first,rows", [(5, 33), (6, 0)],
+                         ids=["every_token", "no_token"])
+def test_one_expert_takes_every_token(first, rows, chunk):
+    """top-1 with one held expert that every token chooses (33 rows, all
+    held: in chunks of 8 the last trip starts at row 25), and then one that
+    none chooses."""
     router, gate_up, down = _expert_weights(4)
     router = router.at[:, 5].add(100.0 * jnp.sign(router[:, 5]))
     tokens = jnp.abs(jnp.asarray(
         np.random.RandomState(5).randn(33, D), jnp.float32)) \
         * jnp.sign(router[:, 5])
-    for first, rows in ((5, 33), (6, 0)):
-        out, load = _share(tokens, router, gate_up, down, first, held=1, k=1)
-        assert int(load[5]) == 33 and int(load[first]) == rows
-        np.testing.assert_allclose(out, _reference_share(
-            tokens, router, gate_up, down, first, held=1, k=1), atol=2e-5)
+    out, load = _share(tokens, router, gate_up, down, first, held=1, k=1)
+    assert int(load[5]) == 33 and int(load[first]) == rows
+    np.testing.assert_allclose(out, _reference_share(
+        tokens, router, gate_up, down, first, held=1, k=1), atol=2e-5)
+
+
+@pytest.mark.parametrize("rows,total,block", [
+    (72, 30, 16), (72, 0, 16), (72, 72, 16), (300, 257, 256), (50, 49, 256)],
+    ids=["part_of_a_block", "no_row", "every_row", "a_block_that_does_not"
+         "_divide", "one_block"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bf16"])
+def test_gated_rows_stop_at_the_held_rows(rows, total, block, dtype,
+                                          monkeypatch):
+    """``silu(gate) * up`` by the two kernels of ``ops/pallas/gated_rows``:
+    below ``total`` the value and the cotangent are jnp's, from ``total`` to
+    the end of its block they are zero, and NaN in the input or in ``dy``
+    past ``total`` reaches neither.  (Past that block nothing is written:
+    the interpreter leaves NaN there, the chip whatever the buffer held.)"""
+    from mxnet_tpu.ops.pallas import gated_rows
+    monkeypatch.setattr(gated_rows, "_BLOCK", block)
+    rng = np.random.RandomState(0)
+    x = jnp.asarray(rng.randn(rows, 2 * F), dtype)
+    dy = jnp.asarray(rng.randn(rows, F), dtype)
+
+    def plain(x):
+        gate, up = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+        return (jax.nn.silu(gate) * up).astype(dtype)
+    want, vjp = jax.vjp(plain, x)
+    want_dx, = vjp(dy)
+    out, vjp = jax.vjp(
+        lambda x: gated_rows.gated_rows(x, jnp.int32(total)),
+        x.at[total:].set(jnp.nan))
+    dx, = vjp(dy.at[total:].set(jnp.nan))
+    assert out.dtype == dx.dtype == dtype
+    assert out.shape == (rows, F) and dx.shape == (rows, 2 * F)
+    tol = 1e-6 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(out[:total].astype(np.float32),
+                               want[:total].astype(np.float32), atol=tol)
+    np.testing.assert_allclose(dx[:total].astype(np.float32),
+                               want_dx[:total].astype(np.float32), atol=tol)
+    end = -(-total // min(block, rows)) * min(block, rows)
+    assert not np.asarray(out[total:end], np.float32).any()
+    assert not np.asarray(dx[total:end], np.float32).any()
+
+
+# what XLA:TPU's grouped product does and XLA:CPU's does not: the rows past
+# the groups' sum come back holding anything, forward and in the gradient of
+# the rows
+def _poison(rows, group_sizes):
+    past = jnp.arange(rows.shape[0]) >= jnp.sum(group_sizes)
+    return jnp.where(past[:, None], jnp.nan, rows)
+
+
+@jax.custom_vjp
+def poisoned_ragged_dot(lhs, rhs, group_sizes):
+    return _poison(_RAGGED_DOT(lhs, rhs, group_sizes), group_sizes)
+
+
+def _poisoned_fwd(lhs, rhs, group_sizes):
+    out, vjp = jax.vjp(lambda l, r: _RAGGED_DOT(l, r, group_sizes), lhs, rhs)
+    return _poison(out, group_sizes), (vjp, group_sizes)
+
+
+def _poisoned_bwd(kept, dy):
+    vjp, group_sizes = kept
+    d_lhs, d_rhs = vjp(dy)
+    return _poison(d_lhs, group_sizes), d_rhs, None
+
+
+_RAGGED_DOT = jax.lax.ragged_dot
+poisoned_ragged_dot.defvjp(_poisoned_fwd, _poisoned_bwd)
+
+
+@pytest.mark.parametrize("recomputed", [False, True],
+                         ids=["kept", "recomputed"])
+def test_rows_past_the_groups_sum_may_hold_anything(recomputed, chunk,
+                                                    monkeypatch):
+    """The op, its load and all four gradients with a grouped product that
+    leaves NaN in every row past its groups' sum, forward and backward:
+    finite, and bit for bit what the clean product gives.  No pass reads
+    such a row unmasked."""
+    router, gate_up, down = _expert_weights(6)
+    tokens = jnp.asarray(np.random.RandomState(7).randn(96, D), jnp.float32)
+
+    def run():
+        def total(*a):
+            out, load = _share(*a, 16)
+            return (out ** 2).sum(), (out, load)
+        if recomputed:
+            total = jax.checkpoint(total)
+        grads, (out, load) = jax.grad(total, range(4), has_aux=True)(
+            tokens, router, gate_up, down)
+        return (out, load) + grads
+    clean = run()
+    assert 0 < int(clean[1][16:32].sum()) < 96 * K
+    monkeypatch.setattr(jax.lax, "ragged_dot", poisoned_ragged_dot)
+    poisoned = run()
+    for got, want in zip(poisoned, clean):
+        assert np.isfinite(np.asarray(got)).all()
+        assert np.array_equal(got, want)
+    assert float(jnp.abs(clean[0]).max()) > 0.1
+    # the poison is there: the product's own rows past the sum are NaN
+    sizes = clean[1][16:32]
+    raw = jax.lax.ragged_dot(jnp.ones((96 * K, D)), gate_up[16:32], sizes)
+    assert bool(jnp.isnan(raw[int(sizes.sum()):]).all())
 
 
 def test_route_topk_renormalises_over_the_chosen():
@@ -684,10 +805,50 @@ def test_train_step_program_names_every_new_scope(stepped):
     assert "ragged_dot" in text
 
 
+def test_train_step_program_bounds_the_row_passes(monkeypatch):
+    """The step's program for the toy decoder (384 sorted rows a layer, in
+    chunks of 128): the gather of every expert layer's output gradient is a
+    loop whose trip count is a value of the program, the stage between the
+    products is the two kernels that take that count, and no select runs
+    over a whole ``[N x k, d]`` buffer."""
+    monkeypatch.setattr(moe, "_row_chunk", lambda m: min(m, 128))
+    net, loss_fn, batch = family.build(dict(TOY, sequence_length=64))
+    net.initialize()
+    net.cast("bfloat16")
+    step = parallel.TrainStep(
+        net, loss_fn, mx.optimizer.create("adamw", learning_rate=1e-3),
+        mesh=_one_device())
+    (ids,), (labels,) = batch(np.random.default_rng(0), 2)
+    text = step.lower(ids, labels).as_text(debug_info=True)
+    paths = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    # a while whose condition compares two carried values, not one against
+    # a constant: the trip count is read on the device
+    dynamic = [paths[loc] for loc in re.findall(
+        r'stablehlo\.while\([^\n]*\n\s*cond \{\n\s*%\d+ = stablehlo\.compare'
+        r'\s+LT, %iterArg\w*, %iterArg\w*,[^\n]*loc\((#loc\d+)\)', text)]
+    rows, d = ids.size * TOY["num_experts_per_tok"], TOY["hidden_size"]
+    assert rows == 384 and f"tensor<{rows}x{d}xbf16>" in text
+    for i in range(len(CUT)):
+        found = [p for p in dynamic if "transpose(jvp(forward))" in p
+                 and f"/layer{i}/moe/combine/while/cond" in p]
+        assert len(found) == 1, (i, found)
+    assert len(dynamic) == len(CUT)
+    # every layer calls the same lowerings of the kernels: the forward one
+    # as traced in the forward pass and as traced again for the recomputed
+    # one, and the backward one
+    for kernel in ("moe_gated_fwd", "moe_gated_bwd"):
+        assert any(kernel in p for p in paths.values()), kernel
+    assert len(re.findall(r"func\.func private @_call\w*\(", text)) == 3
+    assert len(re.findall(r" call @_call\w*\(", text)) == 3 * len(CUT)
+    assert not re.search(rf"stablehlo\.select [^\n]*tensor<{rows}x{d}xbf16>",
+                         text)
+
+
 def test_the_load_reads_back_after_a_step(stepped):
     net, step, ids, labels = stepped
     before = parallel.publish_load(net)
-    assert before == {"moe.load_max_over_mean": 0.0, "moe.held_share": 0.0}
+    assert before == {"moe.load_max_over_mean": 0.0, "moe.held_share": 0.0,
+                      "moe.row_pass_share": 0.0}
     loss = float(step(ids, labels).asnumpy())
     assert np.isfinite(loss)
     step.sync_params_to_net()
@@ -705,8 +866,36 @@ def test_the_load_reads_back_after_a_step(stepped):
     gauges = telemetry.registry().snapshot()["gauges"]
     assert gauges["moe.held_share"] == got["moe.held_share"]
     assert gauges["moe.load_max_over_mean"] == got["moe.load_max_over_mean"]
+    # 384 sorted rows a layer are one chunk of the bounded passes: every
+    # layer holds a row, so every row was visited
+    assert got["moe.row_pass_share"] == gauges["moe.row_pass_share"] == 1.0
     # every trained leaf moved, the router among them
     names = [n for n, p in zip(step._names, step._plist)
              if p.grad_req != "null"]
     assert any(n.endswith("router") for n in names)
     assert sum("load" in n for n in step._names) == 4
+
+
+@pytest.mark.parametrize("rows,loads,want", [
+    (32, [[40, 24, 0, 64], [0, 0, 0, 128]], (64 + 0) / 256),
+    (32, [[33, 0, 1, 94], [128, 0, 0, 0]], (64 + 128) / 256),
+    (48, [[33, 0, 1, 94], [50, 50, 0, 28]], (48 + 128) / 256),
+    (128, [[1, 0, 0, 127], [0, 0, 0, 128]], (128 + 0) / 256)],
+    ids=["whole_chunks", "one_row_over", "a_chunk_that_does_not_divide",
+         "the_rule_at_this_size"])
+def test_row_pass_share_counts_whole_chunks(rows, loads, want, monkeypatch):
+    """``moe.row_pass_share`` from the loads alone: each layer's held rows
+    (experts 0 and 1 of 4) rounded up to whole chunks, never past the
+    buffer; a layer with no held row is not visited at all."""
+    monkeypatch.setattr(moe, "_row_chunk", lambda m: min(m, rows))
+    net = gluon.nn.HybridSequential()
+    for load in loads:
+        block = parallel.DroplessMoEFFN(8, 4, 4, 2, held=2)
+        block.initialize()
+        block.load.set_data(NDArray(jnp.asarray(load, jnp.int32)))
+        net.add(block)
+    got = parallel.publish_load(net)
+    assert got["moe.row_pass_share"] == pytest.approx(want)
+    held = sum(sum(l[:2]) for l in loads) / 256
+    assert got["moe.held_share"] == pytest.approx(held)
+    assert held <= want

@@ -151,11 +151,14 @@ def test_dropless_expert_layer_compiles_for_v5e(one_chip, no_compile_cache,
     """Routing (softmax, or sigmoid scores chosen by score + bias), sort, the
     two grouped products and their gradients at a cell's widths, one
     sequence: ``ragged_dot`` has to have a lowering for the TPU forward and
-    for both gradients.  The bf16 router is the precision control of
+    for both gradients, and the row passes around it, whose trip count is
+    read on the device, have to stay loops or Mosaic kernels.  The bf16 router is the precision control of
     ``tests_tpu/test_moe_decoder_tpu.py`` and ``test_lfm2_moe_tpu.py``."""
     from mxnet_tpu.ops.registry import get_op
     from mxnet_tpu.parallel import moe
+    from mxnet_tpu.ops.pallas import gated_rows
     monkeypatch.setattr(moe, "_ROUTER_DTYPE", router)
+    monkeypatch.setattr(gated_rows, "on_tpu", lambda: True)
     n = 8192
     d, f, held, routed, k, how = EXPERT_LAYERS[config]
     how = dict(how)
@@ -176,4 +179,9 @@ def test_dropless_expert_layer_compiles_for_v5e(one_chip, no_compile_cache,
     # the N x k rows through both products, forward and backward, and no
     # (N, E, C) dispatch tensor: under 3 GB of temporaries
     assert compiled.memory_analysis().temp_size_in_bytes < 3e9
-    assert "ragged-dot" in compiled.as_text()
+    text = compiled.as_text()
+    assert "ragged-dot" in text
+    # the bounded row passes: the gather of the output's gradient stays a
+    # loop for the TPU; the stage between the products is its two kernels
+    assert text.count(" while(") == 1
+    assert "moe_gated_fwd" in text and "moe_gated_bwd" in text
