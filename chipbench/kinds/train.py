@@ -57,6 +57,20 @@ def _reference(net, loss_fn, check_labels, step, data, labels, key):
     return made, float(on_made), float(on_own)
 
 
+def program_snapshot():
+    """Every counter and gauge of the program's registry under its own name,
+    and every number of ``telemetry.compile_stats()`` as ``compile_<key>``
+    (``compile_ms_total``; a tracked site's count as
+    ``compile_sites.<site>``): what a metric file's ``counter`` can read."""
+    snap = telemetry.registry().snapshot()
+    out = {**snap["counters"], **snap["gauges"]}
+    stats = telemetry.compile_stats()
+    for site, count in stats.pop("sites").items():
+        out[f"compile_sites.{site}"] = count
+    out.update({f"compile_{k}": v for k, v in stats.items()})
+    return out
+
+
 def run(ctx):
     cfg, wl, n = ctx.cfg, ctx.wl, len(ctx.devices)
     if ctx.trace:
@@ -102,15 +116,18 @@ def run(ctx):
     got = float(step(x, one(made)).asnumpy())
     ctx.check(f"TrainStep's loss on the check labels within {tol} of the "
               f"float32 reference's", abs(got - ref) <= tol,
-              f"{got:.5f} vs {ref:.5f} (off by {abs(got - ref):.2e})")
+              f"{got:.5f} vs {ref:.5f} (off by {abs(got - ref):.2e})",
+              key="loss_err", value=abs(got - ref), limit=f"<= {tol}")
     ctx.check(f"a forward that misses the reference's logits would show: its "
               f"loss on the batch's own labels is over {TEETH} tolerances "
               f"above", ref_own - ref >= TEETH * tol,
               f"{ref_own:.4f} on the batch's labels, {ref:.4f} on the check "
-              f"labels")
+              f"labels", key="own_label_gap", value=ref_own - ref,
+              limit=f">= {TEETH * tol}")
     losses = [float(step(x, y).asnumpy()) for _ in range(WARMUP_STEPS)]
     ctx.check("loss falls over the warm-up steps", losses[-1] < losses[0],
-              str([round(v, 4) for v in losses]))
+              str([round(v, 4) for v in losses]), key="warmup_loss_fall",
+              value=losses[0] - losses[-1], limit="> 0")
     programs = step._jit._cache_size()
     created = ctx.executables
     tracked = telemetry.compile_stats()["events"]
@@ -138,7 +155,9 @@ def run(ctx):
     ctx.close_trace()
 
     ctx.check("every fetched loss is finite", bool(np.isfinite(fetched).all()),
-              f"{len(fetched)} steps, last {fetched[-1]:.4f}")
+              f"{len(fetched)} steps, last {fetched[-1]:.4f}",
+              key="nonfinite_losses",
+              value=int((~np.isfinite(fetched)).sum()), limit="== 0")
     # jax's own compile events (every executable the process creates or
     # loads, eager ops included), TrainStep's jit cache, and in a traced run
     # the program's compile-event stream
@@ -148,7 +167,9 @@ def run(ctx):
               and telemetry.compile_stats()["events"] == tracked,
               f"jax {created} -> {ctx.executables}, TrainStep "
               f"{programs} -> {step._jit._cache_size()}, telemetry {tracked} "
-              f"-> {telemetry.compile_stats()['events']}")
+              f"-> {telemetry.compile_stats()['events']}",
+              key="executables_in_window", value=ctx.executables - created,
+              limit="== 0")
     step_ms = np.diff(stamps) * 1e3
     ctx.log(f"{len(fetched)} steps in {elapsed:.3f} s; step ms "
             f"{[round(float(v), 1) for v in step_ms]}")
@@ -156,4 +177,11 @@ def run(ctx):
     ctx.e2e["train_samples_per_s"] = len(fetched) * batch / elapsed / n
     ctx.series["step_ms"] = step_ms.tolist()
     if ctx.trace:
-        ctx.counters["compile_ms_total"] = telemetry.compile_stats()["ms_total"]
+        # the program's own numbers under the program's own names: the expert
+        # layers' loads are aux state of the step, published as gauges
+        step.sync_params_to_net()
+        loads = parallel.publish_load(net)
+        ctx.counters.update(program_snapshot())
+        if not any(loads.values()):     # no expert layer: nothing to read
+            for gauge in loads:
+                del ctx.counters[gauge]

@@ -3,8 +3,9 @@ rules as a check that runs before anything touches a chip, and the view of
 one cell that ``run.py`` works from.
 
 Found by name, so that a later PR adds files and entries and edits none:
-``<configs[].file>``, ``chipbench/workloads/<cell>.json`` and
-``chipbench/layer_metrics/<metric>.json``.
+``<configs[].file>``, ``chipbench/workloads/<cell>.json``,
+``chipbench/layer_metrics/<metric>.json`` and, for a metric whose reader is
+not yet there, ``chipbench/layer_readers/<fn>.py`` (``readers.find``).
 """
 import json
 import os
@@ -168,7 +169,7 @@ def validate(m, root):
                        f"cells do not report")
         try:
             fn = load_json(root, metric_file(r["name"]))["reader"]["fn"]
-            if not callable(getattr(readers, fn, None)):
+            if readers.find(fn) is None:
                 err.append(f"{r['name']}: no reader {fn!r}")
         except (OSError, KeyError, ValueError) as e:
             err.append(f"{r['name']}: {metric_file(r['name'])}: {e!r}")

@@ -34,14 +34,21 @@ class Context:
         self.trace_dir = os.path.join(cell["root"], "chipbench", ".out",
                                       cell["name"], "trace")
         self.checks, self.e2e, self.counters, self.series = [], {}, {}, {}
+        self.compared = {}      # each number compared, beside its limit
         self.attempted = self.failed = self.executables = 0
         self.reduced = self.peaks = self._capture = None
 
     def log(self, msg):
         print(f"[chipbench] {msg}", flush=True)
 
-    def check(self, name, ok, detail=""):
+    def check(self, name, ok, detail="", key=None, value=None, limit=None):
+        """One comparison that ``correct`` rests on.  ``key`` is a short
+        plain name under which the number compared and its limit go onto the
+        result's line and, as the run's last lines, to standard error."""
         self.checks.append(bool(ok))
+        if key is not None:
+            self.compared[key] = {"value": value, "limit": limit,
+                                  "ok": bool(ok)}
         self.log(f"[{'ok' if ok else 'FAIL'}] {name}"
                  + (f": {detail}" if detail else ""))
 
@@ -90,7 +97,9 @@ def run_cell(cell, devices, seed, seconds, trace, t0=None):
     ctx.watch_compiles()
     dev = devices[0]
     ctx.check("runs on a TPU", dev.platform == "tpu",
-              f"{len(devices)} x {dev.device_kind} ({dev.platform})")
+              f"{len(devices)} x {dev.device_kind} ({dev.platform})",
+              key="tpu_chips", value=len(devices) * (dev.platform == "tpu"),
+              limit=f">= {cell['chips']}")
     try:
         importlib.import_module(f"chipbench.kinds.{ctx.cfg['kind']}").run(ctx)
     finally:
@@ -113,8 +122,10 @@ def run_cell(cell, devices, seed, seconds, trace, t0=None):
             if ctx.e2e.get(m["name"]) is not None:
                 result["metrics"][m["name"]] = {
                     "value": ctx.e2e[m["name"]], "unit": m["unit"]}
+        result["checks"] = ctx.compared         # last on the line
         return result
     from chipbench.trace import reduce
+    t_reduce = time.perf_counter()
     if dev.platform == "tpu":       # XLA:CPU has no peaks and no device plane
         ctx.peaks = flops.peaks(dev.device_kind)
         ctx.reduced = reduce.Trace(reduce.find_xplane(ctx.trace_dir))
@@ -124,10 +135,13 @@ def run_cell(cell, devices, seed, seconds, trace, t0=None):
                                "idle_gaps": ctx.reduced.idle_gaps()}
     for m in cell["per_layer"]:
         spec = dict(m["file"]["reader"])
-        value = getattr(readers, spec.pop("fn"))(ctx, **spec)
+        value = readers.find(spec.pop("fn"))(ctx, **spec)
         if value is not None:
             result["metrics"][m["name"]] = {"value": float(value),
                                             "unit": m["unit"]}
+    ctx.log(f"trace reduced and {len(cell['per_layer'])} per-layer metrics "
+            f"read in {time.perf_counter() - t_reduce:.1f} s")
+    result["checks"] = ctx.compared             # last on the line
     return result
 
 
@@ -152,6 +166,10 @@ def main():
     result = run_cell(cell, devices[:cell["chips"]], args.seed, args.seconds,
                       args.trace, t0=T0)
     print(json.dumps(result), flush=True)
+    for key, c in result["checks"].items():
+        print(f"[chipbench] {'ok' if c['ok'] else 'FAIL'} {key}: "
+              f"{c['value']} (limit {c['limit']})", file=sys.stderr)
+    sys.stderr.flush()
 
 
 if __name__ == "__main__":

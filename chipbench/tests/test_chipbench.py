@@ -3,6 +3,7 @@
     JAX_PLATFORMS=cpu python3 -m pytest chipbench/tests -q -p no:cacheprovider
 """
 import copy
+import importlib
 import json
 import os
 import shutil
@@ -37,8 +38,12 @@ def test_benchmark_json_meets_the_contract(real):
     assert manifest.validate(real, ROOT) == []
     for cell in real["workloads"]:
         view = manifest.cell(real, ROOT, cell["name"])
-        assert view["cfg"]["kind"] == "train"
-        assert view["wl"]["driver"] == "train_loop"
+        # a cell's kind is a module of chipbench/kinds/ with a run(ctx); its
+        # driver a legal name, which that module reads (train reads none)
+        kind = importlib.import_module(
+            f"chipbench.kinds.{view['cfg']['kind']}")
+        assert callable(kind.run)
+        assert manifest.NAME.match(view["wl"]["driver"])
         assert len(view["end_to_end"]) >= 2 and view["per_layer"]
 
 
@@ -153,6 +158,14 @@ def tiny(tmp_path_factory):
     m["per_layer"].append({"name": "step_ms_p99", "unit": "ms",
                            "better": "lower", "source": "host_clock",
                            "layer": "toy", "moves": "train_samples_per_s"})
+    # and so is a number the program publishes: its own name is the key
+    with open(os.path.join(root, manifest.metric_file("executables")),
+              "w") as f:
+        json.dump({"reader": {"fn": "counter",
+                              "key": "compile_executables_created"}}, f)
+    m["per_layer"].append({"name": "executables", "unit": "1",
+                           "better": "lower", "source": "program_counter",
+                           "layer": "toy", "moves": "setup_s"})
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(m, f)
     assert manifest.validate(m, root) == []
@@ -168,7 +181,9 @@ def _rehearse(tiny, cell, trace, capsys, seconds=2.0):
         pytest.skip(f"jax started with {len(jax.devices())} devices")
     res = run.run_cell(view, jax.devices()[:view["chips"]], 2**31 + 11,
                        seconds, trace)
-    assert set(res) == {"correct", "attempted", "failed", "metrics", "device"}
+    assert set(res) == {"correct", "attempted", "failed", "metrics", "device",
+                        "checks"}
+    assert list(res)[-1] == "checks"            # last on the line
     # a CPU run can never pass for a result, and that is its only fault
     assert res["correct"] is False
     out = capsys.readouterr().out
@@ -190,8 +205,30 @@ def test_cpu_rehearsal_untraced(tiny, cell, capsys):
 def test_cpu_rehearsal_traced(tiny, capsys):
     got = set(_rehearse(tiny, "resnet50_v1.train_b256", 1, capsys)["metrics"])
     # the metric added as a file and an entry is read like the others
-    assert got == {"step_ms_p50", "step_ms_p99", "compile_ms_total"}
-    # left out, not zero: no peak is known for a CPU, and it has no device plane
+    assert got == {"step_ms_p50", "step_ms_p99", "compile_ms_total",
+                   "executables"}
+    # left out, not zero: no peak is known for a CPU, it has no device plane,
+    # and a ResNet has no expert layer whose gauges could be read
+
+
+def test_the_program_s_snapshot_under_its_own_names():
+    """What a traced run hands to ``counter``: every counter and gauge of the
+    program's registry, and ``telemetry.compile_stats()`` as compile_<key>."""
+    from chipbench.kinds import train
+    from mxnet_tpu import telemetry
+    telemetry.registry().gauge("moe.held_share").set(0.5)
+    telemetry.registry().counter("toy.requests").inc(3)
+    try:
+        snap = train.program_snapshot()
+    finally:
+        telemetry.registry().remove("moe.held_share")
+        telemetry.registry().remove("toy.requests")
+    assert snap["moe.held_share"] == 0.5 and snap["toy.requests"] == 3
+    assert {"compile_ms_total", "compile_events", "compile_hits",
+            "compile_misses", "compile_executables_created",
+            "compile_persistent_cache_hits", "compile_backend_compile_s"} \
+        <= set(snap)
+    assert all(isinstance(v, (int, float)) for v in snap.values()), snap
 
 
 def test_a_wrong_forward_fails_the_reference_check(tiny, capsys, monkeypatch):
