@@ -12,6 +12,10 @@ Usage mirrors the reference:
     import mxnet_tpu as mx
     from mxnet_tpu import nd, autograd, gluon
 """
+import time as _time
+
+_IMPORT_T0 = _time.perf_counter()   # telemetry.now_us's clock, in seconds
+
 # Multi-process bring-up MUST precede any jax backend touch (jax.devices et
 # al.), so when launched under the DMLC_* env contract (tools/launch.py) the
 # coordination service connects before the rest of the package imports.
@@ -62,6 +66,7 @@ ndarray.sparse = sparse      # mx.nd.sparse, matching the reference layout
 from . import numpy as np           # mx.np — numpy-semantics frontend
 from . import numpy_extension as npx  # mx.npx — set_np + neural ops
 from . import profiler
+from . import telemetry           # mx.telemetry — spans, metrics, compile events
 from . import onnx
 from . import parallel
 from . import gluon
@@ -85,3 +90,9 @@ config._apply_startup()
 __version__ = "0.1.0"
 
 waitall = engine.waitall
+
+# when the program began, on ``time.perf_counter`` (the clock of every span):
+# a process's own start to here is its runtime's, from here on the program's
+telemetry.registry().gauge("process.import_t0_s").set(_IMPORT_T0)
+telemetry.registry().gauge("process.import_ms").set(
+    (_time.perf_counter() - _IMPORT_T0) * 1e3)

@@ -136,7 +136,7 @@ def setup_compile_cache():
     program one, the eager ops around them sub-second ones, and a warm
     start should recompile none of them.  Also registers ``watch_compiles()``.
     Entry points call this before their first compile (``chip_smoke.py``,
-    ``bench.py``, ``tests_tpu/``, the examples)."""
+    ``chipbench/run.py``, ``tests_tpu/``, the examples)."""
     import jax
 
     cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
@@ -155,9 +155,10 @@ _WATCHING = False
 
 def watch_compiles():
     """Feed ``telemetry.compile_stats()``'s always-on counters from jax's
-    own monitoring events (executables compiled or loaded, persistent-cache
-    hits, misses and seconds saved).  Registered once per process; the
-    listeners run only when jax compiles or loads."""
+    own monitoring events (seconds tracing, lowering, compiling or loading;
+    executables created; persistent-cache hits, misses, seconds saved and
+    seconds reading).  Registered once per process; the listeners run only
+    when jax traces, lowers, compiles or loads."""
     global _WATCHING
     if _WATCHING:
         return
@@ -167,6 +168,7 @@ def watch_compiles():
     from . import telemetry
     monitoring.register_event_listener(telemetry.note_jax_event)
     monitoring.register_event_duration_secs_listener(telemetry.note_jax_event)
+    monitoring.register_scalar_listener(telemetry.note_jax_region)
 
 
 def _apply_startup():

@@ -33,6 +33,7 @@ import numpy as np
 
 from .. import initializer as init_mod
 from .. import random as _random
+from .. import telemetry as _telemetry
 from ..base import MXNetError, dtype_np
 from ..context import current_context
 from ..ndarray import NDArray
@@ -346,18 +347,23 @@ def _exported(jitted, args, key):
     threefry is traced anew for every distinct shape (3.0 of 3.3 s for
     ResNet-50's 21 shapes, chip run, PR 26), while the stored module lowers
     in a tenth of that.  None where there is no cache directory, or the
-    program holds something ``jax.export`` will not serialize."""
+    program holds something ``jax.export`` will not serialize.  Which
+    it was goes onto the span open around the call (``program``: ``stored``
+    or ``lowered``)."""
     cache_dir = jax.config.jax_compilation_cache_dir
     if not cache_dir:
         return None
     path = os.path.join(cache_dir, f"mxnet_tpu-program-{key}.stablehlo")
     try:
         with open(path, "rb") as f:
-            return jax.export.deserialize(bytearray(f.read()))
+            exported = jax.export.deserialize(bytearray(f.read()))
+        _telemetry.scope_note(program="stored")
+        return exported
     except OSError:
         pass                    # not there yet
     except Exception:  # noqa: BLE001 — a cache must not stop the program:
         pass           # damaged, or another jax's format; written anew
+    _telemetry.scope_note(program="lowered")
     try:
         exported = jax.export.export(jitted)(*args)
     except ValueError:          # e.g. a custom call outside export's list

@@ -144,12 +144,41 @@ def _has_pending(net):
                for p in net.collect_params().values())
 
 
-def _materialize_net(net, sample_args, mesh):
+# The set-up spans (``cat="setup"``: kept whatever ``telemetry.enable``'s
+# ``sample``), ``<who>`` being ``TrainStep`` or ``EvalStep``:
+# ``<who>.deferred_init`` (children ``.infer_shapes``, ``.materialize``),
+# ``<who>.state_init`` and ``<who>.compile``.
+def _materialize_net(net, sample_args, mesh, who):
     """Before a step first reads ``net``'s parameters: one abstract pass
     for the deferred shapes, then one program that makes every pending
-    array.  Returns how many it made."""
-    with MeshScope(mesh):
-        return materialize(infer_shapes(net, *sample_args))
+    array (``program``: ``stored`` where its module was found beside the
+    compile cache, ``lowered`` where it had to be made, absent where this
+    process had run it before).  ``executables``: what jax created under
+    the span, counted while ``config.watch_compiles`` listens.  Returns how
+    many arrays it made."""
+    with _pscope(f"{who}.deferred_init", cat="setup") as span, \
+            MeshScope(mesh):
+        before = _telemetry.compile_stats()["executables_created"]
+        with _pscope(f"{who}.infer_shapes", cat="setup"):
+            pending = infer_shapes(net, *sample_args)
+        with _pscope(f"{who}.materialize", cat="setup"):
+            made = materialize(pending)
+        span.set(params=made, executables=_telemetry.compile_stats()[
+            "executables_created"] - before)
+    return made
+
+
+def _compile_first(who, call):
+    """The first call of a signature: trace, lower, compile or load from
+    the persistent cache, and the first execution (waited for, so the span
+    holds all of it).  The span's attributes are jax's own account of the
+    call (``telemetry.compile_split``): ``trace_ms``, ``lower_ms``,
+    ``backend_ms``, ``cache_retrieval_ms`` and ``cache_hit``."""
+    with _pscope(f"{who}.compile", cat="setup") as span:
+        before = _telemetry.compile_stats()
+        out = jax.block_until_ready(call())
+        span.set(**_telemetry.compile_split(before))
+    return out
 
 
 class TrainStep:
@@ -233,20 +262,13 @@ class TrainStep:
     # --------------------------------------------------------------- build --
     def _build(self, sample_args):
         if _has_pending(self.net):
-            # ``executables``: what jax created under the span, counted
-            # while config.watch_compiles listens
-            with _pscope("TrainStep.deferred_init", cat="step") as span:
-                before = _telemetry.compile_stats()["executables_created"]
-                made = _materialize_net(self.net, sample_args, self.mesh)
-                span.set(params=made, executables=_telemetry.compile_stats()[
-                    "executables_created"] - before)
+            _materialize_net(self.net, sample_args, self.mesh, "TrainStep")
         names, plist, arrays = param_names_and_values(self.net)
         self._names, self._plist = names, plist
         self._train_idx, self._aux_idx = trainable_split(plist)
         shardings = param_sharding(names, [a.shape for a in arrays],
                                    self.mesh, self.rules)
         self._param_shardings = shardings
-        arrays = jax.device_put(arrays, shardings)
         train_sh = [shardings[i] for i in self._train_idx]
         aux_sh = [shardings[i] for i in self._aux_idx]
         opt = self.optimizer
@@ -259,8 +281,14 @@ class TrainStep:
                     [jnp.copy(arrays[i]) for i in self._aux_idx],
                     tuple(tuple(state_template(opt, a)) for a in train))
 
-        self._train_arrays, self._aux_arrays, self._states = _run_program(
-            own, (arrays,), (train_sh, aux_sh, tuple(train_sh)))
+        # ends when ``own`` is compiled (or loaded) and DISPATCHED: its
+        # writes, 12 bytes a parameter under a multi-precision optimizer,
+        # run on the device under what follows (``TrainStep.compile`` waits)
+        with _pscope("TrainStep.state_init", cat="setup"):
+            arrays = jax.device_put(arrays, shardings)
+            self._train_arrays, self._aux_arrays, self._states = \
+                _run_program(own, (arrays,),
+                             (train_sh, aux_sh, tuple(train_sh)))
         # static per-param lr/wd multipliers (ref: Optimizer._get_lr/_get_wd)
         self._lr_mults = [plist[i].lr_mult for i in self._train_idx]
         self._wd_mults = [plist[i].wd_mult for i in self._train_idx]
@@ -492,25 +520,6 @@ class TrainStep:
         with _telemetry.compile_guard("TrainStep", self._jit, key="step"):
             return self._invoke(args)
 
-    def _compile_first(self, args):
-        """The first call of a signature: trace, lower, compile or load
-        from the persistent cache, and the first execution (waited for, so
-        the span holds all of it).  ``cache_hit`` says which it was, from
-        jax's own cache events (None when nobody watches them:
-        ``config.watch_compiles``)."""
-        with _pscope("TrainStep.compile", cat="step") as span:
-            before = _telemetry.compile_stats()
-            out = self._run_guarded(args)
-            jax.block_until_ready(out[4])
-            after = _telemetry.compile_stats()
-            hits = after["persistent_cache_hits"] \
-                - before["persistent_cache_hits"]
-            misses = after["persistent_cache_misses"] \
-                - before["persistent_cache_misses"]
-            span.set(cache_hit=(hits > 0 and misses == 0)
-                     if hits or misses else None)
-        return out
-
     def _step(self, data, label, span):
         _fire("step")
         t_wall = time.perf_counter()
@@ -539,7 +548,8 @@ class TrainStep:
             self._last_avals = jax.tree.map(
                 lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args)
         if fresh:
-            out = self._compile_first(args)
+            out = _compile_first("TrainStep",
+                                 lambda: self._run_guarded(args))
         else:
             # host time to hand the program to the device: the device
             # runs it after this span has closed
@@ -728,12 +738,13 @@ class EvalStep:
 
     def _build(self, sample_args):
         if _has_pending(self.net):
-            _materialize_net(self.net, sample_args, self.mesh)
+            _materialize_net(self.net, sample_args, self.mesh, "EvalStep")
         names, plist, arrays = param_names_and_values(self.net)
         self._names, self._plist = names, plist
         sh = param_sharding(names, [a.shape for a in arrays], self.mesh,
                             self.rules)
-        self._arrays = jax.device_put(arrays, sh)
+        with _pscope("EvalStep.state_init", cat="setup"):
+            self._arrays = jax.device_put(arrays, sh)
         self._shardings = sh
         self._built = True
 
@@ -764,8 +775,12 @@ class EvalStep:
         key = _random.next_key()
         dat_sh = NamedSharding(self.mesh, self._data_pspec)
         data_leaves = [_put_batch(l, dat_sh) for l in data_leaves]
-        with _telemetry.compile_guard("EvalStep", self._jit, key="eval"):
-            outs = self._jit(self._arrays, key, *data_leaves)
+
+        def run():
+            with _telemetry.compile_guard("EvalStep", self._jit, key="eval"):
+                return self._jit(self._arrays, key, *data_leaves)
+        outs = _compile_first("EvalStep", run) \
+            if self._jit._cache_size() == 0 else run()
         res = _unflatten_nd(self._holder.out_tree,
                             tuple(NDArray(o) for o in outs))
         if isinstance(res, tuple) and len(res) == 1:

@@ -176,7 +176,12 @@ class scope:
       test (``scope_cost()``);
     - while ``telemetry`` is armed, a ``telemetry.Span`` of the same name
       in its store, child of the enclosing scope on this thread
-      (``telemetry.open_scope``); ``set(**attrs)`` adds attributes to it;
+      (``telemetry.open_scope``); ``set(**attrs)`` adds attributes to it.
+      ``telemetry.enable(sample=...)`` samples root scopes, and what is
+      nested in them, out; ``cat="setup"`` marks a scope that runs once a
+      process or once a signature (``TrainStep.deferred_init``,
+      ``.state_init``, ``.compile``), which is kept whatever ``sample``
+      says;
     - while ``mx.profiler`` runs, an event in its Chrome-trace buffer.
 
     The program's names are ``<Component>.<phase>`` with the component in
@@ -200,7 +205,8 @@ class scope:
         self._ann = _Annotation(self._name)
         self._ann.__enter__()
         if _telemetry.ACTIVE and self._STORE:
-            self._token = _telemetry.open_scope(self._name, self._attrs)
+            self._token = _telemetry.open_scope(
+                self._name, self._attrs, keep=self._cat == "setup")
         self._t0 = _now_us()
         return self
 

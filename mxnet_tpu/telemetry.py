@@ -39,7 +39,8 @@ paths; this is the equivalent for the REQUEST path:
   over this registry (the two systems cannot report different values
   for one series), ``admission.ClassStats`` hosts its p50/p99 here, and
   span durations feed per-phase latency histograms
-  (``<server>::<phase>_ms``) that ``bench.py`` reads.  One
+  (``<server>::<phase>_ms``; a ``profiler.scope``'s, ``<name>_ms``, which
+  the benchmark's per-layer readers read).  One
   ``exposition()`` schema (JSON + Prometheus-style text via
   ``render_prometheus``) is served by ``InferenceServer.telemetry()``,
   ``GenerationServer.telemetry()``, ``ServingFleet.telemetry()``
@@ -112,7 +113,8 @@ __all__ = [
     "compile_site_stats", "compile_stats", "compile_events",
     "compile_gauges", "reset_compiles", "memory_gauges", "ckpt_gauges",
     "FlightRecorder", "flight", "enable_flight", "flight_from_env",
-    "flight_trip", "FLIGHT_ENV", "maybe_trace",
+    "flight_trip", "FLIGHT_ENV", "maybe_trace", "scope_note",
+    "note_jax_region", "compile_split",
 ]
 
 SCHEMA = "mxtpu.telemetry/1"
@@ -357,13 +359,15 @@ class Trace:
             (_CFG.scopes if self.scoped else _CFG.collected).append(self)
 
 
-def maybe_trace(name, server="", t0=None, attrs=None):
+def maybe_trace(name, server="", t0=None, attrs=None, keep=False):
     """A fresh ``Trace`` honoring the off-switch, suppression, and the
     sampling rate — or None.  The non-request spelling of
     ``begin_request`` (training-step spans use it: there is no Request
     future to carry the trace, the emitting loop owns the whole
-    lifecycle and calls ``finish()`` itself)."""
-    if not ACTIVE or _suppressed() or not _sampled():
+    lifecycle and calls ``finish()`` itself).  ``keep`` leaves the
+    sampling rate out: ``sample`` bounds what spans cost under load, and a
+    span that runs once a process has no such cost."""
+    if not ACTIVE or _suppressed() or not (keep or _sampled()):
         return None
     try:
         return Trace(name, server=server, t0=t0, attrs=attrs)
@@ -379,18 +383,23 @@ def maybe_trace(name, server="", t0=None, attrs=None):
 # closes); a nested one is a child span of the enclosing scope.  One device
 # step serves a whole group of requests and belongs to none of them, so a
 # scope never joins a request's tree: it names the requests current on its
-# thread (``push_current``) in its ``traces`` attribute instead.
+# thread (``push_current``) in its ``traces`` attribute instead.  A SET-UP
+# scope (``profiler.scope(name, cat="setup")``: it runs once a process or
+# once a signature) is never sampled out: under a root that was, it is the
+# root of a small trace of its own, and set-up scopes nested in it are its
+# children.
 _UNSAMPLED = object()      # an enclosing scope was sampled out: so are we
 
 
-def open_scope(name, attrs=None):
+def open_scope(name, attrs=None, keep=False):
     """Begin the in-memory span of a ``profiler.scope`` and return the
-    token ``close_scope`` takes.  Never raises."""
+    token ``close_scope`` takes; ``keep`` for a set-up scope.  Never
+    raises."""
     prev = getattr(_tls, "scope", None)
     span = _UNSAMPLED
     try:
-        if prev is None:
-            tr = maybe_trace(name, attrs=attrs)
+        if prev is None or (keep and prev is _UNSAMPLED):
+            tr = maybe_trace(name, attrs=attrs, keep=keep)
             if tr is not None:
                 tr.scoped = True
                 span = tr.root
@@ -418,10 +427,19 @@ def close_scope(token, t1=None, error=None):
         span.end(t1)
         if error is not None:
             span.attrs.setdefault("error", error.__name__)
-        if prev is None:
+        if span is span.trace.root:
             span.trace.finish()
     except Exception:
         _oops()
+
+
+def scope_note(**attrs):
+    """Attributes for the innermost scope open on this thread, from code
+    that runs under it without holding it (``gluon.parameter`` says whether
+    a stored program was found); nothing where there is none."""
+    span = getattr(_tls, "scope", None)
+    if span is not None and span is not _UNSAMPLED:
+        span.attrs.update(attrs)
 
 
 def scope_spans(name=None, since_us=None, until_us=None):
@@ -1110,6 +1128,9 @@ class _CompileSite:
 
 
 _COMPILE_LOCK = threading.Lock()
+# compile_split's attribute -> the site's registry counter it adds to
+_SPLIT_COUNTERS = (("trace_ms", "jaxpr_trace_ms"), ("lower_ms", "lower_ms"),
+                   ("backend_ms", "backend_ms"))
 _COMPILE_SITES = {}
 _COMPILE_EVENTS = collections.deque(maxlen=1024)
 
@@ -1122,7 +1143,11 @@ def compile_event(site, key=None, ms=None, cache_hit=False,
     None (no event record).  Otherwise one event is recorded: a new
     executable exists — ``key`` is a short signature label, ``ms`` the
     wall time of the compiling call, ``n_executables`` the site's cache
-    size after (default: previous count + 1).  A miss past the site's
+    size after (default: previous count + 1); ``attrs`` go onto the record,
+    and those of ``compile_split`` (``track_compile`` passes them) also add
+    to the registry's ``compile::<site>::jaxpr_trace_ms`` / ``::lower_ms`` /
+    ``::backend_ms`` beside ``compile::ms_total``, so the exposition says
+    where a site's compile seconds went.  A miss past the site's
     ``pin_compile_census`` count is an *unexpected recompile*: it
     increments the ``compile::recompiles_unexpected`` counter and lands
     a ``recompile`` span event on the thread's current spans (the same
@@ -1167,6 +1192,10 @@ def compile_event(site, key=None, ms=None, cache_hit=False,
             reg.counter("compile::events").add()
             if ms is not None:
                 reg.counter("compile::ms_total").add(float(ms))
+            for attr, name in _SPLIT_COUNTERS:
+                if attrs.get(attr) is not None:
+                    reg.counter(f"compile::{site}::{name}").add(
+                        float(attrs[attr]))
             reg.gauge(f"compile_cache::{site}").set(n_after)
             if unexpected:
                 reg.counter("compile::recompiles_unexpected").add()
@@ -1220,30 +1249,57 @@ def compile_site_stats(site):
                 "ms_total": st.ms_total, "unexpected": st.unexpected}
 
 
-# What jax itself compiled or loaded, whoever asked and whether or not
-# tracing is armed: fed by the ``jax.monitoring`` listeners that
-# ``config.setup_compile_cache()`` registers (this module stays
-# standard-library only).  They fire only when jax compiles or loads an
-# executable, so a steady-state step pays nothing.
+# What jax itself traced, lowered, compiled or loaded, whoever asked and
+# whether or not tracing is armed: fed by the ``jax.monitoring`` listeners
+# that ``config.watch_compiles()`` registers (this module stays
+# standard-library only).  They fire only when jax traces, lowers, compiles
+# or loads, so a steady-state step pays nothing.
 _JAX_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": (None, "jaxpr_trace_s"),
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": (None, "lower_s"),
     "/jax/core/compile/backend_compile_duration":
         ("executables_created", "backend_compile_s"),
     "/jax/compilation_cache/cache_hits": ("persistent_cache_hits", None),
     "/jax/compilation_cache/cache_misses": ("persistent_cache_misses", None),
     "/jax/compilation_cache/compile_time_saved_sec":
         (None, "compile_time_saved_s"),
+    "/jax/compilation_cache/cache_retrieval_time_sec":
+        (None, "cache_retrieval_s"),
 }
+# the three that jax times as a REGION (an opening event, then a duration):
+# tracing a function traces the jitted functions it calls, so regions nest
+_JAX_REGIONS = frozenset(e for e in _JAX_EVENTS if "/core/compile/" in e)
 _JAX_COMPILES = {"executables_created": 0, "backend_compile_s": 0.0,
                  "persistent_cache_hits": 0, "persistent_cache_misses": 0,
-                 "compile_time_saved_s": 0.0}
+                 "compile_time_saved_s": 0.0, "jaxpr_trace_s": 0.0,
+                 "lower_s": 0.0, "cache_retrieval_s": 0.0}
+
+
+def note_jax_region(event, _start=None, **_):
+    """``jax.monitoring`` scalar listener: jax opens one of its timed
+    regions on this thread (``note_jax_event`` hears it close)."""
+    if event in _JAX_REGIONS:
+        _tls.jax_regions = getattr(_tls, "jax_regions", 0) + 1
 
 
 def note_jax_event(event, seconds=None, **_):
-    """``jax.monitoring`` listener body (events and event durations)."""
+    """``jax.monitoring`` listener body (events and event durations).  A
+    region's seconds count only where no other region is open around it on
+    its thread (the jitted functions a traced function calls are traced
+    inside its own time, an eager operation compiled under a trace inside
+    the trace's), so ``jaxpr_trace_s + lower_s + backend_compile_s`` never
+    exceeds the wall they were spent in; ``executables_created`` counts
+    every one."""
     keys = _JAX_EVENTS.get(event)
     if keys is None:
         return
     count, secs = keys
+    if event in _JAX_REGIONS:
+        depth = getattr(_tls, "jax_regions", 0)
+        if depth:                      # 0: nobody listens for the openings
+            _tls.jax_regions = depth - 1
+            if depth > 1:
+                secs = None
     with _COMPILE_LOCK:
         if count is not None:
             _JAX_COMPILES[count] += 1
@@ -1251,12 +1307,39 @@ def note_jax_event(event, seconds=None, **_):
             _JAX_COMPILES[secs] += float(seconds)
 
 
+def compile_split(before, after=None):
+    """What jax did between two readings of ``compile_stats()`` (``after``:
+    now), as the attributes a compile's span and event carry: ``trace_ms``
+    (to a jaxpr), ``lower_ms`` (to a StableHLO module), ``backend_ms`` (XLA's
+    compile, or the persistent cache's load: ``cache_retrieval_ms`` is the
+    part of it spent reading the cache) and ``cache_hit`` (every executable
+    asked of the persistent cache was found; None when none was asked, or
+    nobody watches jax's events: ``config.watch_compiles``).  The sums are
+    the PROCESS's: two threads compiling at once read each other's
+    seconds."""
+    if after is None:
+        after = compile_stats()
+    hits, misses, trace, lower, backend, retrieval = (
+        after[k] - before[k] for k in (
+            "persistent_cache_hits", "persistent_cache_misses",
+            "jaxpr_trace_s", "lower_s", "backend_compile_s",
+            "cache_retrieval_s"))
+    return {"trace_ms": round(trace * 1e3, 3),
+            "lower_ms": round(lower * 1e3, 3),
+            "backend_ms": round(backend * 1e3, 3),
+            "cache_retrieval_ms": round(retrieval * 1e3, 3),
+            "cache_hit": (hits > 0 and misses == 0)
+            if hits or misses else None}
+
+
 def compile_stats():
     """Process-wide compile totals.  ``events`` .. ``sites`` come from
     the program's tracked compile sites and count only while tracing is
     armed; ``executables_created`` (compiled or loaded from the
     persistent cache), ``persistent_cache_hits`` / ``_misses`` and the
-    two second-sums are jax's own events and count always."""
+    second-sums (``jaxpr_trace_s``, ``lower_s``, ``backend_compile_s`` with
+    ``cache_retrieval_s`` inside it, ``compile_time_saved_s``) are jax's own
+    events and count always (``note_jax_event``)."""
     with _COMPILE_LOCK:
         sites = dict(_COMPILE_SITES)
         out = {"events": 0, "hits": 0, "misses": 0, "ms_total": 0.0,
@@ -1319,7 +1402,10 @@ class track_compile:
     probed or recorded).  With a jit wrapper (anything exposing
     ``_cache_size``) or an explicit ``probe`` callable, the cache size
     is read before/after: growth emits one ``compile_event`` per new
-    executable with the block's wall-ms split between them, no growth
+    executable with the block's wall-ms and jax's own split of it
+    (``compile_split`` across the block, taken only when the cache grew;
+    its ``cache_hit`` goes by ``persistent_cache_hit`` there, the event's
+    own ``cache_hit`` being the jit cache's) divided between them, no growth
     records a hit — growth another concurrent tracked block already
     claimed is deduplicated through a per-fn high-water mark (pass
     ``hw_key`` with ``probe`` to name the owning object; a ``jit_fn``
@@ -1329,7 +1415,7 @@ class track_compile:
     dispatch proves no executable exists."""
 
     __slots__ = ("_site", "_key", "_assume", "_probe", "_on", "_t0",
-                 "_n0", "_hw_key")
+                 "_n0", "_hw_key", "_jax0")
 
     def __init__(self, site, jit_fn=None, key=None, assume_miss=False,
                  probe=None, hw_key=None):
@@ -1347,6 +1433,7 @@ class track_compile:
             return self
         self._t0 = time.perf_counter()
         self._n0 = None
+        self._jax0 = dict(_JAX_COMPILES)
         if self._probe is not None:
             try:
                 self._n0 = int(self._probe())
@@ -1384,9 +1471,14 @@ class track_compile:
                     compile_event(self._site, key=self._key,
                                   cache_hit=True)
                 else:
+                    split = compile_split(self._jax0, _JAX_COMPILES)
+                    hit = split.pop("cache_hit")
                     for _ in range(grew):
-                        compile_event(self._site, key=self._key,
-                                      ms=ms / grew)
+                        compile_event(
+                            self._site, key=self._key, ms=ms / grew,
+                            persistent_cache_hit=hit,
+                            **{k: round(v / grew, 3)
+                               for k, v in split.items()})
             elif exc and exc[0] is not None:
                 # probe-less + the call raised: nothing proves an
                 # executable exists.  Recording the assumed miss would

@@ -723,9 +723,13 @@ def test_family_rehearsed_through_the_benchmark(toy_benchmark, capsys, trace):
     assert len(fails) == 1 and "runs on a TPU" in fails[0], fails
     assert res["correct"] is False and res["device"]["platform"] == "cpu"
     assert res["attempted"] > 0 and res["failed"] == 0
-    want = {"step_ms_p50", "compile_ms_total"} if trace \
-        else {"setup_s", "train_samples_per_s"}
-    assert set(res["metrics"]) == want
+    # a traced line holds whatever the program's counters and spans let a
+    # later metric read off the chip too; an untraced one the two it times
+    if trace:
+        assert set(res["metrics"]) >= {"step_ms_p50", "compile_ms_total"}
+        assert not set(res["metrics"]) & {"setup_s", "train_samples_per_s"}
+    else:
+        assert set(res["metrics"]) == {"setup_s", "train_samples_per_s"}
 
 
 @pytest.mark.parametrize("name", sorted(ON_THIS_DECODER))
@@ -742,7 +746,7 @@ def test_the_real_benchmark_holds_the_cell(name):
     assert view["wl"]["batch_per_chip"] in (1, 2, 4)
     assert [m["name"] for m in view["end_to_end"]] == [
         "train_samples_per_s", "setup_s"]
-    assert {m["name"] for m in view["per_layer"]} == {
+    assert {m["name"] for m in view["per_layer"]} >= {
         "compile_ms_total", "step_ms_p50", "mfu_pct", "device_idle_pct.train"}
     entry = real["workloads"][cells.index(cell)]
     assert len(entry["why"]) <= 200 and "four times" in view["wl"]["why"]

@@ -842,8 +842,8 @@ def test_trainstep_step_spans_and_compile_events():
         # the first call makes the parameters initialize() recorded and
         # compiles, later ones dispatch: a span that ends when the dispatch
         # returns is not called compute
-        assert names == (["TrainStep.deferred_init", "TrainStep.h2d",
-                          "TrainStep.compile"] if i == 0
+        assert names == (["TrainStep.deferred_init", "TrainStep.state_init",
+                          "TrainStep.h2d", "TrainStep.compile"] if i == 0
                          else ["TrainStep.h2d", "TrainStep.dispatch"])
     snap = telemetry.registry().snapshot()
     assert snap["histograms"]["TrainStep.step_ms"]["count"] == 3
