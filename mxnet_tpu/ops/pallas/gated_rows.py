@@ -27,6 +27,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ...context import on_tpu
+from .once import bind
 
 # rows a grid step: 256 x (2F + F + 2F) x 2 bytes, double-buffered, and the
 # float32 intermediates are 16.7 MB of VMEM backward at the widest cell's F
@@ -77,7 +78,13 @@ def _bwd_kernel(total_ref, x_ref, dy_ref, out_ref):
 def _call(total, *operands, backward, block, interpret):
     """One of the two kernels.  A ``jax.jit``, so that a model's layers
     share one lowering of each (they are traced three times a layer under
-    recomputation; each lowering costs a warm ``setup_s`` ~0.15 s)."""
+    recomputation; each lowering costs a warm ``setup_s`` ~0.15 s), over
+    the kernel traced once a process (``once.bind``)."""
+    return bind(_build, (total, *operands), backward=backward, block=block,
+                interpret=interpret)[0]
+
+
+def _build(total, *operands, backward, block, interpret):
     kernel, name = (_bwd_kernel, "moe_gated_bwd") if backward \
         else (_fwd_kernel, "moe_gated_fwd")
     m, width = operands[0].shape

@@ -15,19 +15,23 @@ sigmoid scores chosen by score + a per-expert bias that selects and does not
 weigh), top-k, re-normalised; the block is told which experts it HOLDS
 (``first_expert``, ``held``: one chip's share under expert parallelism),
 sorts the token-expert assignments that land on its own experts to the front,
-runs two grouped matrix products (``jax.lax.ragged_dot``) over them with
-``silu(g) * u`` between (gated experts, no bias), and sums its experts'
-weighted results back per token.  No capacity, no dropped token whatever the
-imbalance: the row buffer holds all ``N * k`` assignments.  What the absent
-experts would have added is left out; on one chip the layer runs without an
-exchange.  This is the path for a model whose experts are routed per token at
-real widths.
+runs two grouped matrix products over them with ``silu(g) * u`` between (gated
+experts, no bias), and sums its experts' weighted results back per token.
+The products and their gradients are the Pallas kernels of
+``ops/pallas/grouped_matmul.py`` (``moe_grouped_fwd`` / ``_dx`` / ``_dw``,
+PR 38; XLA's ``jax.lax.ragged_dot`` is no longer on the path), which walk the
+held rows' tiles by a map built once a layer from the experts' loads.  No
+capacity, no dropped token whatever the imbalance: the row buffer holds all
+``N * k`` assignments.  What the absent experts would have added is left out;
+on one chip the layer runs without an exchange.  This is the path for a model
+whose experts are routed per token at real widths.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from ..ops.pallas import grouped_matmul
 from ..ops.pallas.gated_rows import gated_rows
 from ..ops.registry import register_op
 
@@ -343,9 +347,12 @@ def _moe_dropless_ffn(tokens, router, gate_up, down, bias=None, num_experts=1,
 
     The N * k assignments are sorted by held expert, the unheld behind the
     held; the held rows run as two grouped products whose groups are the
-    experts' loads.  Rows past the groups' sum, ``total``, belong to no held
-    expert and a grouped product does not visit them.  The passes that
-    WRITE sorted rows between and after the products stop there too:
+    experts' loads: the kernels of ``ops/pallas/grouped_matmul.py``, all six
+    calls of a layer (two products, their input and weight gradients) over
+    ONE tile map built here from the loads.  Rows past the groups' sum,
+    ``total``, belong to no held expert and a grouped product does not
+    visit them.  The passes that WRITE sorted rows between and after the
+    products stop there too:
     ``silu(g) * u`` and its backward pass are the kernels of
     ``ops/pallas/gated_rows.py``, whose grid steps past ``total`` fetch and
     write nothing, the gather of the output's gradient is a loop of
@@ -353,7 +360,9 @@ def _moe_dropless_ffn(tokens, router, gate_up, down, bias=None, num_experts=1,
     loop it made XLA schedule the step of ``mellum2_12b_a2_5b.train_s8192``
     with 2 GB more of temporaries, which does not load.)  What lies past
     ``total`` in a buffer of sorted rows may be anything: other tokens,
-    what the buffer held before, XLA:TPU's grouped product leaves garbage.
+    what the buffer held before; the grouped products leave such rows
+    unwritten (NaN in the interpreter), as XLA:TPU's ``ragged_dot`` left
+    garbage there before them.
     The token-side sums that READ sorted rows (the weighted sum out, the
     tokens' gradient) mask those rows before they sum: no row past ``total``
     is ever read unmasked, in the backward pass too."""
@@ -376,10 +385,13 @@ def _moe_dropless_ffn(tokens, router, gate_up, down, bias=None, num_experts=1,
             at, unique_indices=True).reshape(n, k)
         sizes = load[first_expert:first_expert + held]
         total = jnp.sum(sizes)
+        groups = grouped_matmul.group_map(
+            sizes, n * k, grouped_matmul.row_tile(n * k))
         rows = _dispatch_rows(tokens, order, back, total)
     with jax.named_scope("experts"):
-        hidden = gated_rows(jax.lax.ragged_dot(rows, gate_up, sizes), total)
-        out = jax.lax.ragged_dot(hidden, down, sizes)
+        hidden = gated_rows(grouped_matmul.grouped_matmul(
+            rows, gate_up, groups), total)
+        out = grouped_matmul.grouped_matmul(hidden, down, groups)
     with jax.named_scope("combine"):
         out = _combine_rows(out, gates, order, back, total)
     return out, load
@@ -494,7 +506,10 @@ def publish_load(net):
     under even routing) and ``moe.row_pass_share`` (the share of the N * k
     sorted rows that the op's bounded gather visited: the held rows rounded
     up to whole chunks of ``_row_chunk``, layer by layer; 1 is a pass over
-    every row).
+    every row) and ``moe.product_tile_share`` (the share of the N * k
+    buffer's row tiles that the grouped-product kernels compute: each
+    layer's held experts' tiles, a tile two experts share once for each;
+    ``grouped_matmul.tile_visits``).
     Returns ``{gauge: value}``; all are 0 before any step."""
     import numpy as np
     from .. import telemetry
@@ -504,7 +519,7 @@ def publish_load(net):
     loads = [np.asarray(b.load.data()._data, np.float64) for b in blocks]
     total = sum(l.sum() for l in loads)
     values = {"moe.load_max_over_mean": 0.0, "moe.held_share": 0.0,
-              "moe.row_pass_share": 0.0}
+              "moe.row_pass_share": 0.0, "moe.product_tile_share": 0.0}
     if total:
         held = [l[slice(*b.held_range())].sum()
                 for b, l in zip(blocks, loads)]
@@ -515,6 +530,11 @@ def publish_load(net):
         values["moe.row_pass_share"] = float(sum(
             min(-(-h // c) * c, l.sum())
             for h, l, c in zip(held, loads, chunks) if c) / total)
+        tiles = [grouped_matmul.row_tile(int(l.sum())) for l in loads]
+        values["moe.product_tile_share"] = float(sum(
+            grouped_matmul.tile_visits(l[slice(*b.held_range())], t)
+            for b, l, t in zip(blocks, loads, tiles) if t) / sum(
+            -(-int(l.sum()) // t) for l, t in zip(loads, tiles) if t))
     for name, v in values.items():
         telemetry.registry().gauge(name).set(v)
     return values

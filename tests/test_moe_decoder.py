@@ -364,42 +364,22 @@ def test_gated_rows_stop_at_the_held_rows(rows, total, block, dtype,
     assert not np.asarray(dx[total:end], np.float32).any()
 
 
-# what XLA:TPU's grouped product does and XLA:CPU's does not: the rows past
-# the groups' sum come back holding anything, forward and in the gradient of
-# the rows
-def _poison(rows, group_sizes):
-    past = jnp.arange(rows.shape[0]) >= jnp.sum(group_sizes)
-    return jnp.where(past[:, None], jnp.nan, rows)
-
-
-@jax.custom_vjp
-def poisoned_ragged_dot(lhs, rhs, group_sizes):
-    return _poison(_RAGGED_DOT(lhs, rhs, group_sizes), group_sizes)
-
-
-def _poisoned_fwd(lhs, rhs, group_sizes):
-    out, vjp = jax.vjp(lambda l, r: _RAGGED_DOT(l, r, group_sizes), lhs, rhs)
-    return _poison(out, group_sizes), (vjp, group_sizes)
-
-
-def _poisoned_bwd(kept, dy):
-    vjp, group_sizes = kept
-    d_lhs, d_rhs = vjp(dy)
-    return _poison(d_lhs, group_sizes), d_rhs, None
-
-
-_RAGGED_DOT = jax.lax.ragged_dot
-poisoned_ragged_dot.defvjp(_poisoned_fwd, _poisoned_bwd)
+def ragged_grouped_matmul(x, w, groups):
+    """The grouped product the op ran before its kernels (PR 38): XLA's
+    ``ragged_dot`` over the experts of ``groups`` (a ``GroupMap``); on
+    XLA:CPU its rows past the groups' sum come back zero."""
+    return jax.lax.ragged_dot(x, w, jnp.diff(groups.offsets))
 
 
 @pytest.mark.parametrize("recomputed", [False, True],
                          ids=["kept", "recomputed"])
 def test_rows_past_the_groups_sum_may_hold_anything(recomputed, chunk,
                                                     monkeypatch):
-    """The op, its load and all four gradients with a grouped product that
-    leaves NaN in every row past its groups' sum, forward and backward:
-    finite, and bit for bit what the clean product gives.  No pass reads
-    such a row unmasked."""
+    """The op, its load and all four gradients with the grouped-product
+    kernels, which leave NaN in every row past their groups' sum in the
+    interpreter (as stale memory on the chip), forward and in the rows'
+    gradient: finite, and within rounding of the same op over
+    ``ragged_dot``.  No pass reads such a row unmasked."""
     router, gate_up, down = _expert_weights(6)
     tokens = jnp.asarray(np.random.RandomState(7).randn(96, D), jnp.float32)
 
@@ -409,21 +389,29 @@ def test_rows_past_the_groups_sum_may_hold_anything(recomputed, chunk,
             return (out ** 2).sum(), (out, load)
         if recomputed:
             total = jax.checkpoint(total)
-        grads, (out, load) = jax.grad(total, range(4), has_aux=True)(
-            tokens, router, gate_up, down)
+        with jax.default_matmul_precision("highest"):
+            grads, (out, load) = jax.grad(total, range(4), has_aux=True)(
+                tokens, router, gate_up, down)
         return (out, load) + grads
-    clean = run()
-    assert 0 < int(clean[1][16:32].sum()) < 96 * K
-    monkeypatch.setattr(jax.lax, "ragged_dot", poisoned_ragged_dot)
     poisoned = run()
+    sizes = poisoned[1][16:32]
+    assert 0 < int(sizes.sum()) < 96 * K
+    # the poison is there: the kernel's own rows past the sum are NaN
+    rows = 96 * K
+    groups = moe.grouped_matmul.group_map(
+        sizes, rows, moe.grouped_matmul.row_tile(rows))
+    raw = moe.grouped_matmul.grouped_matmul(jnp.ones((rows, D)),
+                                            gate_up[16:32], groups)
+    assert bool(jnp.isnan(raw[int(sizes.sum()):]).all())
+    monkeypatch.setattr(moe.grouped_matmul, "grouped_matmul",
+                        ragged_grouped_matmul)
+    clean = run()
+    assert np.array_equal(poisoned[1], clean[1])
     for got, want in zip(poisoned, clean):
         assert np.isfinite(np.asarray(got)).all()
-        assert np.array_equal(got, want)
+        np.testing.assert_allclose(got, want, atol=2e-5 * float(
+            jnp.abs(want).max()))
     assert float(jnp.abs(clean[0]).max()) > 0.1
-    # the poison is there: the product's own rows past the sum are NaN
-    sizes = clean[1][16:32]
-    raw = jax.lax.ragged_dot(jnp.ones((96 * K, D)), gate_up[16:32], sizes)
-    assert bool(jnp.isnan(raw[int(sizes.sum()):]).all())
 
 
 def test_route_topk_renormalises_over_the_chosen():
@@ -804,9 +792,11 @@ def test_train_step_program_names_every_new_scope(stepped):
     assert "rematted_computation" in text
     for kernel in ("flash_attention_fwd", "flash_attention_bwd_dq",
                    "flash_attention_bwd_dkv", "window_attention_fwd",
-                   "window_attention_bwd_dq", "window_attention_bwd_dkv"):
+                   "window_attention_bwd_dq", "window_attention_bwd_dkv",
+                   "moe_grouped_fwd", "moe_grouped_dx", "moe_grouped_dw"):
         assert kernel in text
-    assert "ragged_dot" in text
+    ops = "\n".join(l for l in text.splitlines() if not l.startswith("#loc"))
+    assert "ragged_dot" not in ops
 
 
 def test_train_step_program_bounds_the_row_passes(monkeypatch):
@@ -816,6 +806,9 @@ def test_train_step_program_bounds_the_row_passes(monkeypatch):
     products is the two kernels that take that count, and no select runs
     over a whole ``[N x k, d]`` buffer."""
     monkeypatch.setattr(moe, "_row_chunk", lambda m: min(m, 128))
+    # the grouped products' tiles too: their interpreted bodies' masks are
+    # selects over a tile, which must not be the whole buffer here
+    monkeypatch.setattr(moe.grouped_matmul, "_ROW_TILE", 128)
     net, loss_fn, batch = family.build(dict(TOY, sequence_length=64))
     net.initialize()
     net.cast("bfloat16")
@@ -852,7 +845,8 @@ def test_the_load_reads_back_after_a_step(stepped):
     net, step, ids, labels = stepped
     before = parallel.publish_load(net)
     assert before == {"moe.load_max_over_mean": 0.0, "moe.held_share": 0.0,
-                      "moe.row_pass_share": 0.0}
+                      "moe.row_pass_share": 0.0,
+                      "moe.product_tile_share": 0.0}
     loss = float(step(ids, labels).asnumpy())
     assert np.isfinite(loss)
     step.sync_params_to_net()
@@ -873,6 +867,11 @@ def test_the_load_reads_back_after_a_step(stepped):
     # 384 sorted rows a layer are one chunk of the bounded passes: every
     # layer holds a row, so every row was visited
     assert got["moe.row_pass_share"] == gauges["moe.row_pass_share"] == 1.0
+    # ... and one row tile of the products, which every held expert that
+    # holds a row visits: a share of one visit for each
+    visits = sum(int((l[2:6] > 0).sum()) for l in loads) / len(loads)
+    assert got["moe.product_tile_share"] == pytest.approx(visits)
+    assert gauges["moe.product_tile_share"] == got["moe.product_tile_share"]
     # every trained leaf moved, the router among them
     names = [n for n, p in zip(step._names, step._plist)
              if p.grad_req != "null"]
@@ -903,3 +902,26 @@ def test_row_pass_share_counts_whole_chunks(rows, loads, want, monkeypatch):
     held = sum(sum(l[:2]) for l in loads) / 256
     assert got["moe.held_share"] == pytest.approx(held)
     assert held <= want
+
+
+@pytest.mark.parametrize("rows,loads,want", [
+    (32, [[40, 24, 0, 64], [0, 0, 0, 128]], (2 + 1 + 0) / 8),
+    (32, [[33, 0, 1, 94], [128, 0, 0, 0]], (2 + 4) / 8),
+    (48, [[33, 0, 1, 94], [50, 50, 0, 28]], (1 + 2 + 2) / 6)],
+    ids=["a_shared_tile", "an_empty_expert", "a_tile_that_does_not_divide"])
+def test_product_tile_share_counts_tile_visits(rows, loads, want,
+                                               monkeypatch):
+    """``moe.product_tile_share`` from the loads alone: each layer's held
+    experts (0 and 1 of 4) visit the row tiles their rows touch, a tile two
+    of them share once for each, an empty one none; over every layer's
+    tiles of its N x k buffer."""
+    from mxnet_tpu.ops.pallas import grouped_matmul
+    monkeypatch.setattr(grouped_matmul, "_ROW_TILE", rows)
+    net = gluon.nn.HybridSequential()
+    for load in loads:
+        block = parallel.DroplessMoEFFN(8, 4, 4, 2, held=2)
+        block.initialize()
+        block.load.set_data(NDArray(jnp.asarray(load, jnp.int32)))
+        net.add(block)
+    got = parallel.publish_load(net)
+    assert got["moe.product_tile_share"] == pytest.approx(want)

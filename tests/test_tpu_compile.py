@@ -150,15 +150,17 @@ def test_dropless_expert_layer_compiles_for_v5e(one_chip, no_compile_cache,
                                                 monkeypatch, router, config):
     """Routing (softmax, or sigmoid scores chosen by score + bias), sort, the
     two grouped products and their gradients at a cell's widths, one
-    sequence: ``ragged_dot`` has to have a lowering for the TPU forward and
-    for both gradients, and the row passes around it, whose trip count is
-    read on the device, have to stay loops or Mosaic kernels.  The bf16 router is the precision control of
+    sequence: Mosaic has to take the three grouped-product kernels at the
+    cell's widths and tiles, and the row passes around them, whose trip
+    count is read on the device, have to stay loops or Mosaic kernels.  The
+    bf16 router is the precision control of
     ``tests_tpu/test_moe_decoder_tpu.py`` and ``test_lfm2_moe_tpu.py``."""
     from mxnet_tpu.ops.registry import get_op
     from mxnet_tpu.parallel import moe
-    from mxnet_tpu.ops.pallas import gated_rows
+    from mxnet_tpu.ops.pallas import gated_rows, grouped_matmul
     monkeypatch.setattr(moe, "_ROUTER_DTYPE", router)
     monkeypatch.setattr(gated_rows, "on_tpu", lambda: True)
+    monkeypatch.setattr(grouped_matmul, "on_tpu", lambda: True)
     n = 8192
     d, f, held, routed, k, how = EXPERT_LAYERS[config]
     how = dict(how)
@@ -180,8 +182,54 @@ def test_dropless_expert_layer_compiles_for_v5e(one_chip, no_compile_cache,
     # (N, E, C) dispatch tensor: under 3 GB of temporaries
     assert compiled.memory_analysis().temp_size_in_bytes < 3e9
     text = compiled.as_text()
-    assert "ragged-dot" in text
+    assert "ragged-dot" not in text
+    for kernel in ("moe_grouped_fwd", "moe_grouped_dx", "moe_grouped_dw"):
+        assert text.count(f"/{kernel}/pallas_call") == 2, kernel
     # the bounded row passes: the gather of the output's gradient stays a
     # loop for the TPU; the stage between the products is its two kernels
     assert text.count(" while(") == 1
     assert "moe_gated_fwd" in text and "moe_gated_bwd" in text
+
+
+def test_expert_layers_share_one_lowering_a_kernel(one_chip, no_compile_cache,
+                                                  monkeypatch):
+    """Two recomputed expert layers at mellum2_12b_a2_5b's widths, forward
+    and backward, compiled for a v5e: their grouped products come to many
+    call sites (each layer's forward, its recomputation, the input and
+    weight gradients) but six distinct Mosaic payloads, two widths x
+    forward, input gradient and weight gradient (and the two of the
+    gated rows): what a process lowers, and what its executable holds
+    again at each site."""
+    import re
+
+    from mxnet_tpu.ops.registry import get_op
+    from mxnet_tpu.ops.pallas import gated_rows, grouped_matmul
+    monkeypatch.setattr(gated_rows, "on_tpu", lambda: True)
+    monkeypatch.setattr(grouped_matmul, "on_tpu", lambda: True)
+    n = 8192
+    d, f, held, routed, k, _ = EXPERT_LAYERS["mellum2_12b_a2_5b"]
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=one_chip)
+
+    @jax.checkpoint
+    def layer(tokens, router, gate_up, down):
+        return get_op("moe_dropless_ffn")(tokens, router, gate_up, down,
+                                          num_experts=routed, k=k)[0]
+
+    def total(tokens, router, gate_up, down):
+        for _ in range(2):
+            tokens = tokens + layer(tokens, router, gate_up, down)
+        return tokens.astype(jnp.float32).sum()
+    lowered = jax.jit(jax.grad(total, range(4))).trace(
+        shape(n, d), shape(d, routed), shape(held, d, 2 * f),
+        shape(held, f, d)).lower(lowering_platforms=("tpu",))
+    payloads = re.findall(r'stablehlo\.custom_call @tpu_custom_call\(.*?'
+                          r'backend_config = "(.*?)"', lowered.as_text())
+    assert len(payloads) > len(set(payloads)) == 6 + 2
+    text = lowered.compile().as_text()
+    sites = re.findall(r'/(moe_grouped_\w+)/pallas_call', text)
+    assert sorted(set(sites)) == ["moe_grouped_dw", "moe_grouped_dx",
+                                  "moe_grouped_fwd"]
+    assert sites.count("moe_grouped_dx") == sites.count("moe_grouped_dw") \
+        == 2 * 2
