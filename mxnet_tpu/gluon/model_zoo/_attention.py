@@ -6,7 +6,10 @@ window's on a grid that walks the band and under names of their own
 (``window_attention_fwd`` / ``_bwd_dq`` / ``_bwd_dkv``), which picks its block
 itself because it pads what the block does not divide.  An optional RMSNorm
 over each head of Q and of K, then an optional rotary step on both, stand in
-front of either."""
+front of either.  ``_LatentAttention`` is multi-head latent attention in its
+training form: K and V made from a normed low-rank latent, a rotary slice of
+each head, and the causal flash kernels at a V head narrower than Q's and
+K's."""
 from __future__ import annotations
 
 import jax
@@ -84,3 +87,64 @@ class _Attention(HybridBlock):
                 window=self._window))
         return self.out_proj(_causal_attention(
             F, q, k, v, self._heads, self._kv_heads)), k, v
+
+
+class _LatentAttention(HybridBlock):
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434), the
+    training form, causal, no bias anywhere.  Per head ``h``:
+
+    - ``q_h = [q_nope_h; q_pe_h] = (W_q u)_h``, ``nope_dim + rope_dim`` wide;
+    - ``[c; k_pe] = W_kva u`` (``kv_rank + rope_dim``), ``c = RMSNorm(c)``
+      (a learned gain of ``kv_rank``, moments float32);
+      ``[k_nope_h; v_h] = (W_kvb c)_h`` (``nope_dim + v_dim``);
+    - ``q_pe_h`` and ``k_pe`` rotated by ``rope`` (a table of ``ops.rotary.
+      rope_frequencies`` for heads of ``rope_dim``), pairs of neighbours
+      (2i, 2i+1) turned together; ``k_pe`` is ONE head that every query
+      head reads;
+    - ``k_h = [k_nope_h; k_pe]``, ``o_h = softmax_causal(q_h k_h^T /
+      sqrt(nope_dim + rope_dim)) v_h`` through ``ops.flash_attention`` with
+      heads of ``nope_dim + rope_dim`` and ``v_dim``, V not padded;
+    - ``W_o [o_1 .. o_H]``.
+
+    Scopes: ``latent`` holds ``kv_a``, the latent norm and ``kv_b``;
+    ``rope`` both rotations.  Returns the output alone."""
+
+    def __init__(self, hidden, heads, kv_rank, nope_dim, rope_dim, v_dim,
+                 rope, eps, **kwargs):
+        super().__init__(**kwargs)
+        self._heads, self._rank = heads, kv_rank
+        self._nope, self._rope_dim, self._v = nope_dim, rope_dim, v_dim
+        self._rope = rope
+        with self.name_scope():
+            self.q = _dense(heads * (nope_dim + rope_dim), hidden)
+            self.kv_a = _dense(kv_rank + rope_dim, hidden)
+            self.kv_norm = RMSNorm(epsilon=eps, in_channels=kv_rank)
+            self.kv_b = _dense(heads * (nope_dim + v_dim), kv_rank)
+            self.out_proj = _dense(hidden, heads * v_dim)
+
+    def _rotate(self, F, x, heads):
+        inv_freq, factor = self._rope
+        return F.rotary_embedding(x, inv_freq=inv_freq, heads=heads,
+                                  factor=factor, interleaved=True)
+
+    def forward(self, u):
+        from ... import ndarray as F
+        b, t = u.shape[0], u.shape[1]
+        heads, nope, rope = self._heads, self._nope, self._rope_dim
+        q = self.q(u).reshape((b, t, heads, nope + rope))
+        q_nope, q_pe = F.split_v2(q, axis=-1, indices=(nope,))
+        with jax.named_scope("latent"):
+            c, k_pe = F.split_v2(self.kv_a(u), axis=-1, indices=(self._rank,))
+            kv = self.kv_b(self.kv_norm(c)).reshape(
+                (b, t, heads, nope + self._v))
+            k_nope, v = F.split_v2(kv, axis=-1, indices=(nope,))
+        with jax.named_scope("rope"):
+            q_pe = self._rotate(F, q_pe.reshape((b, t, heads * rope)), heads)
+            k_pe = self._rotate(F, k_pe, 1)
+        q = F.concat(q_nope, q_pe.reshape((b, t, heads, rope)), dim=-1)
+        k = F.concat(k_nope, F.broadcast_to(
+            k_pe.reshape((b, t, 1, rope)), shape=(b, t, heads, rope)), dim=-1)
+        width = heads * (nope + rope)
+        return self.out_proj(_causal_attention(
+            F, q.reshape((b, t, width)), k.reshape((b, t, width)),
+            v.reshape((b, t, heads * self._v)), heads, heads))

@@ -1,11 +1,14 @@
 """A sparse mixture-of-experts decoder whose layers are configuration: the
-mixer of a layer is window attention, full attention or a gated short
-convolution, its feed-forward an expert layer or a dense gated one.  Two
-published architectures run on it: Mellum2-12B-A2.5B-Instruct
+mixer of a layer is window attention, full attention, latent attention or a
+gated short convolution, its feed-forward an expert layer (with or without a
+shared expert beside it) or a dense gated one.  Three published
+architectures run on it: Mellum2-12B-A2.5B-Instruct
 (huggingface.co/JetBrains/Mellum2-12B-A2.5B-Instruct ``config.json``,
-``model_type`` ``mellum``; YaRN per Peng et al., arXiv:2309.00071) and
+``model_type`` ``mellum``; YaRN per Peng et al., arXiv:2309.00071),
 LFM2-8B-A1B (huggingface.co/LiquidAI/LFM2-8B-A1B ``config.json``,
-``model_type`` ``lfm2_moe``).  No reference analogue.
+``model_type`` ``lfm2_moe``) and Kanana-2-30B-A3B
+(huggingface.co/kakaocorp/kanana-2-30b-a3b-instruct-2601 ``config.json``,
+``model_type`` ``deepseek_v3``).  No reference analogue.
 
 Every layer i, pre-norm:  ``a = x + Op_i(RMSNorm(x));  x' = a +
 FF_i(RMSNorm(a))``; an RMSNorm after the last layer; logits in float32,
@@ -23,9 +26,17 @@ the embedding.
   conv(B * v)``, a causal depthwise convolution over time with ``conv_taps``
   taps, zeros before the start, no bias, no activation; ``W_out (C * z)``.
   No heads, no K/V, no rotary table.
+- ``latent``: multi-head latent attention (``_attention._LatentAttention``):
+  Q by one projection, K and V from a normed latent of ``kv_lora_rank``, a
+  rotary slice of ``qk_rope_head_dim`` on each Q head and on one K head that
+  all share (pairs of neighbours turned together), heads of
+  ``qk_nope_head_dim + qk_rope_head_dim`` for Q and K and of ``v_head_dim``
+  for V through the causal flash kernels.
 - ``sparse``: ``parallel.DroplessMoEFFN``: a router over all ``num_experts``
   (softmax, or sigmoid scores chosen by score + ``expert_bias``), top-k
-  re-normalised, gated SwiGLU experts, no shared expert, no bias.  The
+  re-normalised, gated SwiGLU experts, no bias; with ``shared_experts`` a
+  dense gated feed-forward of that width on the same normed input, added
+  beside the routed experts' part (scope ``shared_experts``).  The
   decoder is told which experts it holds (``first_expert``,
   ``held_experts``: one chip's share under expert parallelism) and computes
   their part of each layer's result; that partial result goes on to the next
@@ -45,15 +56,15 @@ import jax
 from ... import initializer as init_mod
 from ..block import HybridBlock
 from ..nn import Embedding, RMSNorm
-from ._attention import _Attention, _dense
+from ._attention import _Attention, _LatentAttention, _dense
 
 __all__ = ["MoEDecoder", "MoEDecoderLayer", "published_layers", "KINDS",
            "FEED_FORWARDS"]
 
-KINDS = ("window", "full", "conv")
+KINDS = ("window", "full", "conv", "latent")
 FEED_FORWARDS = ("sparse", "dense")
 _SCOPE = {"window": "window_attention", "full": "attention",
-          "conv": "short_conv"}
+          "conv": "short_conv", "latent": "attention"}
 PERIOD = ("window", "window", "window", "full")
 
 
@@ -107,11 +118,13 @@ class MoEDecoderLayer(HybridBlock):
     (``feed_forward``: a dict of ``DroplessMoEFFN``'s arguments for a sparse
     one, a width for a dense one), each behind its RMSNorm and added to the
     residual.  A ``sparse`` layer returns the residual and the expert layer's
-    assignments per expert, a dense one the residual alone."""
+    assignments per expert, a dense one the residual alone.  ``latent`` is
+    a dict of ``_LatentAttention``'s widths for a ``latent`` mixer;
+    ``shared`` the width of a sparse layer's shared expert (None: none)."""
 
     def __init__(self, index, kind, hidden, heads, kv_heads, head_dim,
                  window, eps, rope, feed_forward, qk_norm=None, conv_taps=3,
-                 **kwargs):
+                 latent=None, shared=None, **kwargs):
         super().__init__(**kwargs)
         from ...parallel.moe import DroplessMoEFFN
         if kind not in KINDS:
@@ -122,6 +135,9 @@ class MoEDecoderLayer(HybridBlock):
             self.norm1 = RMSNorm(epsilon=eps, in_channels=hidden)
             if kind == "conv":
                 self.mixer = _ShortConv(hidden, conv_taps)
+            elif kind == "latent":
+                self.mixer = _LatentAttention(hidden, heads, rope=rope,
+                                              eps=eps, **latent)
             else:
                 self.mixer = _Attention(
                     hidden, heads, kv_heads,
@@ -130,6 +146,8 @@ class MoEDecoderLayer(HybridBlock):
             self.norm2 = RMSNorm(epsilon=eps, in_channels=hidden)
             if isinstance(feed_forward, dict):
                 self.moe = DroplessMoEFFN(hidden, **feed_forward)
+                if shared:
+                    self.shared_experts = _GatedFFN(hidden, shared)
             else:
                 self.mlp = _GatedFFN(hidden, feed_forward)
 
@@ -146,7 +164,11 @@ class MoEDecoderLayer(HybridBlock):
                 with jax.named_scope("mlp"):
                     return x + self.mlp(self.norm2(x))
             with jax.named_scope("moe"):
-                out, load = self.moe(self.norm2(x))
+                u = self.norm2(x)
+                out, load = self.moe(u)
+            if "shared_experts" in self._children:
+                with jax.named_scope("shared_experts"):
+                    out = out + self.shared_experts(u)
             x = x + out
         return x, load
 
@@ -159,7 +181,13 @@ class MoEDecoder(HybridBlock):
     layer sparse), a dense one of ``intermediate_size``.  ``rope_parameters``
     maps an attention kind to its rotary entry (``{"rope_type": "default" |
     "yarn", "rope_theta": ..., ...}``); a kind without an entry gets no rotary
-    step, and ``conv`` takes none.  ``qk_norm`` puts an RMSNorm (eps
+    step, and ``conv`` takes none.  A ``latent`` layer takes the widths
+    ``kv_lora_rank``, ``qk_nope_head_dim``, ``qk_rope_head_dim`` and
+    ``v_head_dim`` (``num_key_value_heads`` and ``head_dim`` are not read
+    for it), its table is over ``qk_rope_head_dim`` and turns pairs of
+    neighbours.  ``shared_experts`` (a width, default none)
+    puts a dense gated feed-forward beside each sparse layer's experts.
+    ``qk_norm`` puts an RMSNorm (eps
     ``rms_norm_eps``) on every head of Q and K.  ``held_experts`` (default:
     all) from ``first_expert`` on are the experts this decoder holds of each
     layer's ``num_experts``; ``score_function``, ``use_expert_bias``,
@@ -177,7 +205,10 @@ class MoEDecoder(HybridBlock):
                  intermediate_size=None, conv_taps=3, qk_norm=False,
                  score_function="softmax", use_expert_bias=False,
                  norm_topk_eps=0.0, routed_scaling_factor=1.0,
-                 tie_head=False, prefix=None, params=None):
+                 tie_head=False, kv_lora_rank=None, qk_nope_head_dim=None,
+                 qk_rope_head_dim=None, v_head_dim=None,
+                 shared_experts=None, prefix=None,
+                 params=None):
         super().__init__(prefix=prefix, params=params)
         from ...ops.rotary import rope_frequencies
         layers = list(layers)
@@ -189,11 +220,21 @@ class MoEDecoder(HybridBlock):
                              f"{len(layers)} layers")
         if "dense" in mlp_layers and not intermediate_size:
             raise ValueError("a dense layer needs intermediate_size")
-        tables = {kind: rope_frequencies(entry, head_dim)
+        latent = None
+        if "latent" in layers:
+            latent = dict(kv_rank=kv_lora_rank, nope_dim=qk_nope_head_dim,
+                          rope_dim=qk_rope_head_dim, v_dim=v_head_dim)
+            if not all(latent.values()):
+                raise ValueError("a latent layer needs kv_lora_rank, "
+                                 "qk_nope_head_dim, qk_rope_head_dim and "
+                                 "v_head_dim")
+        tables = {kind: rope_frequencies(
+                      entry, qk_rope_head_dim if kind == "latent" else head_dim)
                   for kind, entry in (rope_parameters or {}).items()}
-        if set(tables) - {"window", "full"}:
+        if set(tables) - {"window", "full", "latent"}:
             raise ValueError(f"rope_parameters for {sorted(tables)}: the "
-                             f"kinds that rotate are 'window' and 'full'")
+                             f"kinds that rotate are 'window', 'full' and "
+                             f"'latent'")
         moe = dict(hidden_size=moe_intermediate_size, num_experts=num_experts,
                    k=num_experts_per_tok, held=held_experts,
                    first_expert=first_expert, renormalise=norm_topk_prob,
@@ -211,7 +252,8 @@ class MoEDecoder(HybridBlock):
                     rms_norm_eps, tables.get(kind),
                     moe if ff == "sparse" else intermediate_size,
                     qk_norm=rms_norm_eps if qk_norm else None,
-                    conv_taps=conv_taps, prefix=f"layer{i}_")
+                    conv_taps=conv_taps, latent=latent, shared=shared_experts,
+                    prefix=f"layer{i}_")
                 self.register_child(layer, f"layer{i}")
                 self.layers.append(layer)
             self.norm = RMSNorm(epsilon=rms_norm_eps, in_channels=hidden_size)

@@ -88,21 +88,22 @@ def _multi_head_attention(q, k, v, mask=None, heads=1, dropout=0.0,
 
 def _flash(q, k, v, heads, kv_heads, causal, block_q, block_k, dropout=0.0,
            seed=None, window=None):
-    """(B, S, H*D) projections, k and v (B, S, kv_heads*D; None: as many
-    as ``heads``), through the Pallas kernels, which take (B*H, S, D), and
-    back."""
+    """(B, S, H*D) projections, k (B, S, kv_heads*D; None: as many as
+    ``heads``) and v (B, S, kv_heads*D_v, its own head width), through the
+    Pallas kernels, which take (B*H, S, D), and back to (B, S, H*D_v)."""
     from .pallas import flash_attention
-    b, sq, hd = q.shape
-    d = hd // heads
+    b, sq, _ = q.shape
     kv_heads = heads if kv_heads is None else kv_heads
     def to_bhsd(x, h=heads):
+        d = x.shape[-1] // h
         return jnp.transpose(x.reshape(b, -1, h, d),
                              (0, 2, 1, 3)).reshape(b * h, -1, d)
     out = flash_attention(to_bhsd(q), to_bhsd(k, kv_heads),
                           to_bhsd(v, kv_heads), None, causal, block_q,
                           block_k, None, dropout, seed, window)
-    out = out.reshape(b, heads, sq, d)
-    return jnp.transpose(out, (0, 2, 1, 3)).reshape(b, sq, hd)
+    d_v = out.shape[-1]
+    out = out.reshape(b, heads, sq, d_v)
+    return jnp.transpose(out, (0, 2, 1, 3)).reshape(b, sq, heads * d_v)
 
 
 @register_op("flash_attention")
@@ -115,7 +116,9 @@ def _flash_attention_op(q, k, v, heads=1, causal=False, block_q=128,
     applies attention-probability dropout inside the kernel (training only),
     seeded from the framework RNG stream each call.  ``kv_heads`` < ``heads``
     is grouped-query attention: k and v are (B, S, kv_heads*D) and each
-    serves ``heads // kv_heads`` query heads."""
+    serves ``heads // kv_heads`` query heads.  v's head may be narrower or
+    wider than Q's and K's (latent attention's 128 beside 192): the output
+    is (B, S, heads*D_v), the scale ``1 / sqrt(D)`` of Q's head."""
     from .. import autograd as _autograd
     from .. import random as _random
     if training is None:

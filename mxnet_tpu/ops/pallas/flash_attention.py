@@ -307,6 +307,7 @@ def _name(kernel, window):
 def _flash_fwd(q, k, v, seed, scale, causal, block_q, block_k, interpret,
                dropout, window=None):
     bh, s, d = q.shape
+    d_v = v.shape[-1]
     block_q = min(block_q, s)
     block_k = min(block_k, s)
     assert s % block_q == 0 and s % block_k == 0, (s, block_q, block_k)
@@ -315,9 +316,13 @@ def _flash_fwd(q, k, v, seed, scale, causal, block_q, block_k, interpret,
     group, rest = divmod(bh, k.shape[0])
     assert rest == 0, (q.shape, k.shape)
     n_k, key_block, _, _ = _needed(causal, block_q, block_k, window, s)
-    kv_spec = pl.BlockSpec((1, block_k, d),
-                           lambda b, i, j: (b // group, key_block(i, j), 0))
-    q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
+
+    def kv_spec(width):
+        return pl.BlockSpec((1, block_k, width),
+                            lambda b, i, j: (b // group, key_block(i, j), 0))
+
+    def q_spec(width):
+        return pl.BlockSpec((1, block_q, width), lambda b, i, j: (b, i, 0))
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, block_q=block_q,
         block_k=block_k, n_k=n_k, dropout=dropout, window=window, seq=s)
@@ -326,19 +331,19 @@ def _flash_fwd(q, k, v, seed, scale, causal, block_q, block_k, interpret,
         grid=(bh, s // block_q, n_k),
         in_specs=[
             pl.BlockSpec((1,), lambda b, i, j: (0,)),
-            q_spec, kv_spec, kv_spec,
+            q_spec(d), kv_spec(d), kv_spec(d_v),
         ],
         out_specs=[
-            q_spec,
+            q_spec(d_v),
             _row_spec(block_q, lambda b, i, j: (b, i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((bh, s, d_v), q.dtype),
             jax.ShapeDtypeStruct((bh, s // block_q, 1, block_q),
                                  jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, d_v), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
@@ -436,6 +441,7 @@ def _dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _flash_bwd(q, k, v, seed, o, lse, do, scale, causal, block_q, block_k,
                interpret, dropout, window=None):
     bh, s, d = q.shape
+    d_v = v.shape[-1]
     block_q = min(block_q, s)
     block_k = min(block_k, s)
     group = bh // k.shape[0]
@@ -445,9 +451,12 @@ def _flash_bwd(q, k, v, seed, o, lse, do, scale, causal, block_q, block_k,
     lse = lse.reshape(bh, s // block_q, 1, block_q)
     delta = delta.reshape(bh, s // block_q, 1, block_q)
 
-    q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
-    kv_spec = pl.BlockSpec((1, block_k, d),
-                           lambda b, i, j: (b // group, key_block(i, j), 0))
+    def q_spec(width):
+        return pl.BlockSpec((1, block_q, width), lambda b, i, j: (b, i, 0))
+
+    def kv_spec(width):
+        return pl.BlockSpec((1, block_k, width),
+                            lambda b, i, j: (b // group, key_block(i, j), 0))
     row_spec = _row_spec(block_q, lambda b, i, j: (b, i, 0, 0))
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
@@ -455,8 +464,9 @@ def _flash_bwd(q, k, v, seed, o, lse, do, scale, causal, block_q, block_k,
                           dropout=dropout, window=window, seq=s),
         grid=(bh, s // block_q, n_k),
         in_specs=[pl.BlockSpec((1,), lambda b, i, j: (0,)),
-                  q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-        out_specs=q_spec,
+                  q_spec(d), kv_spec(d), kv_spec(d_v), q_spec(d_v), row_spec,
+                  row_spec],
+        out_specs=q_spec(d),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
                         pltpu.VMEM((block_q, _LANES), jnp.float32),
@@ -467,10 +477,13 @@ def _flash_bwd(q, k, v, seed, o, lse, do, scale, causal, block_q, block_k,
 
     # grouped-query heads: a K/V head's gradient is the sum over its group
     # of query heads, which the grid walks before it moves to the next block
-    q_spec = pl.BlockSpec(
-        (1, block_q, d),
-        lambda b, j, g, i: (b * group + g, query_block(i, j), 0))
-    kv_spec = pl.BlockSpec((1, block_k, d), lambda b, j, g, i: (b, j, 0))
+    def q_spec(width):
+        return pl.BlockSpec(
+            (1, block_q, width),
+            lambda b, j, g, i: (b * group + g, query_block(i, j), 0))
+
+    def kv_spec(width):
+        return pl.BlockSpec((1, block_k, width), lambda b, j, g, i: (b, j, 0))
     row_spec = _row_spec(
         block_q, lambda b, j, g, i: (b * group + g, query_block(i, j), 0, 0))
     dk, dv = pl.pallas_call(
@@ -479,12 +492,13 @@ def _flash_bwd(q, k, v, seed, o, lse, do, scale, causal, block_q, block_k,
                           n_q=n_q, dropout=dropout, window=window, seq=s),
         grid=(k.shape[0], s // block_k, group, n_q),
         in_specs=[pl.BlockSpec((1,), lambda b, j, g, i: (0,)),
-                  q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-        out_specs=[kv_spec, kv_spec],
+                  q_spec(d), kv_spec(d), kv_spec(d_v), q_spec(d_v), row_spec,
+                  row_spec],
+        out_specs=[kv_spec(d), kv_spec(d_v)],
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
+                        pltpu.VMEM((block_k, d_v), jnp.float32)],
         name=_name("bwd_dkv", window),
         interpret=interpret,
     )(seed, q, k, v, do, lse, delta)
@@ -505,10 +519,12 @@ def flash_attention(q, k, v, scale=None, causal=False, block_q=128,
                     window=None):
     """softmax(scale · Q Kᵀ [, causal]) V without materialising S×S.
 
-    q: (B*H, S, D); k, v the same, or (B*H_kv, S, D) for grouped-query
+    q: (B*H, S, D); k the same, or (B*H_kv, S, D) for grouped-query
     heads with H a multiple of H_kv: query head h reads K/V head
     h // (H / H_kv) through the kernels' index maps, and nothing is repeated
-    in memory.  ``dropout`` applies attention-probability dropout
+    in memory.  v is k's shape but for its head, ``D_v``, which may differ
+    from Q's and K's ``D`` (latent attention: 192 / 128); the output is
+    (B*H, S, D_v), and the scale defaults to ``1 / sqrt(D)``.  ``dropout`` applies attention-probability dropout
     inside the kernel (the mask is regenerated from a counter-based hash in
     forward AND backward — never stored).  ``seed`` may be a traced int32
     scalar so each training step draws a fresh mask without retracing.

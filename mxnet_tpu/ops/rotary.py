@@ -73,11 +73,14 @@ def rope_frequencies(parameters, dim):
 
 
 @register_op("rotary_embedding")
-def _rotary_embedding(data, inv_freq=(), heads=1, factor=1.0):
+def _rotary_embedding(data, inv_freq=(), heads=1, factor=1.0,
+                      interleaved=False):
     """``data`` (B, T, heads*D) at positions 0..T-1, each head rotated:
     ``x * cos + rotate_half(x) * sin`` with ``rotate_half([x1, x2]) = [-x2,
     x1]`` over the two halves of D, ``cos = factor * cos(t * [f, f])`` and
-    ``sin`` alike, ``f = inv_freq`` (D / 2 of them)."""
+    ``sin`` alike, ``f = inv_freq`` (D / 2 of them).  ``interleaved`` turns
+    the pairs of neighbours instead: dims ``(2i, 2i+1)`` by ``t * f[i]``,
+    each pair kept in its place (``rope_interleave``)."""
     b, t, hd = data.shape
     d = hd // heads
     if len(inv_freq) * 2 != d:
@@ -87,6 +90,12 @@ def _rotary_embedding(data, inv_freq=(), heads=1, factor=1.0):
         * jnp.asarray(inv_freq, _ANGLE_DTYPE)[None, :]            # (T, D/2)
     cos = (factor * jnp.cos(angles)).astype(data.dtype)[None, :, None, :]
     sin = (factor * jnp.sin(angles)).astype(data.dtype)[None, :, None, :]
+    if interleaved:
+        x = data.reshape(b, t, heads, d // 2, 2)
+        even, odd = x[..., 0], x[..., 1]
+        out = jnp.stack([even * cos - odd * sin, odd * cos + even * sin],
+                        axis=-1)
+        return out.reshape(b, t, hd)
     x = data.reshape(b, t, heads, d)
     x1, x2 = x[..., :d // 2], x[..., d // 2:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)

@@ -507,3 +507,60 @@ def test_flash_window_needs_causal_and_a_key():
         flash_attention(q, q, q, window=8)
     with pytest.raises(ValueError, match="window"):
         flash_attention(q, q, q, causal=True, window=0)
+
+
+# ---------------------------------------------------------------------------
+# a V head of its own width (latent attention: Q and K heads of 192, V and
+# the output of 128): the forward's V block, output and accumulator and the
+# dk/dv kernel's dv are D_v wide, Q, K, dq and dk D wide
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("d,d_v,heads,kv_heads,s,block", [
+    (192, 128, 2, 2, 128, 64), (24, 8, 4, 2, 64, 32), (8, 24, 2, 1, 64, 16)],
+    ids=["latent_192_128", "narrow_24_8_grouped", "wider_v_8_24"])
+def test_flash_v_head_of_its_own_width(d, d_v, heads, kv_heads, s, block):
+    """Causal softmax attention with V narrower (or wider) than Q and K,
+    against the dense float32 formulation: output and all three gradients,
+    each in its own width; the scale is 1/sqrt of Q's head."""
+    import jax.numpy as jnp
+    from mxnet_tpu.ops.pallas.flash_attention import flash_attention
+    rng = np.random.RandomState(12)
+    q = jnp.asarray(rng.randn(heads, s, d) * 0.5, jnp.float32)
+    k = jnp.asarray(rng.randn(kv_heads, s, d) * 0.5, jnp.float32)
+    v = jnp.asarray(rng.randn(kv_heads, s, d_v) * 0.5, jnp.float32)
+    w = jnp.asarray(rng.randn(heads, s, d_v), jnp.float32)
+    got = _out_and_grads(
+        lambda *a: flash_attention(*a, causal=True, block_q=block,
+                                   block_k=block), q, k, v, w)
+    want = _out_and_grads(lambda *a: _dense_f32(*a, True), q, k, v, w)
+    assert got["out"].shape == (heads, s, d_v)
+    assert (got["dq"].shape, got["dk"].shape, got["dv"].shape) == (
+        q.shape, k.shape, v.shape)
+    for name in got:
+        np.testing.assert_allclose(got[name], want[name],
+                                   **_tol(2e-4, 2e-5, 1e-2), err_msg=name)
+
+
+def test_flash_op_takes_a_v_head_of_its_own_width():
+    """The op on (B, S, H*D) projections: V as (B, S, H*D_v), the output
+    (B, S, H*D_v), equal to the kernels' call on the heads laid out."""
+    import jax.numpy as jnp
+    from mxnet_tpu.ops.pallas.flash_attention import flash_attention
+    from mxnet_tpu.ops.registry import get_op
+    b, s, heads, d, d_v = 2, 64, 3, 24, 16
+    rng = np.random.RandomState(13)
+    q, k = (jnp.asarray(rng.randn(b, s, heads * d), jnp.float32)
+            for _ in range(2))
+    v = jnp.asarray(rng.randn(b, s, heads * d_v), jnp.float32)
+    got = get_op("flash_attention")(q, k, v, heads=heads, causal=True,
+                                    block_q=32, block_k=32)
+    assert got.shape == (b, s, heads * d_v)
+
+    def heads_first(x, width):
+        return x.reshape(b, s, heads, width).transpose(0, 2, 1, 3).reshape(
+            b * heads, s, width)
+    want = flash_attention(heads_first(q, d), heads_first(k, d),
+                           heads_first(v, d_v), causal=True, block_q=32,
+                           block_k=32)
+    want = want.reshape(b, heads, s, d_v).transpose(0, 2, 1, 3).reshape(
+        b, s, heads * d_v)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

@@ -31,6 +31,7 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 from chipbench import manifest                                  # noqa: E402
+from chipbench.families import deepseek_v3 as kanana2_family    # noqa: E402
 from chipbench.families import lfm2_moe as lfm2_family          # noqa: E402
 from chipbench.families import moe_decoder as family            # noqa: E402
 from chipbench.reference import moe_decoder as reference        # noqa: E402
@@ -72,11 +73,32 @@ LFM2_TOY = dict(layers=["conv", "full", "conv", "conv", "conv"],
                 routed_scaling_factor=1, use_expert_bias=True, conv_L_cache=3,
                 norm_eps=1e-5, rope_theta=1e6, embedding_std=0.5,
                 expert_bias_std=0.1)
-# (family, configuration, cell, toy model) of each configuration the
-# benchmark runs on this decoder
+# the third (``test_kanana2.py`` has its own cases): latent attention in
+# every layer, a leading dense layer, 8 experts of which 2 are held (2 and
+# 3), top-3 of sigmoid score + bias, a shared expert of 2 x 16; heads of 16 +
+# 8 for Q and K and of 16 for V, a latent of 16
+KANANA2_CONFIG = manifest.load_json(ROOT,
+                                    "chipbench/configs/kanana2_30b_a3b.json")
+KANANA2_CELL = "kanana2_30b_a3b.train_s8192"
+KANANA2_TOY = dict(
+    layers=["latent"] * 3, mlp_layers=["dense", "sparse", "sparse"],
+    num_experts=2, first_expert=2, routed_experts=8, vocab_size=128,
+    sequence_length=64, hidden_size=64, num_attention_heads=4, head_dim=8,
+    kv_lora_rank=16, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+    intermediate_size=48, moe_intermediate_size=16, n_shared_experts=2,
+    num_experts_per_tok=3, norm_topk_prob=True, routed_scaling_factor=2.448,
+    scoring_func="sigmoid", topk_method="noaux_tc", n_group=1, topk_group=1,
+    rms_norm_eps=1e-6, rope_theta=1e6, rope_interleave=True,
+    embedding_std=0.5, expert_bias_std=0.1)
+# (family, configuration, cell, toy model, how much more than its share the
+# cell's attention sees, in words and in the entry's short form) of each
+# configuration the benchmark runs on this decoder
 ON_THIS_DECODER = {
-    "mellum2_12b_a2_5b": (family, CONFIG, CELL, TOY),
-    "lfm2_8b_a1b": (lfm2_family, LFM2_CONFIG, LFM2_CELL, LFM2_TOY)}
+    "mellum2_12b_a2_5b": (family, CONFIG, CELL, TOY, "four times", "4x"),
+    "lfm2_8b_a1b": (lfm2_family, LFM2_CONFIG, LFM2_CELL, LFM2_TOY,
+                    "four times", "4x"),
+    "kanana2_30b_a3b": (kanana2_family, KANANA2_CONFIG, KANANA2_CELL,
+                        KANANA2_TOY, "eight times", "8x")}
 
 
 def _batch(seed=0, n=2, t=T):
@@ -531,6 +553,48 @@ def test_rotary_keeps_position_0_and_scores_depend_on_distance(table):
         get_op("rotary_embedding")(x, inv_freq=f[:4], heads=3)
 
 
+@pytest.mark.parametrize("heads,d", [(3, 16), (1, 64)])
+def test_rotary_interleaved_turns_neighbouring_pairs(heads, d):
+    """``interleaved=True``: dims (2i, 2i+1) of each head turned by ``t *
+    f[i]`` in their place, against the explicit rotation of every pair in
+    numpy; position 0 as it came; the default is the rotate-half program,
+    unchanged by the new argument."""
+    f, m = rope_frequencies(PLAIN, d)
+    rng = np.random.RandomState(3)
+    x = jnp.asarray(rng.randn(2, 20, heads * d), jnp.float32)
+    got = np.asarray(get_op("rotary_embedding")(x, inv_freq=f, heads=heads,
+                                                interleaved=True))
+    want = np.asarray(x).reshape(2, 20, heads, d).astype(np.float64)
+    out = np.empty_like(want)
+    for t in range(20):
+        for i in range(d // 2):
+            c, s = np.cos(t * np.float32(f[i])), np.sin(t * np.float32(f[i]))
+            a, b = want[:, t, :, 2 * i], want[:, t, :, 2 * i + 1]
+            out[:, t, :, 2 * i] = a * c - b * s
+            out[:, t, :, 2 * i + 1] = a * s + b * c
+    np.testing.assert_allclose(got, out.reshape(got.shape), atol=2e-5)
+    np.testing.assert_array_equal(got[:, 0], np.asarray(x)[:, 0])
+    # the pairs keep their lengths
+    pairs = got.reshape(2, 20, heads, d // 2, 2)
+    np.testing.assert_allclose((pairs ** 2).sum(-1), (np.asarray(x).reshape(
+        pairs.shape) ** 2).sum(-1), rtol=1e-5)
+    # and the rotate-half program stays as it was
+    plain = [str(jax.make_jaxpr(lambda a: get_op("rotary_embedding")(
+        a, inv_freq=f, heads=heads, **kw))(x)) for kw in ({}, {
+            "interleaved": False})]
+    assert plain[0] == plain[1]
+    np.testing.assert_allclose(
+        get_op("rotary_embedding")(x, inv_freq=f, heads=heads),
+        reference._rotate(x.reshape(2, 20, heads, d),
+                          reference.rope_table(PLAIN, d)).reshape(x.shape),
+        atol=1e-6)
+    from chipbench.reference import deepseek_v3
+    np.testing.assert_allclose(
+        got, deepseek_v3.rotate_pairs(x.reshape(2, 20, heads, d),
+                                      PLAIN["rope_theta"]).reshape(x.shape),
+        atol=2e-5)
+
+
 def test_the_window_counts_1024_keys_with_the_query():
     """Uniform scores, a value that is 1 at key 0 alone: query t reads 1 /
     (keys it sees) while key 0 is among them: 1,024 at t = 1,023, and
@@ -667,7 +731,7 @@ def toy_benchmark(tmp_path, request):
     """A throw-away benchmark holding one configuration and its cell at toy
     sizes, added as files and entries beside none."""
     name = request.param
-    _, config, cell, toy = ON_THIS_DECODER[name]
+    _, config, cell, toy, _, _ = ON_THIS_DECODER[name]
     root, src = str(tmp_path), os.path.join(ROOT, "chipbench")
     real = manifest.load(ROOT)
     for d in ("configs", "workloads", "layer_metrics"):
@@ -722,7 +786,7 @@ def test_family_rehearsed_through_the_benchmark(toy_benchmark, capsys, trace):
 
 @pytest.mark.parametrize("name", sorted(ON_THIS_DECODER))
 def test_the_real_benchmark_holds_the_cell(name):
-    family_, config, cell, _ = ON_THIS_DECODER[name]
+    family_, config, cell, _, words, short = ON_THIS_DECODER[name]
     real = manifest.load(ROOT)
     assert manifest.validate(real, ROOT) == []
     cells = [w["name"] for w in real["workloads"]]
@@ -737,19 +801,19 @@ def test_the_real_benchmark_holds_the_cell(name):
     assert {m["name"] for m in view["per_layer"]} >= {
         "compile_ms_total", "step_ms_p50", "mfu_pct", "device_idle_pct.train"}
     entry = real["workloads"][cells.index(cell)]
-    assert len(entry["why"]) <= 200 and "four times" in view["wl"]["why"]
-    assert "4x" in entry["why"]
+    assert len(entry["why"]) <= 200 and words in view["wl"]["why"]
+    assert short in entry["why"]
 
 
 @pytest.mark.parametrize("name", sorted(ON_THIS_DECODER))
 def test_family_draws_its_batch_from_the_seed(name):
-    family_, _, _, toy = ON_THIS_DECODER[name]
+    family_, _, _, toy, _, _ = ON_THIS_DECODER[name]
     _, _, batch = family_.build(dict(toy, sequence_length=64))
     (a,), (la,) = batch(np.random.default_rng(5), 3)
     (b,), _ = batch(np.random.default_rng(5), 3)
     (c,), _ = batch(np.random.default_rng(6), 3)
     assert a.shape == la.shape == (3, 64) and a.dtype == la.dtype == np.int32
-    assert 0 <= a.min() and a.max() < VOCAB
+    assert 0 <= a.min() and a.max() < toy["vocab_size"]
     assert np.array_equal(a, b) and not np.array_equal(a, c)
     assert np.array_equal(la[:, :-1], a[:, 1:])
     made, = family_.check_labels(NDArray(jnp.eye(4)[None]))

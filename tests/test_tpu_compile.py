@@ -101,6 +101,35 @@ def test_flash_kernels_compile_for_v5e_at_heads_of_128(one_chip,
         assert kernel in text
 
 
+# kanana2_30b_a3b.train_s8192: latent attention, 32 heads of 192 for Q and K
+# and of 128 for V over 8,192 positions
+def test_flash_kernels_compile_for_v5e_with_a_v_head_of_128_beside_192(
+        one_chip, no_compile_cache):
+    """The three causal kernels with Q and K heads of 192, which are not a
+    multiple of the 128 lanes, and V and output heads of 128, unpadded, at
+    the block the decoders give, bf16."""
+    from mxnet_tpu.gluon.model_zoo._attention import _flash_block
+    from mxnet_tpu.ops.pallas import flash_attention
+    t, block = 8192, _flash_block(8192)
+
+    def shape(d):
+        return jax.ShapeDtypeStruct((32, t, d), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def total(q, k, v):
+        return flash_attention(
+            q, k, v, causal=True, block_q=block, block_k=block,
+            interpret=False).astype(jnp.float32).sum()
+    compiled = jax.jit(jax.grad(total, range(3))).trace(
+        shape(192), shape(192), shape(128)).lower(
+        lowering_platforms=("tpu",)).compile()
+    text = compiled.as_text()
+    for kernel in ("flash_attention_fwd", "flash_attention_bwd_dq",
+                   "flash_attention_bwd_dkv"):
+        assert kernel in text
+    assert "bf16[32,8192,128]" in text and "bf16[32,8192,192]" in text
+
+
 # the two decoders' window layers: mellum2_12b_a2_5b.train_s8192 (32 query and
 # 4 K/V heads of 128, a window of 1,024 over 8,192 positions) and
 # phi4_mini_flash.train_s4096 (40 and 20 heads of 64, 512 over 4,096)
@@ -135,12 +164,15 @@ def test_window_kernels_compile_for_v5e(one_chip, no_compile_cache, heads,
     assert "flash_attention_" not in text
 
 
-# (d, F, held, routed, k, the router's own arguments) of the two cells that
-# run the dropless expert layer
+# (d, F, held, routed, k, the router's own arguments) of the cells that run
+# the dropless expert layer
 EXPERT_LAYERS = {
     "mellum2_12b_a2_5b": (2304, 896, 16, 64, 8, {}),
     "lfm2_8b_a1b": (2048, 1792, 8, 32, 4,
-                    {"score": "sigmoid", "eps": 1e-6, "bias": True})}
+                    {"score": "sigmoid", "eps": 1e-6, "bias": True}),
+    "kanana2_30b_a3b": (2048, 768, 16, 128, 6,
+                        {"score": "sigmoid", "eps": 1e-20, "scale": 2.448,
+                         "bias": True})}
 
 
 @pytest.mark.parametrize("config", sorted(EXPERT_LAYERS))
