@@ -20,7 +20,9 @@ experts, no bias), and sums its experts' weighted results back per token.
 The products and their gradients are the Pallas kernels of
 ``ops/pallas/grouped_matmul.py`` (``moe_grouped_fwd`` / ``_dx`` / ``_dw``,
 PR 38; XLA's ``jax.lax.ragged_dot`` is no longer on the path), which walk the
-held rows' tiles by a map built once a layer from the experts' loads.  No
+held rows' tiles by a map built once a layer from the experts' loads; the
+token-side passes over the sorted rows are those of
+``ops/pallas/token_rows.py`` (``moe_token_sum`` / ``_dot``).  No
 capacity, no dropped token whatever the imbalance: the row buffer holds all
 ``N * k`` assignments.  What the absent experts would have added is left out;
 on one chip the layer runs without an exchange.  This is the path for a model
@@ -31,7 +33,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..ops.pallas import grouped_matmul
+from ..ops.pallas import grouped_matmul, token_rows
 from ..ops.pallas.gated_rows import gated_rows
 from ..ops.registry import register_op
 
@@ -253,63 +255,56 @@ def _row_chunk(m):
 
 
 @jax.custom_vjp
-def _dispatch_rows(tokens, order, back, total):
+def _dispatch_rows(tokens, order, tmap):
     """Each assignment's token, in sorted order: ``tokens[order // k]``, all
     N * k rows (a row past ``total`` holds the token of an assignment that no
-    held expert reads).  ``back`` (N, k) lists, token by token, the sorted
-    rows that read it: the backward pass is then a gather too (``dy[back]``
-    summed per token) where autodiff would scatter-add N * k rows; ``dy``'s
-    rows past ``total`` may hold anything, and the sum drops them."""
-    return tokens[order // back.shape[1]]
+    held expert reads).  ``tmap`` (a ``token_rows.TokenMap``) lists, token
+    by token, the sorted rows that read it: the backward pass is then the
+    kernel ``moe_token_sum`` with unit weights (each token's held rows of
+    ``dy`` summed) where autodiff would scatter-add N * k rows; ``dy``'s rows
+    past ``total`` may hold anything, and the kernel never reads them."""
+    return tokens[order // tmap.slots.shape[1]]
 
 
-def _dispatch_rows_fwd(tokens, order, back, total):
-    return _dispatch_rows(tokens, order, back, total), (back, total)
+def _dispatch_rows_fwd(tokens, order, tmap):
+    return _dispatch_rows(tokens, order, tmap), tmap
 
 
-def _dispatch_rows_bwd(kept, dy):
-    back, total = kept
-    held = jnp.where((back < total)[..., None], dy[back], 0)
-    return jnp.sum(held, axis=1).astype(dy.dtype), None, None, None
+def _dispatch_rows_bwd(tmap, dy):
+    k = tmap.slots.shape[1]
+    ones = jnp.ones((dy.shape[0] // k, k), jnp.float32)
+    return token_rows.moe_token_sum(dy, tmap, ones), None, None
 
 
 _dispatch_rows.defvjp(_dispatch_rows_fwd, _dispatch_rows_bwd)
 
 
-def _weigh(out, back, total, gates):
-    """(each token's rows of ``out`` below ``total`` weighed and summed,
-    those rows (N, k, d) with the others zeroed)."""
-    held = jnp.where((back < total)[..., None], out[back], 0)
-    weighed = jnp.sum(held.astype(jnp.float32) * gates[..., None], axis=1)
-    return weighed.astype(out.dtype), held
-
-
 @jax.custom_vjp
-def _combine_rows(out, gates, order, back, total):
+def _combine_rows(out, gates, order, tmap):
     """Each token's ``k`` sorted rows of ``out`` (N * k, d), those below
-    ``total`` alone (the others may hold anything: a mask drops them, not a
-    product with zero), weighed by ``gates`` (N, k) float32 and summed in
-    float32.  The backward pass writes the sorted rows below ``total``, each
-    its token's ``dy`` times its gate, as a loop over the ``ceil(total /
-    chunk)`` chunks that hold one (``total`` is a device value and the trip
-    count, so the work follows it while every shape stays static): zeros to
-    the end of the last such chunk and nothing past it, where the rows hold
-    anything, as the product's do."""
-    return _weigh(out, back, total, gates)[0]
+    ``total`` alone (the others may hold anything, and are never read),
+    weighed by ``gates`` (N, k) float32 and summed in float32: the kernel
+    ``moe_token_sum`` over ``tmap`` (a ``token_rows.TokenMap``).  The
+    backward pass gives the gates' gradient by the kernel ``moe_token_dot``
+    and writes the sorted rows below ``total``, each its token's ``dy``
+    times its gate, as a loop over the ``ceil(total / chunk)`` chunks that
+    hold one (``total`` is a device value and the trip count, so the work
+    follows it while every shape stays static): zeros to the end of the last
+    such chunk and nothing past it, where the rows hold anything, as the
+    product's do."""
+    return token_rows.moe_token_sum(out, tmap, gates)
 
 
-def _combine_rows_fwd(out, gates, order, back, total):
-    weighed, held = _weigh(out, back, total, gates)
-    return weighed, (held, gates, order, total)
+def _combine_rows_fwd(out, gates, order, tmap):
+    return _combine_rows(out, gates, order, tmap), (out, gates, order, tmap)
 
 
 def _combine_rows_bwd(kept, dy):
-    held, gates, order, total = kept
+    out, gates, order, tmap = kept
     m, k = order.shape[0], gates.shape[1]
     chunk = _row_chunk(m)
-    d_gates = jnp.sum(held.astype(jnp.float32)
-                      * dy.astype(jnp.float32)[:, None, :],
-                      axis=-1).astype(gates.dtype)
+    total = tmap.total[0]
+    d_gates = token_rows.moe_token_dot(out, tmap, dy).astype(gates.dtype)
 
     def trip(i, d_out):
         start = jnp.minimum(i * chunk, m - chunk)
@@ -324,10 +319,9 @@ def _combine_rows_bwd(kept, dy):
     # it lfm2_8b_a1b.train_s8192 peaks 0.42 GB lower): a buffer of zeros of
     # its own cost that step 3.9 GB of temporaries (9.28 against 5.41;
     # PERF.md 6, PR 34), and the rows past ``total`` need not be zero
-    held = jax.lax.optimization_barrier((held, d_gates))[0]
-    d_out = jax.lax.fori_loop(0, (total + chunk - 1) // chunk, trip,
-                              held.reshape(m, -1))
-    return d_out, d_gates, None, None, None
+    out = jax.lax.optimization_barrier((out, d_gates))[0]
+    d_out = jax.lax.fori_loop(0, (total + chunk - 1) // chunk, trip, out)
+    return d_out, d_gates, None, None
 
 
 _combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
@@ -363,9 +357,11 @@ def _moe_dropless_ffn(tokens, router, gate_up, down, bias=None, num_experts=1,
     what the buffer held before; the grouped products leave such rows
     unwritten (NaN in the interpreter), as XLA:TPU's ``ragged_dot`` left
     garbage there before them.
-    The token-side sums that READ sorted rows (the weighted sum out, the
-    tokens' gradient) mask those rows before they sum: no row past ``total``
-    is ever read unmasked, in the backward pass too."""
+    The token-side passes that READ sorted rows (the weighted sum out, the
+    tokens' gradient, the gates' gradient) are the kernels of
+    ``ops/pallas/token_rows.py`` over ONE token map a layer: each block of
+    tokens fetches the 8-row chunks of its held rows and no row past
+    ``total``, and zeroes what a fetched chunk holds past it."""
     n = tokens.shape[0]
     held = gate_up.shape[0]
     with jax.named_scope("router"):
@@ -387,13 +383,14 @@ def _moe_dropless_ffn(tokens, router, gate_up, down, bias=None, num_experts=1,
         total = jnp.sum(sizes)
         groups = grouped_matmul.group_map(
             sizes, n * k, grouped_matmul.row_tile(n * k))
-        rows = _dispatch_rows(tokens, order, back, total)
+        tmap = token_rows.token_map(back, groups.offsets)
+        rows = _dispatch_rows(tokens, order, tmap)
     with jax.named_scope("experts"):
         hidden = gated_rows(grouped_matmul.grouped_matmul(
             rows, gate_up, groups), total)
         out = grouped_matmul.grouped_matmul(hidden, down, groups)
     with jax.named_scope("combine"):
-        out = _combine_rows(out, gates, order, back, total)
+        out = _combine_rows(out, gates, order, tmap)
     return out, load
 
 

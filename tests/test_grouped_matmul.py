@@ -14,7 +14,7 @@ import pytest
 import mxnet_tpu as mx
 from mxnet_tpu import parallel
 from mxnet_tpu.ops.pallas import grouped_matmul as gm
-from mxnet_tpu.ops.pallas import gated_rows, once
+from mxnet_tpu.ops.pallas import gated_rows, once, token_rows
 from mxnet_tpu.ops.registry import get_op
 from mxnet_tpu.parallel import moe
 
@@ -148,18 +148,19 @@ def test_grad_through_the_op_equals_the_parents_formulation(how, recomputed,
 @pytest.fixture
 def traces(monkeypatch):
     """Count each kernel's traces (its body reaching ``pallas_call``), the
-    grouped products' and the gated rows', with their jit caches and
-    ``once``'s emptied first and after."""
+    grouped products', the gated rows' and the token-side ones, with their
+    jit caches and ``once``'s emptied first and after."""
     names = []
     real = gm.pl.pallas_call
 
     def counted(*a, name=None, **kw):
         names.append(name)
         return real(*a, name=name, **kw)
-    for module in (gm, gated_rows):
+    for module in (gm, gated_rows, token_rows):
         monkeypatch.setattr(module, "pl", types.SimpleNamespace(
             **{**vars(gm.pl), "pallas_call": counted}))
-    jits = (gm._product, gm._weights, gm.group_map, gated_rows._call)
+    jits = (gm._product, gm._weights, gm.group_map, gated_rows._call,
+            token_rows._reduce, token_rows.token_map)
     for fn in jits:
         fn.clear_cache()
     once.traced.cache_clear()
@@ -178,8 +179,9 @@ def _step(net, loss_fn):
 def test_one_trace_and_one_lowering_per_distinct_kernel(traces):
     """One ``TrainStep`` of the toy decoder (two recomputed expert layers,
     bf16): deferred initialisation and the step trace each of the six
-    distinct kernels (two widths x forward, input gradient, weight gradient)
-    and the gated rows' two once, whichever layer, pass or recomputation
+    distinct kernels (two widths x forward, input gradient, weight gradient),
+    the gated rows' two and the token-side two (the unit-weight sum is the
+    weighted sum's kernel) once, whichever layer, pass or recomputation
     calls them (``ops/pallas/once.py``); a second step's trace adds none,
     and the step's program holds one function a kernel that every layer
     calls (the recomputed forward's copy of a forward kernel aside, which
@@ -193,10 +195,11 @@ def test_one_trace_and_one_lowering_per_distinct_kernel(traces):
     assert np.isfinite(float(_step(net, loss_fn)(ids, labels).asnumpy()))
     assert sorted(traces) == sorted(
         2 * ["moe_grouped_fwd", "moe_grouped_dx", "moe_grouped_dw"]
-        + ["moe_gated_fwd", "moe_gated_bwd"])
+        + ["moe_gated_fwd", "moe_gated_bwd", "moe_token_sum",
+           "moe_token_dot"])
     again = _step(net, loss_fn)
     assert np.isfinite(float(again(ids, labels).asnumpy()))
-    assert len(traces) == 8, traces[8:]
+    assert len(traces) == 10, traces[10:]
     text = again.lower(ids, labels).as_text()
     layers = len(model["layers"])
     funcs = re.findall(r"func\.func private @(_product|_weights)(_\d+)?\(",

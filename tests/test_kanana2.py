@@ -422,16 +422,20 @@ def test_kernel_roofline_reads_executions_by_name():
 
 
 def test_the_new_metrics_read_this_cell_alone():
-    """The three metrics this configuration brings are the last entries
-    and list its cell alone; the cell reports every accepted metric whose
-    selection it matches and not the two that read nothing in it."""
+    """The three metrics this configuration brings are appended together,
+    behind those it found, and list its cell alone; the cell reports every
+    accepted metric whose selection it matches and not the two that read
+    nothing in it."""
     real = manifest.load(ROOT)
     cell = "kanana2_30b_a3b.train_s8192"
-    assert [m["name"] for m in real["per_layer"][-3:]] == [
+    names = [m["name"] for m in real["per_layer"]]
+    at = names.index("latent_kv_ms")
+    brought = real["per_layer"][at:at + 3]
+    assert [m["name"] for m in brought] == [
         "latent_kv_ms", "shared_experts_ms", "attention_kernels_roofline_pct"]
+    assert names.index("moe_product_tile_pct") < at
     assert all(m["workloads"] == [cell] and m["layer"] == "model_ops"
-               and m["moves"] == "train_samples_per_s"
-               for m in real["per_layer"][-3:])
+               and m["moves"] == "train_samples_per_s" for m in brought)
     mine = {m["name"] for m in manifest.cell(real, ROOT, cell)["per_layer"]}
     assert mine >= {"mfu_pct", "attention_ms", "attention_kernels_ms",
                     "ffn_ms", "moe_rows_ms", "moe_product_kernels_ms",

@@ -867,8 +867,13 @@ def test_train_step_program_bounds_the_row_passes(monkeypatch):
     """The step's program for the toy decoder (384 sorted rows a layer, in
     chunks of 128): the gather of every expert layer's output gradient is a
     loop whose trip count is a value of the program, the stage between the
-    products is the two kernels that take that count, and no select runs
-    over a whole ``[N x k, d]`` buffer."""
+    products is the two kernels that take that count, no select runs over a
+    whole ``[N x k, d]`` buffer, and the token-side passes are the kernels
+    of ``ops/pallas/token_rows``: ``moe_token_sum`` under ``moe/combine`` in
+    the forward pass and under ``moe/dispatch`` in the backward pass,
+    ``moe_token_dot`` under ``moe/combine`` in the backward pass (the
+    recomputed forward has no sum: the backward pass keeps the rows, not the
+    sum), and no ``[N, k, d]`` tensor of gathered rows is left."""
     monkeypatch.setattr(moe, "_row_chunk", lambda m: min(m, 128))
     # the grouped products' tiles too: their interpreted bodies' masks are
     # selects over a tile, which must not be the whole buffer here
@@ -884,9 +889,12 @@ def test_train_step_program_bounds_the_row_passes(monkeypatch):
     paths = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
     # a while whose condition compares two carried values, not one against
     # a constant: the trip count is read on the device
+    # (the interpreted token kernels' own loops, outside the step's scopes,
+    # aside)
     dynamic = [paths[loc] for loc in re.findall(
         r'stablehlo\.while\([^\n]*\n\s*cond \{\n\s*%\d+ = stablehlo\.compare'
-        r'\s+LT, %iterArg\w*, %iterArg\w*,[^\n]*loc\((#loc\d+)\)', text)]
+        r'\s+LT, %iterArg\w*, %iterArg\w*,[^\n]*loc\((#loc\d+)\)', text)
+        if paths[loc].startswith("jit(")]
     rows, d = ids.size * TOY["num_experts_per_tok"], TOY["hidden_size"]
     assert rows == 384 and f"tensor<{rows}x{d}xbf16>" in text
     for i in range(len(CUT)):
@@ -903,6 +911,25 @@ def test_train_step_program_bounds_the_row_passes(monkeypatch):
     assert len(re.findall(r" call @_call\w*\(", text)) == 3 * len(CUT)
     assert not re.search(rf"stablehlo\.select [^\n]*tensor<{rows}x{d}xbf16>",
                          text)
+    # each call of the token kernels' jit, by the kernel its body runs and
+    # the scope it is called from
+    bodies = dict(re.findall(r"func\.func private @(_reduce\w*)\((.*?)\n  \}",
+                             text, re.S))
+    kernels = {f: {paths[loc].split("/")[0] for loc in re.findall(
+        r"loc\((#loc\d+)\)", body) if paths.get(loc, "").startswith(
+        "moe_token_")} for f, body in bodies.items()}
+    assert all(len(k) == 1 for k in kernels.values()), kernels
+    sites = sorted(
+        (min(kernels[f]), "transpose(" in paths[loc],
+         paths[loc].rsplit("/moe/", 1)[1])
+        for f, loc in re.findall(r" call @(_reduce\w*)\(.*loc\((#loc\d+)\)",
+                                 text))
+    assert sites == sorted(len(CUT) * [
+        ("moe_token_sum", False, "combine/jit(_reduce)"),
+        ("moe_token_sum", True, "dispatch/jit(_reduce)"),
+        ("moe_token_dot", True, "combine/jit(_reduce)")]), sites
+    n, k = ids.size, TOY["num_experts_per_tok"]
+    assert not re.search(rf"tensor<{n}x{k}x{d}x(bf16|f32)>", text)
 
 
 def test_the_load_reads_back_after_a_step(stepped):

@@ -189,10 +189,10 @@ def test_dropless_expert_layer_compiles_for_v5e(one_chip, no_compile_cache,
     ``tests_tpu/test_moe_decoder_tpu.py`` and ``test_lfm2_moe_tpu.py``."""
     from mxnet_tpu.ops.registry import get_op
     from mxnet_tpu.parallel import moe
-    from mxnet_tpu.ops.pallas import gated_rows, grouped_matmul
+    from mxnet_tpu.ops.pallas import gated_rows, grouped_matmul, token_rows
     monkeypatch.setattr(moe, "_ROUTER_DTYPE", router)
-    monkeypatch.setattr(gated_rows, "on_tpu", lambda: True)
-    monkeypatch.setattr(grouped_matmul, "on_tpu", lambda: True)
+    for module in (gated_rows, grouped_matmul, token_rows):
+        monkeypatch.setattr(module, "on_tpu", lambda: True)
     n = 8192
     d, f, held, routed, k, how = EXPERT_LAYERS[config]
     how = dict(how)
@@ -218,9 +218,14 @@ def test_dropless_expert_layer_compiles_for_v5e(one_chip, no_compile_cache,
     for kernel in ("moe_grouped_fwd", "moe_grouped_dx", "moe_grouped_dw"):
         assert text.count(f"/{kernel}/pallas_call") == 2, kernel
     # the bounded row passes: the gather of the output's gradient stays a
-    # loop for the TPU; the stage between the products is its two kernels
+    # loop for the TPU; the stage between the products is its two kernels,
+    # the token-side passes the two of ``token_rows``: the sum of the gather
+    # in's backward pass (the forward's is dead: a sum's gradient does not
+    # read its value) and the gates' gradient
     assert text.count(" while(") == 1
     assert "moe_gated_fwd" in text and "moe_gated_bwd" in text
+    assert text.count("/moe_token_sum/pallas_call") == 1
+    assert text.count("/moe_token_dot/pallas_call") == 1
 
 
 def test_expert_layers_share_one_lowering_a_kernel(one_chip, no_compile_cache,
@@ -230,14 +235,14 @@ def test_expert_layers_share_one_lowering_a_kernel(one_chip, no_compile_cache,
     call sites (each layer's forward, its recomputation, the input and
     weight gradients) but six distinct Mosaic payloads, two widths x
     forward, input gradient and weight gradient (and the two of the
-    gated rows): what a process lowers, and what its executable holds
-    again at each site."""
+    gated rows, and the token sum, weighted or not, and dot): what a process
+    lowers, and what its executable holds again at each site."""
     import re
 
     from mxnet_tpu.ops.registry import get_op
-    from mxnet_tpu.ops.pallas import gated_rows, grouped_matmul
-    monkeypatch.setattr(gated_rows, "on_tpu", lambda: True)
-    monkeypatch.setattr(grouped_matmul, "on_tpu", lambda: True)
+    from mxnet_tpu.ops.pallas import gated_rows, grouped_matmul, token_rows
+    for module in (gated_rows, grouped_matmul, token_rows):
+        monkeypatch.setattr(module, "on_tpu", lambda: True)
     n = 8192
     d, f, held, routed, k, _ = EXPERT_LAYERS["mellum2_12b_a2_5b"]
 
@@ -258,10 +263,45 @@ def test_expert_layers_share_one_lowering_a_kernel(one_chip, no_compile_cache,
         shape(held, f, d)).lower(lowering_platforms=("tpu",))
     payloads = re.findall(r'stablehlo\.custom_call @tpu_custom_call\(.*?'
                           r'backend_config = "(.*?)"', lowered.as_text())
-    assert len(payloads) > len(set(payloads)) == 6 + 2
+    assert len(payloads) > len(set(payloads)) == 6 + 2 + 2
     text = lowered.compile().as_text()
     sites = re.findall(r'/(moe_grouped_\w+)/pallas_call', text)
     assert sorted(set(sites)) == ["moe_grouped_dw", "moe_grouped_dx",
                                   "moe_grouped_fwd"]
     assert sites.count("moe_grouped_dx") == sites.count("moe_grouped_dw") \
         == 2 * 2
+
+
+# (N, k, d, held) of the three expert cells' steps: mellum2_12b_a2_5b and
+# kanana2_30b_a3b two sequences of 8,192, lfm2_8b_a1b four
+TOKEN_SIDE = {"mellum2_12b_a2_5b": (16384, 8, 2304, 16),
+              "lfm2_8b_a1b": (32768, 4, 2048, 8),
+              "kanana2_30b_a3b": (16384, 6, 2048, 16)}
+
+
+@pytest.mark.parametrize("config", sorted(TOKEN_SIDE))
+def test_token_kernels_compile_for_v5e(one_chip, no_compile_cache, config):
+    """``moe_token_sum`` and ``moe_token_dot`` and the token map at a cell's
+    step, bf16 rows of the N x k buffer in HBM: Mosaic has to take the
+    8-row chunk copies, the two sets of slots in VMEM and the products over
+    them."""
+    from mxnet_tpu.ops.pallas import token_rows
+    n, k, d, held = TOKEN_SIDE[config]
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def passes(rows, back, offsets, weights, dy):
+        tmap = token_rows.token_map(back, offsets)
+        return (token_rows._reduce(rows, tmap, weights, dot=False,
+                                   interpret=False),
+                token_rows._reduce(rows, tmap, dy, dot=True,
+                                   interpret=False))
+    compiled = jax.jit(passes).trace(
+        shape(n * k, d), shape(n, k, dtype=jnp.int32),
+        shape(held + 1, dtype=jnp.int32), shape(n, k, dtype=jnp.float32),
+        shape(n, d)).lower(lowering_platforms=("tpu",)).compile()
+    text = compiled.as_text()
+    assert "moe_token_sum" in text and "moe_token_dot" in text
+    # no [N, k, d] tensor and no gather of the rows
+    assert f"bf16[{n},{k},{d}]" not in text and " gather(" not in text
