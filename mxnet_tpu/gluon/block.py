@@ -16,6 +16,7 @@ flag is a static jit argument, so both modes get their own executable.
 """
 from __future__ import annotations
 
+import contextlib
 import re
 import threading
 from collections import OrderedDict
@@ -25,9 +26,12 @@ import numpy as np
 
 from .. import autograd as _autograd
 from .. import random as _random
+from .. import telemetry as _telemetry
 from ..base import dtype_np
 from ..context import current_context
 from ..ndarray import NDArray
+from ..ops.pallas.flash_attention import KEPT as _KEPT
+from ..ops.pallas.flash_attention import traced_bytes as _traced_bytes
 from . import parameter as _parameter
 from .parameter import DeferredInitializationError, Parameter, ParameterDict
 
@@ -163,10 +167,33 @@ def infer_shapes(block, *args):
                   and p._deferred_init is not None and p._shape_known()]
 
 
+# what a recomputed block keeps besides its inputs: the attention kernels'
+# output and log-sum-exp (a block without the kernels keeps its inputs alone)
+_KEEP = jax.checkpoint_policies.save_only_these_names(*_KEPT)
+
+
+@contextlib.contextmanager
+def publish_kept():
+    """Around one trace of a differentiated program: set the gauge
+    ``recompute.kept_attention_bytes`` to the bytes of attention-kernel
+    results that the recomputed blocks traced inside keep for the backward
+    pass.  Left alone where no recomputed block ran."""
+    outer = getattr(_naming, "kept", None)
+    _naming.kept = kept = []
+    try:
+        yield
+    finally:
+        _naming.kept = outer
+    if kept:
+        _telemetry.registry().gauge(
+            "recompute.kept_attention_bytes").set(sum(kept))
+
+
 def _recomputed(block, args):
     """``block(*args)`` under ``jax.checkpoint``: the backward pass keeps
-    the block's inputs and runs its forward again for everything else.  The
-    block's parameters enter as the traced values they already are."""
+    the block's inputs and its attention kernels' results (``_KEEP``) and
+    runs its forward again for everything else.  The block's parameters
+    enter as the traced values they already are."""
     leaves, tree = _flatten_nd(args)
     held = [(p, p._data._data) for p in block.collect_params().values()
             if p._data is not None]
@@ -184,7 +211,14 @@ def _recomputed(block, args):
         out_leaves, seen["out"] = _flatten_nd(out)
         return [o._data for o in out_leaves]
 
-    outs = jax.checkpoint(body)(*[l._data for l in leaves])
+    depth, before = getattr(_naming, "recomputing", 0), _traced_bytes()
+    _naming.recomputing = depth + 1
+    try:
+        outs = jax.checkpoint(body, policy=_KEEP)(*[l._data for l in leaves])
+    finally:
+        _naming.recomputing = depth
+    if depth == 0 and getattr(_naming, "kept", None) is not None:
+        _naming.kept.append(_traced_bytes() - before)
     return _unflatten_nd(seen["out"], tuple(NDArray(o) for o in outs))
 
 
@@ -311,8 +345,12 @@ class Block:
         """Trade compute for memory: wherever this block runs under a trace
         that is differentiated (``parallel.TrainStep``, a hybridized
         parent, ``jax.grad`` over ``functional_call``), keep only its
-        inputs for the backward pass and run its forward a second time
-        there (``jax.checkpoint``).  Values and gradients are unchanged.
+        inputs, and its attention kernels' results, for the backward pass and
+        run the rest of its forward a second time there (``jax.checkpoint``
+        with ``save_only_these_names`` on the flash kernels' ``out`` and
+        ``lse``: ``B*H*T*D_v`` values of the output's dtype and ``B*H*T``
+        float32 a call, so the kernel runs once a step).  Values and
+        gradients are unchanged.
         Marks this block alone: mark each layer of a stack to hold one
         layer's intermediates at a time.  A block whose forward rewrites
         aux state (BatchNorm) raises.  Eager calls are not affected (the

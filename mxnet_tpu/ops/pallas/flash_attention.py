@@ -48,9 +48,11 @@ where the causal kernels compute 136).
 from __future__ import annotations
 
 import functools
+import threading
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -506,6 +508,20 @@ def _flash_bwd(q, k, v, seed, o, lse, do, scale, causal, block_q, block_k,
 
 
 # ----------------------------------------------------------- public api -----
+# The forward rule tags the kernel's output and log-sum-exp with these names.
+# A recomputed block (``gluon.Block.recompute``) keeps what carries them for
+# its backward pass, which then runs the rest of the block's forward again
+# but not this kernel.  Outside a ``jax.checkpoint`` a name is the identity.
+KEPT = ("flash_attention.out", "flash_attention.lse")
+_traced = threading.local()
+
+
+def traced_bytes():
+    """The bytes of ``out`` and ``lse`` of every call made on this thread so
+    far: across the trace of a recomputed block, what that block keeps."""
+    return getattr(_traced, "bytes", 0)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
 def _flash_attention_core(q, k, v, seed, scale, causal, block_q, block_k,
                           interpret, dropout, window):
@@ -539,8 +555,11 @@ def flash_attention(q, k, v, scale=None, causal=False, block_q=128,
         seed = jnp.zeros((1,), jnp.int32)
     else:
         seed = jnp.asarray(seed, jnp.int32).reshape((1,))
-    return _flash_attention_core(q, k, v, seed, scale, causal, block_q,
-                                 block_k, interpret, dropout, window)
+    out = _flash_attention_core(q, k, v, seed, scale, causal, block_q,
+                                block_k, interpret, dropout, window)
+    _traced.bytes = traced_bytes() + out.size * out.dtype.itemsize \
+        + out.shape[0] * out.shape[1] * 4          # lse: float32 (B*H, S)
+    return out
 
 
 def _resolve(scale, d, interpret):
@@ -556,6 +575,9 @@ def _flash_fwd_rule(q, k, v, seed, scale, causal, block_q, block_k,
     scale, interpret = _resolve(scale, q.shape[-1], interpret)
     out, lse = _flash_fwd(q, k, v, seed, scale, causal, block_q, block_k,
                           interpret, float(dropout), window)
+    # the primal output carries the name too: the output projection's
+    # gradient reads it, and an untagged copy would run the kernel again
+    out, lse = checkpoint_name(out, KEPT[0]), checkpoint_name(lse, KEPT[1])
     return out, (q, k, v, seed, out, lse)
 
 

@@ -32,7 +32,8 @@ from .. import profiler as _profiler
 from .. import telemetry as _telemetry
 from ..profiler import scope as _pscope
 from ..ndarray import NDArray
-from ..gluon.block import _flatten_nd, _unflatten_nd, infer_shapes
+from ..gluon.block import (_flatten_nd, _unflatten_nd, infer_shapes,
+                           publish_kept)
 from ..gluon.parameter import _run_program, materialize
 from .mesh import MeshScope, default_mesh
 from .sharding import ShardingRules, batch_spec, param_sharding
@@ -346,7 +347,8 @@ class TrainStep:
                 # device-side names: jax writes the forward pass's ops as
                 # ``jvp(forward)/...`` and their transposes, the backward
                 # pass, as ``transpose(jvp(forward))/...``
-                return jax.value_and_grad(loss_of, has_aux=True)(ta_in)
+                with publish_kept():
+                    return jax.value_and_grad(loss_of, has_aux=True)(ta_in)
 
             if self._grad_reduce == "f32":
                 # implicit path: grads of the sharded-batch loss — the

@@ -15,11 +15,12 @@ import numpy as np
 import pytest
 
 import mxnet_tpu as mx
-from mxnet_tpu import parallel
+from mxnet_tpu import parallel, telemetry
 from mxnet_tpu.gluon.model_zoo import moe_decoder
 
 from test_moe_decoder import (KANANA2_CONFIG as CONFIG, KANANA2_TOY as TOY,
-                              ROOT, _loss_and_grads, _one_device, _worst)
+                              ROOT, _forward_kernel_calls, _loss_and_grads,
+                              _one_device, _worst)
 
 from chipbench import manifest                                  # noqa: E402
 from chipbench.families import deepseek_v3 as family            # noqa: E402
@@ -104,9 +105,13 @@ def test_every_gradient_leaf_matches_the_reference(cut):
 def test_recomputed_layers_give_equal_gradients(cut):
     _, ids, (loss, grads), _ = cut
     _, labels = _batch()
-    again, marked = _loss_and_grads(_net(recompute=True), ids, labels)
+    again, marked, text = _loss_and_grads(_net(recompute=True), ids, labels,
+                                          program=True)
     assert again == pytest.approx(loss, rel=1e-6)
     assert _worst(marked, grads) < 1e-6
+    # the marked layers keep their attention kernels' output and
+    # log-sum-exp: the forward kernel runs once a layer
+    assert _forward_kernel_calls(text) == len(TOY["layers"])
 
 
 def test_latent_layers_need_their_widths_and_the_router_its_own():
@@ -242,6 +247,47 @@ def test_train_step_program_names_every_new_scope(stepped):
     # a shared jitted kernel first in this process)
     ops = "\n".join(l for l in text.splitlines() if not l.startswith("#loc"))
     assert "window_attention" not in ops
+
+
+def test_a_recomputed_step_runs_the_attention_forward_once_a_layer():
+    """One ``TrainStep`` of the toy, every layer recomputed: each latent
+    layer calls the flash forward once (the recomputation keeps its output
+    and log-sum-exp) and the backward kernels once, a recomputed expert
+    layer still runs its grouped-product forward twice, and the gauge holds
+    the bytes kept: ``o`` (bf16, B*H x T x D_v) and ``lse`` (float32,
+    B*H x T) a layer."""
+    telemetry.registry().remove("recompute.kept_attention_bytes")
+    net, loss_fn, batch = family.build(TOY)
+    mx.random.seed(1)
+    net.initialize()
+    net.cast("bfloat16")
+    step = parallel.TrainStep(
+        net, loss_fn, mx.optimizer.create("adamw", learning_rate=1e-3),
+        mesh=_one_device())
+    (ids,), (labels,) = batch(np.random.default_rng(0), 2)
+    text = step.lower(ids, labels).as_text()
+    layers = len(TOY["layers"])
+
+    def lowered(f):
+        return re.findall(rf"func\.func private @{f}(?:_\d+)?\(", text)
+
+    def calls(f):
+        return len(re.findall(rf" call @{f}(?:_\d+)?\(", text))
+    # one lowering of each flash jit, each called once a layer: _flash_bwd
+    # holds both backward kernels (_bwd_dq, _bwd_dkv)
+    assert (len(lowered("_flash_fwd")), len(lowered("_flash_bwd"))) == (1, 1)
+    assert calls("_flash_fwd") == _forward_kernel_calls(text) == layers
+    assert calls("_flash_bwd") == layers
+    # the expert layers recompute as before: the two products' forward in
+    # the forward pass and again in the recomputed one, their input
+    # gradients; the weight gradients
+    sparse = TOY["mlp_layers"].count("sparse")
+    assert (len(lowered("_product")), len(lowered("_weights"))) == (6, 2)
+    assert (calls("_product"), calls("_weights")) == (6 * sparse, 2 * sparse)
+    heads, d_v = TOY["num_attention_heads"], TOY["v_head_dim"]
+    rows = ids.shape[0] * heads * T
+    assert telemetry.registry().get("recompute.kept_attention_bytes").value \
+        == layers * (rows * d_v * 2 + rows * 4) == 55296
 
 
 def test_a_step_trains_and_leaves_the_bias(stepped):

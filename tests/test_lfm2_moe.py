@@ -23,8 +23,8 @@ from mxnet_tpu.ops.rotary import rope_frequencies
 from mxnet_tpu.parallel import moe
 
 from test_moe_decoder import (LFM2_CONFIG as CONFIG, LFM2_TOY as TOY, ROOT,
-                              _batch, _loss_and_grads, _one_device, _worst,
-                              ragged_grouped_matmul)
+                              _batch, _forward_kernel_calls, _loss_and_grads,
+                              _one_device, _worst, ragged_grouped_matmul)
 
 from chipbench import manifest                                  # noqa: E402
 from chipbench.families import lfm2_moe as family               # noqa: E402
@@ -115,9 +115,12 @@ def test_each_kind_of_layer_alone(kind, ff):
 def test_recomputed_layers_give_equal_gradients(cut):
     _, ids, (loss, grads), _ = cut
     _, labels = _batch()
-    again, marked = _loss_and_grads(_net(recompute=True), ids, labels)
+    again, marked, text = _loss_and_grads(_net(recompute=True), ids, labels,
+                                          program=True)
     assert again == loss
     assert _worst(marked, grads) < 1e-6
+    # the one attention layer keeps its kernel's results: one forward call
+    assert _forward_kernel_calls(text) == CUT.count("full") == 1
 
 
 @pytest.mark.parametrize("rows", [None, 100], ids=["one_chunk",

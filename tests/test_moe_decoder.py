@@ -116,10 +116,11 @@ def _net(model=TOY, seed=3, recompute=False):
     return net
 
 
-def _loss_and_grads(net, ids, labels):
+def _loss_and_grads(net, ids, labels, program=False):
     """The net's loss and gradients as ``TrainStep`` takes them: ``jax.grad``
     over ``functional_call``.  Gradients come back under the reference's
-    structural names, the trained leaves alone."""
+    structural names, the trained leaves alone; with ``program`` the
+    gradient's lowered text comes back third."""
     names, plist, arrays = param_names_and_values(net)
     structural = {p.name: n
                   for n, p in net._collect_params_with_prefix().items()}
@@ -137,11 +138,18 @@ def _loss_and_grads(net, ids, labels):
         return jnp.mean(loss_fn(NDArray(outs[0]),
                                 NDArray(jnp.asarray(labels)))._data)
 
+    fn, x = jax.jit(jax.value_and_grad(loss_of)), [arrays[i] for i in trained]
     with jax.default_matmul_precision("highest"):
-        loss, grads = jax.jit(jax.value_and_grad(loss_of))(
-            [arrays[i] for i in trained])
-    return float(loss), {structural[names[i]]: g
-                         for i, g in zip(trained, grads)}
+        loss, grads = fn(x)
+        text = fn.lower(x).as_text() if program else None
+    got = float(loss), {structural[names[i]]: g
+                        for i, g in zip(trained, grads)}
+    return got + (text,) if program else got
+
+
+def _forward_kernel_calls(text):
+    """Calls of the attention kernels' jitted forward in a program's text."""
+    return len(re.findall(r" call @_flash_fwd(_\d+)?\(", text))
 
 
 def _one_device():
@@ -229,9 +237,14 @@ def test_unknown_kinds_and_tables_raise():
 def test_recomputed_layers_give_equal_gradients(cut):
     _, ids, _, (loss, grads), _ = cut
     _, labels = _batch()
-    again, marked = _loss_and_grads(_net(recompute=True), ids, labels)
+    again, marked, text = _loss_and_grads(_net(recompute=True), ids, labels,
+                                          program=True)
     assert again == loss
     assert _worst(marked, grads) < 1e-6
+    # the marked layers keep their attention kernels' output and
+    # log-sum-exp: the forward kernel runs once a layer, not again in the
+    # recomputed forward
+    assert _forward_kernel_calls(text) == len(CUT)
 
 
 # -------------------------------------------------------- the expert layer --
