@@ -3,7 +3,7 @@
 QKV projection, over a sliding window (``ops.window_attention``) or the whole
 prefix (``ops.flash_attention``): the same Pallas kernels either way, the
 window's on a grid that walks the band and under names of their own
-(``window_attention_fwd`` / ``_bwd_dq`` / ``_bwd_dkv``), which picks its block
+(``window_attention_fwd`` / ``_bwd_dqkv``), which picks its block
 itself because it pads what the block does not divide.  An optional RMSNorm
 over each head of Q and of K, then an optional rotary step on both, stand in
 front of either.  ``_LatentAttention`` is multi-head latent attention in its

@@ -146,8 +146,8 @@ def _window_attention(q, k, v, heads=1, kv_heads=None, window=512):
     """Causal sliding-window attention on (B, S, H*D) projections, k and v
     (B, S, kv_heads*D): query t sees keys t-window+1 .. t, itself included.
     Exact, through the Pallas flash kernels with a window
-    (ops/pallas/flash_attention.py, ``window_attention_fwd`` / ``_bwd_dq`` /
-    ``_bwd_dkv`` in a trace): their grid walks the band's block pairs and
+    (ops/pallas/flash_attention.py, ``window_attention_fwd`` /
+    ``_bwd_dqkv`` in a trace): their grid walks the band's block pairs and
     no others, and the scores never leave VMEM.  A sequence that the block
     does not divide is padded at the end (padded keys lie after every real
     query: causality masks them) and cut back."""

@@ -11,9 +11,13 @@ v2 design:
   holds a whole (S, D) K or V in VMEM, so sequence length is bounded by HBM,
   not VMEM.  Grid (B·H, S/bq, S/bk); accumulators (acc, m, l) live in VMEM
   scratch carried across the k-dimension of the grid.
-- The forward also emits the per-row log-sum-exp, and the **backward is two
-  Pallas kernels** (dq, then dk/dv) using the standard recompute-from-lse
-  formulation — O(S·D) memory end to end.
+- The forward also emits the per-row log-sum-exp, and the **backward is one
+  Pallas kernel** (``bwd_dqkv``) using the standard recompute-from-lse
+  formulation — O(S·D) memory end to end.  It makes each pair's scores,
+  probabilities, ``dp`` and ``ds`` once for dq, dk and dv, and holds their
+  float32 accumulators for a whole sequence in VMEM; a sequence too long for
+  ``FUSED_VMEM_BUDGET`` takes the split pair (``bwd_dq``, then ``bwd_dkv``),
+  which makes them twice and holds a block of each.
 - **Attention-probability dropout runs inside the kernel**: a counter-based
   integer hash (SplitMix32 finaliser) of (head, q-pos, k-pos, seed) drawn
   identically in forward and backward, so no mask is ever materialised.
@@ -36,17 +40,18 @@ times):
   blocks of 512: 36 of 64 pairs computed, 8 of them masked).
 
 With a ``window`` (PR 32; query ``t`` sees keys ``t-window+1 .. t``) the same
-three bodies run under the names ``window_attention_fwd`` / ``_bwd_dq`` /
-``_bwd_dkv``, and **the grid's inner axis walks the band, not the sequence**:
-``_band_steps`` key blocks a query block (and query blocks a key block), the
-block of each step worked out in the index map, a step off the sequence's
-edge clamped onto it and skipped.  The mask also cuts a pair that the
-window's far edge crosses; ``band_pairs`` counts both (at 8,192 positions,
-blocks of 512 and a window of 1,024: 45 pairs a head, 30 of them masked,
-where the causal kernels compute 136).
+bodies run under the names ``window_attention_fwd`` / ``_bwd_dqkv`` (or
+``_bwd_dq`` / ``_bwd_dkv``), and **the grid's inner axis walks the band, not
+the sequence**: ``_band_steps`` key blocks a query block (and query blocks a
+key block), the block of each step worked out in the index map, a step off
+the sequence's edge clamped onto it and skipped.  The mask also cuts a pair
+that the window's far edge crosses; ``band_pairs`` counts both (at 8,192
+positions, blocks of 512 and a window of 1,024: 45 pairs a head, 30 of them
+masked, where the causal kernels compute 136).
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import threading
 
@@ -56,12 +61,23 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ... import telemetry
 from ...context import on_tpu
 
 _NEG_INF = -1e30
 _LANES = 128
 # contract the last dimension of both operands: ``a @ b.T`` with no transpose
 _NT = (((1,), (1,)), ((), ()))
+# contract the first dimension of both: ``a.T @ b``
+_TN = (((0,), (0,)), ((), ()))
+# the most VMEM the fused backward's whole-sequence accumulators and output
+# blocks may take (``fused_vmem_bytes``): 40 MiB at 8,192 positions and
+# heads of 192 / 128 in bf16; its limit leaves room for the blocks of the
+# pairs and their scores beside them
+FUSED_VMEM_BUDGET = 48 * 1024 * 1024
+_FUSED_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("arbitrary",) * 4,
+    vmem_limit_bytes=FUSED_VMEM_BUDGET + 16 * 1024 * 1024)
 
 
 def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
@@ -275,12 +291,12 @@ def _band_query_block(kj, step, block_q, block_k):
 def _needed(causal, block_q, block_k, window, s):
     """``(key steps, key block of (i, j), query steps, query block of
     (i, j))``: the length of the grid's inner axis in the forward and dq
-    kernels and in the dk/dv kernel, and their index maps, ``i`` on the
-    query side and ``j`` on the key side.  Causal: the whole sequence,
-    clamped to the last key block of query block ``i`` and the first query
-    block of key block ``j``; a grid step past them names the block it
-    already holds, and fetches nothing.  With a window: the band alone,
-    clamped to the sequence."""
+    kernels and in the dk/dv and fused backward kernels, and their index
+    maps, ``i`` on the query side and ``j`` on the key side.  Causal: the
+    whole sequence, clamped to the last key block of query block ``i`` and
+    the first query block of key block ``j``; a grid step past them names
+    the block it already holds, and fetches nothing.  With a window: the
+    band alone, clamped to the sequence."""
     n_q, n_k = s // block_q, s // block_k
     if not causal:
         return n_k, (lambda i, j: j), n_q, (lambda i, j: i)
@@ -439,9 +455,87 @@ def _dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnums=(7, 8, 9, 10, 11, 12, 13))
+def _dqkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                 dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *, scale,
+                 causal, block_q, block_k, group, n_q, dropout, window, seq):
+    """dq, dk and dv from one pass over the pairs: each pair's scores,
+    probabilities, ``dp`` and ``ds`` made once, held transposed as in the
+    dk/dv kernel, and ``dq += ds k`` read from ``ds^T`` by a product that
+    contracts its first dimension.  The grid walks a query head's key blocks
+    and, inside each, its query blocks; dq accumulates over the whole
+    sequence of one query head, dk and dv over the whole sequence of one K/V
+    head and its ``group`` query heads, each written once at its end."""
+    bkv = pl.program_id(0)
+    g = pl.program_id(1)
+    kj = pl.program_id(2)
+    step = pl.program_id(3)
+    qi = step if window is None else _band_query_block(
+        kj, step, block_q, block_k)
+    first = (kj == 0) & (step == 0)
+    last = (kj == pl.num_programs(2) - 1) & (step == n_q - 1)
+
+    @pl.when(first)
+    def _init_q():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    @pl.when(first & (g == 0))
+    def _init_kv():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    def _compute(masked):
+        q = q_ref[0]
+        k = k_ref[0]
+        do = do_ref[0]
+        st, pos = _scores(k, q, scale, masked, dropout, qi, kj, block_q,
+                          block_k, window, q_axis=1)           # (bk, bq)
+        pt = jnp.exp(st - lse_ref[0, 0])
+        dpt = _dot(v_ref[0], do, _NT)
+        keep = _keep(bkv * group + g, pos, seed_ref[0], dropout)
+        keys = pl.ds(pl.multiple_of(kj * block_k, block_k), block_k)
+        dv_acc[keys, :] += _dot(_dropped(pt, keep, dropout).astype(do.dtype),
+                                do)
+        dst = (pt * (_dropped(dpt, keep, dropout) - delta_ref[0, 0])
+               ).astype(q.dtype)
+        dk_acc[keys, :] += _dot(dst, q)
+        queries = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+        dq_acc[queries, :] += _dot(dst, k, _TN)
+
+    _visible_pairs(_compute, causal, qi, kj, block_q, block_k, window, seq)
+
+    @pl.when(last)
+    def _finish_q():
+        dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
+
+    @pl.when(last & (g == group - 1))
+    def _finish_kv():
+        dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _padded(width):
+    """Lanes a row of ``width`` takes in VMEM."""
+    return -(-width // _LANES) * _LANES
+
+
+def fused_vmem_bytes(s, d, d_v, itemsize):
+    """VMEM that the fused backward holds whole-sequence: the float32
+    accumulators of dq, dk (``d`` wide) and dv (``d_v``), and the output
+    blocks of the same widths, double-buffered in the operands' dtype."""
+    lanes = 2 * _padded(d) + _padded(d_v)
+    return s * lanes * (4 + 2 * itemsize)
+
+
+def takes_fused(s, d, d_v, itemsize):
+    """Whether a backward of this shape takes the fused kernel: its
+    whole-sequence blocks fit ``FUSED_VMEM_BUDGET``; a longer sequence
+    takes the split pair, whose VMEM does not grow with it."""
+    return fused_vmem_bytes(s, d, d_v, itemsize) <= FUSED_VMEM_BUDGET
+
+
+@functools.partial(jax.jit, static_argnums=(7, 8, 9, 10, 11, 12, 13, 14))
 def _flash_bwd(q, k, v, seed, o, lse, do, scale, causal, block_q, block_k,
-               interpret, dropout, window=None):
+               interpret, dropout, window, fused):
     bh, s, d = q.shape
     d_v = v.shape[-1]
     block_q = min(block_q, s)
@@ -452,6 +546,55 @@ def _flash_bwd(q, k, v, seed, o, lse, do, scale, causal, block_q, block_k,
     delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
     lse = lse.reshape(bh, s // block_q, 1, block_q)
     delta = delta.reshape(bh, s // block_q, 1, block_q)
+    operands = (seed, q, k, v, do, lse, delta)
+    if not fused:
+        return _split_bwd(operands, scale, causal, block_q, block_k, group,
+                          (n_k, key_block, n_q, query_block), interpret,
+                          dropout, window)
+
+    # grouped-query heads: the grid walks a K/V head's group of query heads
+    # one after another, each over every (key block, query block) pair
+    def q_spec(width):
+        return pl.BlockSpec(
+            (1, block_q, width),
+            lambda b, g, j, i: (b * group + g, query_block(i, j), 0))
+
+    def kv_spec(width):
+        return pl.BlockSpec((1, block_k, width), lambda b, g, j, i: (b, j, 0))
+    row_spec = _row_spec(
+        block_q, lambda b, g, j, i: (b * group + g, query_block(i, j), 0, 0))
+    return pl.pallas_call(
+        functools.partial(_dqkv_kernel, scale=scale, causal=causal,
+                          block_q=block_q, block_k=block_k, group=group,
+                          n_q=n_q, dropout=dropout, window=window, seq=s),
+        grid=(k.shape[0], group, s // block_k, n_q),
+        in_specs=[pl.BlockSpec((1,), lambda b, g, j, i: (0,)),
+                  q_spec(d), kv_spec(d), kv_spec(d_v), q_spec(d_v), row_spec,
+                  row_spec],
+        out_specs=[
+            pl.BlockSpec((1, s, d), lambda b, g, j, i: (b * group + g, 0, 0)),
+            pl.BlockSpec((1, s, d), lambda b, g, j, i: (b, 0, 0)),
+            pl.BlockSpec((1, s, d_v), lambda b, g, j, i: (b, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        scratch_shapes=[pltpu.VMEM((s, d), jnp.float32),
+                        pltpu.VMEM((s, d), jnp.float32),
+                        pltpu.VMEM((s, d_v), jnp.float32)],
+        compiler_params=_FUSED_PARAMS,
+        name=_name("bwd_dqkv", window),
+        interpret=interpret,
+    )(*operands)
+
+
+def _split_bwd(operands, scale, causal, block_q, block_k, group, needed,
+               interpret, dropout, window):
+    """dq, then dk and dv, by two kernels that each make the pairs' scores
+    and probabilities: VMEM holds a block of each, for any length."""
+    seed, q, k, v, do, lse, delta = operands
+    bh, s, d = q.shape
+    d_v = v.shape[-1]
+    n_k, key_block, n_q, query_block = needed
 
     def q_spec(width):
         return pl.BlockSpec((1, block_q, width), lambda b, i, j: (b, i, 0))
@@ -475,7 +618,7 @@ def _flash_bwd(q, k, v, seed, o, lse, do, scale, causal, block_q, block_k,
                         pltpu.VMEM((block_q, _LANES), jnp.float32)],
         name=_name("bwd_dq", window),
         interpret=interpret,
-    )(seed, q, k, v, do, lse, delta)
+    )(*operands)
 
     # grouped-query heads: a K/V head's gradient is the sum over its group
     # of query heads, which the grid walks before it moves to the next block
@@ -503,7 +646,7 @@ def _flash_bwd(q, k, v, seed, o, lse, do, scale, causal, block_q, block_k,
                         pltpu.VMEM((block_k, d_v), jnp.float32)],
         name=_name("bwd_dkv", window),
         interpret=interpret,
-    )(seed, q, k, v, do, lse, delta)
+    )(*operands)
     return dq, dk, dv
 
 
@@ -581,13 +724,37 @@ def _flash_fwd_rule(q, k, v, seed, scale, causal, block_q, block_k,
     return out, (q, k, v, seed, out, lse)
 
 
+def backward_paths():
+    """``(fused, split)``: the backward calls traced on this thread so far
+    that took the fused kernel and those that took the split pair."""
+    return getattr(_traced, "fused", 0), getattr(_traced, "split", 0)
+
+
+@contextlib.contextmanager
+def publish_backward_paths():
+    """Around one trace of a differentiated program: set the gauge
+    ``flash.bwd_fused_share`` to the share of the backward calls traced
+    inside that took the fused kernel (1: all of them).  Left alone where
+    none was traced."""
+    before = backward_paths()
+    yield
+    fused, split = (n - m for n, m in zip(backward_paths(), before))
+    if fused + split:
+        telemetry.registry().gauge("flash.bwd_fused_share").set(
+            fused / (fused + split))
+
+
 def _flash_bwd_rule(scale, causal, block_q, block_k, interpret, dropout,
                     window, res, do):
     q, k, v, seed, o, lse = res
+    fused = takes_fused(q.shape[1], q.shape[-1], v.shape[-1],
+                        q.dtype.itemsize)
+    path = "fused" if fused else "split"
+    setattr(_traced, path, getattr(_traced, path, 0) + 1)
     scale, interpret = _resolve(scale, q.shape[-1], interpret)
     dq, dk, dv = _flash_bwd(q, k, v, seed, o, lse, do, scale, causal,
                             block_q, block_k, interpret, float(dropout),
-                            window)
+                            window, fused)
     return dq, dk, dv, None
 
 

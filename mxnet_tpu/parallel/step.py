@@ -35,6 +35,7 @@ from ..ndarray import NDArray
 from ..gluon.block import (_flatten_nd, _unflatten_nd, infer_shapes,
                            publish_kept)
 from ..gluon.parameter import _run_program, materialize
+from ..ops.pallas.flash_attention import publish_backward_paths
 from .mesh import MeshScope, default_mesh
 from .sharding import ShardingRules, batch_spec, param_sharding
 from .functional import (FunctionalState, functional_call,
@@ -347,7 +348,7 @@ class TrainStep:
                 # device-side names: jax writes the forward pass's ops as
                 # ``jvp(forward)/...`` and their transposes, the backward
                 # pass, as ``transpose(jvp(forward))/...``
-                with publish_kept():
+                with publish_kept(), publish_backward_paths():
                     return jax.value_and_grad(loss_of, has_aux=True)(ta_in)
 
             if self._grad_reduce == "f32":

@@ -564,3 +564,155 @@ def test_flash_op_takes_a_v_head_of_its_own_width():
     want = want.reshape(b, heads, s, d_v).transpose(0, 2, 1, 3).reshape(
         b, s, heads * d_v)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+# ---------------------------------------------------------------------------
+# one backward kernel: dq, dk and dv from one pass over the pairs (each
+# pair's scores, probabilities, dp and ds made once), against the dense
+# formulation and against the split pair that a sequence too long for the
+# kernel's VMEM takes
+# ---------------------------------------------------------------------------
+def _flash_module():
+    import importlib
+    return importlib.import_module("mxnet_tpu.ops.pallas.flash_attention")
+
+
+def _dense_dropped(q, k, v, causal, window, dropout, seed):
+    """``_dense_f32`` with the kernels' dropout: the probabilities of query
+    head ``h`` at (t, u) kept where the counter hash of (h, t, u, seed)
+    draws at least ``dropout``, rescaled."""
+    import jax
+    import jax.numpy as jnp
+    if dropout == 0.0:
+        return _dense_f32(q, k, v, causal, window=window)
+    group = q.shape[0] // k.shape[0]
+    k, v = (jnp.repeat(a, group, axis=0) for a in (k, v))
+    s = jnp.einsum("bqd,bkd->bqk", q, k) / np.sqrt(q.shape[-1])
+    at = jnp.arange(s.shape[-1])
+    if causal:
+        seen = at[None, :] <= at[:, None]
+        if window is not None:
+            seen &= at[None, :] > at[:, None] - window
+        s = jnp.where(seen, s, -1e30)
+    heads = jnp.arange(s.shape[0])[:, None, None]
+    keep = _flash_module()._uniform01(heads, at[None, :, None],
+                                      at[None, None, :], seed) >= dropout
+    p = jnp.where(keep, jax.nn.softmax(s, axis=-1), 0.0) / (1.0 - dropout)
+    return jnp.einsum("bqk,bkd->bqd", p, v)
+
+
+# (query heads, K/V heads, positions, D, D_v, block_q, block_k, causal,
+# window, dropout): causal, windowed and full; groups of 1, 2, 4 and 8;
+# square and rectangular blocks either way; latent attention's 192 / 128;
+# dropout on and off
+_BACKWARDS = {
+    "causal_group1": (2, 2, 128, 8, 8, 32, 32, True, None, 0.0),
+    "causal_group2_blocks_16_64": (4, 2, 128, 8, 8, 16, 64, True, None, 0.0),
+    "causal_group4_blocks_64_16": (8, 2, 128, 8, 8, 64, 16, True, None, 0.0),
+    "causal_group8": (8, 1, 64, 8, 8, 32, 32, True, None, 0.0),
+    "full_group2_blocks_32_16": (4, 2, 64, 8, 8, 32, 16, False, None, 0.0),
+    "window_40_group1": (2, 2, 128, 8, 8, 32, 32, True, 40, 0.0),
+    "window_33_group4_blocks_32_16": (8, 2, 128, 8, 8, 32, 16, True, 33, 0.0),
+    "window_24_group8_blocks_16_32": (8, 1, 64, 8, 8, 16, 32, True, 24, 0.0),
+    "latent_192_128": (2, 2, 128, 192, 128, 64, 64, True, None, 0.0),
+    "dropout_causal_group2": (4, 2, 64, 8, 8, 32, 32, True, None, 0.25),
+    "dropout_window_group1": (2, 2, 64, 8, 8, 16, 16, True, 24, 0.25),
+    "dropout_full_v_of_its_own_width": (2, 1, 64, 24, 8, 32, 16, False, None,
+                                        0.25)}
+
+
+@pytest.mark.parametrize("case", sorted(_BACKWARDS))
+def test_fused_backward_matches_dense_and_the_split_pair(monkeypatch, case):
+    """One ``*_bwd_dqkv`` kernel in the gradient's program, and no split
+    kernel; its dq, dk and dv against the dense formulation (the same
+    dropout mask drawn), and against the split pair's: dk and dv bit for
+    bit (the same products summed in the same order), dq to float32
+    rounding (its product reads ``ds`` transposed)."""
+    import re
+    import jax
+    import jax.numpy as jnp
+    fa = _flash_module()
+    heads, kv_heads, s, d, d_v, block_q, block_k, causal, window, dropout = \
+        _BACKWARDS[case]
+    rng = np.random.RandomState(14)
+    q = jnp.asarray(rng.randn(heads, s, d) * 0.5, jnp.float32)
+    k = jnp.asarray(rng.randn(kv_heads, s, d) * 0.5, jnp.float32)
+    v = jnp.asarray(rng.randn(kv_heads, s, d_v) * 0.5, jnp.float32)
+    w = jnp.asarray(rng.randn(heads, s, d_v), jnp.float32)
+    seed = jnp.asarray([9], jnp.int32)
+
+    def fn(*a):
+        return fa.flash_attention(*a, causal=causal, block_q=block_q,
+                                  block_k=block_k, dropout=dropout,
+                                  seed=seed, window=window)
+
+    def kernels():
+        text = str(jax.make_jaxpr(jax.grad(
+            lambda *a: (fn(*a) * w).sum(), (0, 1, 2)))(q, k, v))
+        return sorted(set(re.findall(r"name=(\w+_attention_bwd\w*)", text)))
+    prefix = "window" if window is not None else "flash"
+    assert kernels() == [f"{prefix}_attention_bwd_dqkv"]
+    got = _out_and_grads(fn, q, k, v, w)
+    want = _out_and_grads(
+        lambda *a: _dense_dropped(*a, causal, window, dropout, seed[0]),
+        q, k, v, w)
+    for name in got:
+        np.testing.assert_allclose(got[name], want[name],
+                                   **_tol(2e-4, 2e-5, 1e-2), err_msg=name)
+    monkeypatch.setattr(fa, "FUSED_VMEM_BUDGET", 0)
+    assert kernels() == [f"{prefix}_attention_bwd_dkv",
+                         f"{prefix}_attention_bwd_dq"]
+    split = _out_and_grads(fn, q, k, v, w)
+    for name in ("out", "dk", "dv"):
+        np.testing.assert_array_equal(np.asarray(got[name]),
+                                      np.asarray(split[name]), err_msg=name)
+    np.testing.assert_allclose(got["dq"], split["dq"], rtol=1e-5, atol=1e-6)
+
+
+def test_a_backward_over_the_vmem_budget_takes_the_split_pair(monkeypatch):
+    """The shape rule: a backward whose whole-sequence accumulators and
+    double-buffered output blocks (lanes padded to 128) fit the budget takes
+    the fused kernel, one a byte over it the split pair, counted per path;
+    the gauge around a trace holds the fused share of the calls inside."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu import telemetry
+    fa = _flash_module()
+    s, d, d_v = 64, 24, 8
+    need = fa.fused_vmem_bytes(s, d, d_v, 4)
+    assert need == s * (128 + 128 + 128) * (4 + 2 * 4)
+    rng = np.random.RandomState(15)
+    q, k = (jnp.asarray(rng.randn(2, s, d) * 0.5, jnp.float32)
+            for _ in range(2))
+    v = jnp.asarray(rng.randn(2, s, d_v) * 0.5, jnp.float32)
+    want = jax.grad(lambda *a: _dense_f32(*a, True).sum(), (0, 1, 2))(q, k, v)
+    telemetry.registry().remove("flash.bwd_fused_share")
+    with fa.publish_backward_paths():
+        for budget, path in ((need, 0), (need - 1, 1)):
+            monkeypatch.setattr(fa, "FUSED_VMEM_BUDGET", budget)
+            before = fa.backward_paths()
+            got = jax.grad(lambda *a: fa.flash_attention(
+                *a, causal=True, block_q=32, block_k=16).sum(),
+                (0, 1, 2))(q, k, v)
+            taken = [n - m for n, m in zip(fa.backward_paths(), before)]
+            assert taken == [1 - path, path]
+            for g, w in zip(got, want):
+                np.testing.assert_allclose(g, w, **_tol(2e-4, 2e-5, 1e-2))
+    assert telemetry.registry().get("flash.bwd_fused_share").value == 0.5
+
+
+@pytest.mark.parametrize("s,d,d_v,fused", [
+    (8192, 192, 128, True), (8192, 128, 128, True), (8192, 64, 64, True),
+    (4096, 64, 64, True), (16384, 192, 128, False), (16384, 128, 128, True),
+    (32768, 64, 64, False)],
+    ids=["kanana2", "mellum2", "lfm2", "phi4", "kanana2_at_16k",
+         "mellum2_at_16k", "lfm2_at_32k"])
+def test_the_cells_shapes_take_the_fused_backward(s, d, d_v, fused):
+    """bf16 operands at every decoder cell's heads and length take the
+    fused kernel (kanana2's 192-wide heads pad to 256 lanes: 40 MiB);
+    heads of 128 fit twice the length (48 MiB, the budget itself), heads of
+    192 do not, nor four times the length at heads of 64."""
+    fa = _flash_module()
+    assert fa.takes_fused(s, d, d_v, 2) is fused
+    if (s, d) == (8192, 192):
+        assert fa.fused_vmem_bytes(s, d, d_v, 2) == 40 * 1024 * 1024

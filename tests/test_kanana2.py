@@ -240,9 +240,10 @@ def test_train_step_program_names_every_new_scope(stepped):
     assert not any("/moe/shared_experts" in p for p in paths)
     assert "layer0/shared_experts" not in text
     assert "rematted_computation" in text
-    for kernel in ("flash_attention_fwd", "flash_attention_bwd_dq",
-                   "flash_attention_bwd_dkv"):
+    for kernel in ("flash_attention_fwd", "flash_attention_bwd_dqkv"):
         assert kernel in text
+    # one backward kernel, not the split pair
+    assert not re.search(r"flash_attention_bwd_(dq|dkv)\b", text)
     # the operations, not the source locations (which name whatever traced
     # a shared jitted kernel first in this process)
     ops = "\n".join(l for l in text.splitlines() if not l.startswith("#loc"))
@@ -257,6 +258,7 @@ def test_a_recomputed_step_runs_the_attention_forward_once_a_layer():
     the bytes kept: ``o`` (bf16, B*H x T x D_v) and ``lse`` (float32,
     B*H x T) a layer."""
     telemetry.registry().remove("recompute.kept_attention_bytes")
+    telemetry.registry().remove("flash.bwd_fused_share")
     net, loss_fn, batch = family.build(TOY)
     mx.random.seed(1)
     net.initialize()
@@ -274,7 +276,7 @@ def test_a_recomputed_step_runs_the_attention_forward_once_a_layer():
     def calls(f):
         return len(re.findall(rf" call @{f}(?:_\d+)?\(", text))
     # one lowering of each flash jit, each called once a layer: _flash_bwd
-    # holds both backward kernels (_bwd_dq, _bwd_dkv)
+    # holds the backward kernel (_bwd_dqkv), which every layer took
     assert (len(lowered("_flash_fwd")), len(lowered("_flash_bwd"))) == (1, 1)
     assert calls("_flash_fwd") == _forward_kernel_calls(text) == layers
     assert calls("_flash_bwd") == layers
@@ -288,6 +290,7 @@ def test_a_recomputed_step_runs_the_attention_forward_once_a_layer():
     rows = ids.shape[0] * heads * T
     assert telemetry.registry().get("recompute.kept_attention_bytes").value \
         == layers * (rows * d_v * 2 + rows * 4) == 55296
+    assert telemetry.registry().get("flash.bwd_fused_share").value == 1.0
 
 
 def test_a_step_trains_and_leaves_the_bias(stepped):

@@ -617,9 +617,9 @@ def test_train_step_program_names_every_new_scope(stepped):
             assert any("transpose(jvp(forward))" in p for p in forward), scope
     assert "layer0/moe" not in text and "layer1/mlp" not in text
     assert "rematted_computation" in text
-    for kernel in ("flash_attention_fwd", "flash_attention_bwd_dq",
-                   "flash_attention_bwd_dkv"):
+    for kernel in ("flash_attention_fwd", "flash_attention_bwd_dqkv"):
         assert kernel in text
+    assert not re.search(r"flash_attention_bwd_(dq|dkv)\b", text)
     ops = "\n".join(l for l in text.splitlines() if not l.startswith("#loc"))
     assert "window_attention" not in text and "ragged_dot" not in ops
     for kernel in ("moe_grouped_fwd", "moe_grouped_dx", "moe_grouped_dw"):

@@ -867,11 +867,12 @@ def test_train_step_program_names_every_new_scope(stepped):
         if not scope.endswith(("/router", "/dispatch")) or "router" in scope:
             assert any("transpose(jvp(forward))" in p for p in forward), scope
     assert "rematted_computation" in text
-    for kernel in ("flash_attention_fwd", "flash_attention_bwd_dq",
-                   "flash_attention_bwd_dkv", "window_attention_fwd",
-                   "window_attention_bwd_dq", "window_attention_bwd_dkv",
+    for kernel in ("flash_attention_fwd", "flash_attention_bwd_dqkv",
+                   "window_attention_fwd", "window_attention_bwd_dqkv",
                    "moe_grouped_fwd", "moe_grouped_dx", "moe_grouped_dw"):
         assert kernel in text
+    # one backward kernel a layer, not the split pair
+    assert not re.search(r"(flash|window)_attention_bwd_(dq|dkv)\b", text)
     ops = "\n".join(l for l in text.splitlines() if not l.startswith("#loc"))
     assert "ragged_dot" not in ops
 

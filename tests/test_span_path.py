@@ -348,13 +348,22 @@ def _pallas_names(fn, *args):
     return out
 
 
-def _flash(grad):
-    from mxnet_tpu.ops.pallas.flash_attention import flash_attention
+def _flash(grad, split=False):
+    """The flash kernels' names in a program: the forward, or its gradient
+    through the fused backward or (``split``: no VMEM budget) the pair."""
+    import importlib
+    fa = importlib.import_module("mxnet_tpu.ops.pallas.flash_attention")
     q = jnp.ones((2, 128, 64), jnp.float32)
     if not grad:
-        return _pallas_names(lambda q: flash_attention(q, q, q), q)
-    return _pallas_names(jax.grad(
-        lambda q, k, v: flash_attention(q, k, v).sum(), (0, 1, 2)), q, q, q)
+        return _pallas_names(lambda q: fa.flash_attention(q, q, q), q)
+    held = fa.FUSED_VMEM_BUDGET
+    fa.FUSED_VMEM_BUDGET = 0 if split else held
+    try:
+        return _pallas_names(jax.grad(
+            lambda q, k, v: fa.flash_attention(q, k, v).sum(), (0, 1, 2)),
+            q, q, q)
+    finally:
+        fa.FUSED_VMEM_BUDGET = held
 
 
 def _conv(grad):
@@ -393,13 +402,14 @@ def _scan(grad):
 @pytest.mark.parametrize("name,found", [
     ("paged_attention", _paged),
     ("flash_attention_fwd", lambda: _flash(False)),
-    ("flash_attention_bwd_dq", lambda: _flash(True)),
-    ("flash_attention_bwd_dkv", lambda: _flash(True)),
+    ("flash_attention_bwd_dq", lambda: _flash(True, split=True)),
+    ("flash_attention_bwd_dkv", lambda: _flash(True, split=True)),
     ("fused_conv_fwd", lambda: _conv(False)),
     ("fused_conv_bwd_dx", lambda: _conv(True)),
     ("fused_conv_bwd_dw", lambda: _conv(True)),
     ("selective_scan_fwd", lambda: _scan(False)),
     ("selective_scan_bwd", lambda: _scan(True)),
+    ("flash_attention_bwd_dqkv", lambda: _flash(True)),
 ])
 def test_every_pallas_call_carries_its_name(name, found):
     names = found()
@@ -446,14 +456,21 @@ def test_scan_kernels_lower_for_the_tpu_at_the_cell_shape(name, grad):
 
 @pytest.mark.parametrize("name", ["flash_attention_fwd",
                                   "flash_attention_bwd_dq",
-                                  "flash_attention_bwd_dkv"])
-def test_flash_kernels_lower_for_the_tpu_at_the_cell_shape(name):
+                                  "flash_attention_bwd_dkv",
+                                  "flash_attention_bwd_dqkv"])
+def test_flash_kernels_lower_for_the_tpu_at_the_cell_shape(name,
+                                                           monkeypatch):
     """The same for the flash kernels: 40 query and 20 K/V heads of 64 over
     4,096 positions in bf16, causal, the block ``sambay.py`` gives (whole
     registers of row statistics, their transposes, the clamped index
-    maps)."""
+    maps).  The cell's shape takes the fused backward; the split pair's
+    two kernels are lowered at it with no VMEM budget."""
+    import importlib
     from mxnet_tpu.gluon.model_zoo.sambay import _flash_block
     from mxnet_tpu.ops.pallas.flash_attention import flash_attention
+    if name in ("flash_attention_bwd_dq", "flash_attention_bwd_dkv"):
+        monkeypatch.setattr(importlib.import_module(
+            "mxnet_tpu.ops.pallas.flash_attention"), "FUSED_VMEM_BUDGET", 0)
     q = jax.ShapeDtypeStruct((40, 4096, 64), jnp.bfloat16)
     kv = jax.ShapeDtypeStruct((20, 4096, 64), jnp.bfloat16)
     block = _flash_block(4096)
@@ -465,6 +482,9 @@ def test_flash_kernels_lower_for_the_tpu_at_the_cell_shape(name):
     text = jax.jit(jax.grad(total, (0, 1, 2))).trace(q, kv, kv).lower(
         lowering_platforms=("tpu",)).as_text()
     assert f'kernel_name = "{name}"' in text
+    if name == "flash_attention_bwd_dqkv":
+        assert 'kernel_name = "flash_attention_bwd_dq"' not in text
+        assert 'kernel_name = "flash_attention_bwd_dkv"' not in text
 
 
 def _scope_paths(lowered):

@@ -8,6 +8,7 @@ The TPU's library belongs to one process at a time: the topology is described
 inside a fixture, by the one worker that is given this file, and every such
 compile lives in this file (``on-chip-measurement`` guide, section 2)."""
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -96,9 +97,9 @@ def test_flash_kernels_compile_for_v5e_at_heads_of_128(one_chip,
         shape(32), shape(4), shape(4)).lower(
         lowering_platforms=("tpu",)).compile()
     text = compiled.as_text()
-    for kernel in ("flash_attention_fwd", "flash_attention_bwd_dq",
-                   "flash_attention_bwd_dkv"):
+    for kernel in ("flash_attention_fwd", "flash_attention_bwd_dqkv"):
         assert kernel in text
+    assert not re.search(r"flash_attention_bwd_(dq|dkv)\b", text)
 
 
 # kanana2_30b_a3b.train_s8192: latent attention, 32 heads of 192 for Q and K
@@ -124,9 +125,9 @@ def test_flash_kernels_compile_for_v5e_with_a_v_head_of_128_beside_192(
         shape(192), shape(192), shape(128)).lower(
         lowering_platforms=("tpu",)).compile()
     text = compiled.as_text()
-    for kernel in ("flash_attention_fwd", "flash_attention_bwd_dq",
-                   "flash_attention_bwd_dkv"):
+    for kernel in ("flash_attention_fwd", "flash_attention_bwd_dqkv"):
         assert kernel in text
+    assert not re.search(r"flash_attention_bwd_(dq|dkv)\b", text)
     assert "bf16[32,8192,128]" in text and "bf16[32,8192,192]" in text
 
 
@@ -158,10 +159,51 @@ def test_window_kernels_compile_for_v5e(one_chip, no_compile_cache, heads,
         shape(heads), shape(kv_heads), shape(kv_heads)).lower(
         lowering_platforms=("tpu",)).compile()
     text = compiled.as_text()
-    for kernel in ("window_attention_fwd", "window_attention_bwd_dq",
-                   "window_attention_bwd_dkv"):
+    for kernel in ("window_attention_fwd", "window_attention_bwd_dqkv"):
         assert kernel in text
+    assert not re.search(r"window_attention_bwd_(dq|dkv)\b", text)
     assert "flash_attention_" not in text
+
+
+# every decoder cell's attention as its step runs it (batch and heads
+# together, query heads then K/V heads, positions, Q/K head, V head,
+# window): the fused backward's whole-sequence accumulators and output
+# blocks beside the pairs' blocks have to fit the chip's VMEM
+CELL_ATTENTION = {
+    "kanana2_30b_a3b": (64, 64, 8192, 192, 128, None),
+    "mellum2_12b_a2_5b_full": (64, 8, 8192, 128, 128, None),
+    "mellum2_12b_a2_5b_window": (64, 8, 8192, 128, 128, 1024),
+    "lfm2_8b_a1b": (128, 32, 8192, 64, 64, None),
+    "phi4_mini_flash_full": (40, 20, 4096, 64, 64, None),
+    "phi4_mini_flash_window": (40, 20, 4096, 64, 64, 512)}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_ATTENTION))
+def test_fused_backward_compiles_for_v5e_at_the_cells_shapes(
+        one_chip, no_compile_cache, cell):
+    """The gradient of each decoder cell's attention at its exact shape,
+    bf16, the block the decoders give: one ``*_bwd_dqkv`` kernel and no
+    split pair; a VMEM overflow fails here, without a chip."""
+    from mxnet_tpu.gluon.model_zoo._attention import _flash_block
+    from mxnet_tpu.ops.attention import _window_block
+    from mxnet_tpu.ops.pallas import flash_attention
+    heads, kv_heads, t, d, d_v, window = CELL_ATTENTION[cell]
+    block = _flash_block(t) if window is None else _window_block(t)
+
+    def shape(n, width):
+        return jax.ShapeDtypeStruct((n, t, width), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def total(q, k, v):
+        return flash_attention(
+            q, k, v, causal=True, block_q=block, block_k=block,
+            interpret=False, window=window).astype(jnp.float32).sum()
+    text = jax.jit(jax.grad(total, range(3))).trace(
+        shape(heads, d), shape(kv_heads, d), shape(kv_heads, d_v)).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+    prefix = "flash" if window is None else "window"
+    assert f"{prefix}_attention_bwd_dqkv" in text
+    assert not re.search(r"_attention_bwd_(dq|dkv)\b", text)
 
 
 # (d, F, held, routed, k, the router's own arguments) of the cells that run

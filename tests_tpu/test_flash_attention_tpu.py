@@ -43,9 +43,9 @@ from mxnet_tpu.ops.pallas.flash_attention import flash_attention  # noqa: E402
 ])
 def test_flash_real_shapes_compiled(bh, bh_kv, s, d, causal, dropout, block,
                                     window):
-    """Forward and backward compile on Mosaic at the real shapes (three
-    custom calls: fwd, dq, dk/dv — not the interpreter), and without
-    dropout agree with dense float32 "highest" attention to bf16
+    """Forward and backward compile on Mosaic at the real shapes (two
+    custom calls: fwd and the fused dq/dk/dv — not the interpreter), and
+    without dropout agree with dense float32 "highest" attention to bf16
     accuracy."""
     rng = np.random.RandomState(0)
     q, k, v = (jnp.asarray(rng.randn(n, s, d) * 0.5, jnp.bfloat16)
@@ -62,8 +62,9 @@ def test_flash_real_shapes_compiled(bh, bh_kv, s, d, causal, dropout, block,
                                         window=window)),
         argnums=(0, 1, 2)))
     text = flash.lower(q, k, v).as_text(debug_info=True)
-    assert text.count("tpu_custom_call") == 3
+    assert text.count("tpu_custom_call") == 2
     assert ("window_attention_fwd" in text) == (window is not None)
+    assert "_attention_bwd_dqkv" in text
     val, grads = flash(q, k, v)
     assert np.isfinite(float(val))
     assert all(np.isfinite(np.asarray(g, np.float32)).all() for g in grads)
@@ -83,4 +84,49 @@ def test_flash_real_shapes_compiled(bh, bh_kv, s, d, causal, dropout, block,
         readings[f"d{name}"] = float(rel)
         assert rel < 2e-2, f"d{name}: relative error {rel:.3e}"
     print(f"\n[flash {bh}/{bh_kv} x {s} x {d} window {window}] "
+          f"relative errors {readings}")
+
+
+# the fused backward at the heads of the two cells it was made for, at the
+# 2,048 positions whose dense float32 reference fits beside it:
+# kanana2_30b_a3b.train_s8192's latent attention (32 heads, Q/K 192 beside
+# V 128, group 1) and lfm2_8b_a1b.train_s8192's (32 query and 8 K/V heads of
+# 64, group 4); bf16, causal, the block the decoders give
+@pytest.mark.parametrize("bh,bh_kv,d,d_v", [(32, 32, 192, 128),
+                                            (32, 8, 64, 64)],
+                         ids=["kanana2_heads", "lfm2_heads"])
+def test_fused_backward_real_heads_compiled(bh, bh_kv, d, d_v):
+    """One ``flash_attention_bwd_dqkv`` call in the compiled gradient, no
+    split kernel, and dq, dk, dv within bf16 accuracy of dense float32
+    "highest" attention."""
+    import re
+    from mxnet_tpu.gluon.model_zoo._attention import \
+        _flash_block as _decoder_block
+    s = 2048
+    block = _decoder_block(s)
+    rng = np.random.RandomState(1)
+    q, k = (jnp.asarray(rng.randn(n, s, d) * 0.5, jnp.bfloat16)
+            for n in (bh, bh_kv))
+    v = jnp.asarray(rng.randn(bh_kv, s, d_v) * 0.5, jnp.bfloat16)
+    w = jnp.asarray(rng.randn(bh, s, d_v), jnp.float32)
+
+    def loss(fn):
+        return lambda q, k, v: (fn(q, k, v).astype(jnp.float32) * w).sum()
+    flash = jax.jit(jax.grad(loss(
+        lambda q, k, v: flash_attention(q, k, v, causal=True, block_q=block,
+                                        block_k=block)), argnums=(0, 1, 2)))
+    text = flash.lower(q, k, v).compile().as_text()
+    assert "flash_attention_bwd_dqkv" in text
+    assert not re.search(r"flash_attention_bwd_(dq|dkv)\b", text)
+    grads = flash(q, k, v)
+    with jax.default_matmul_precision("highest"):
+        ref = jax.jit(jax.grad(loss(
+            lambda q, k, v: _dense_f32(q, k, v, True)), argnums=(0, 1, 2)))(
+            q, k, v)
+    readings = {}
+    for name, g, r in zip("qkv", grads, ref):
+        g, r = np.asarray(g, np.float32), np.asarray(r, np.float32)
+        readings[f"d{name}"] = float(np.linalg.norm(g - r) / np.linalg.norm(r))
+        assert readings[f"d{name}"] < 2e-2, readings
+    print(f"\n[fused backward {bh}/{bh_kv} x {s} x {d}/{d_v}] "
           f"relative errors {readings}")

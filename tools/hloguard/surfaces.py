@@ -119,14 +119,15 @@ def _two_layer_step(layer):
 
 @_export_surface(
     "pallas_flash_attention_tpu", precision="bf16",
-    model="causal grouped-query flash attention, forward and both "
-          "backward kernels, two layers (ops/pallas/flash_attention.py)",
+    model="causal grouped-query flash attention, forward and fused "
+          "backward kernel, two layers (ops/pallas/flash_attention.py)",
     geometry="q bf16[40,256,64], k/v bf16[20,256,64], blocks of 128: "
              "phi4_mini_flash's 40/20 heads of 64 at a short sequence")
 def _flash_attention_programs():
     """``_flash_fwd`` and ``_flash_bwd`` are ``jax.jit``s, so two layers
-    share one lowering of each of the three kernels (PR 30): total 3,
-    unique 3.  A fourth payload is a kernel traced again."""
+    share one lowering of each of the two kernels, the forward and the
+    fused backward: total 2, unique 2.  A third payload is a kernel traced
+    again."""
     import functools
 
     import jax
